@@ -1,0 +1,13 @@
+"""init_ms.sweep: milliseconds per batch in the program's
+``madsim.search.init`` span: the (init, run) program lookup and
+``init(seeds[, rows])`` (program span, read from the run's trace by
+program_trace)."""
+
+import program_trace
+
+
+def read(rec):
+    if rec["kind"] != "sweep":
+        return None
+    return program_trace.per_unit(rec, "madsim.search.init",
+                                  "bench.batch")

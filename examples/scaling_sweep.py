@@ -9,8 +9,7 @@ Methodology (engine/measure.py): every cell is timed as >= 5 s-long
 jitted dispatches — a ``fori_loop`` of independent seed-batches inside
 ONE dispatch — so per-dispatch overhead is amortized below the noise
 floor instead of dominating sub-second runs. Cells report the median
-over 3 dispatches with min/max spread; the artifact also records a
-null-kernel dispatch profile quantifying that overhead. A cell is
+over 3 dispatches with min/max spread. A cell is
 quotable only if ``overflow == 0`` and ``all_halted`` — check before
 quoting. It runs on whatever JAX gives it (``JAX_PLATFORMS=cpu`` for
 the CPU backend) and records the platform.
@@ -30,7 +29,7 @@ import time
 import jax
 
 from madsim_tpu.engine import EngineConfig
-from madsim_tpu.engine.measure import measure_throughput, null_dispatch_stats
+from madsim_tpu.engine.measure import measure_throughput
 from madsim_tpu.models import BENCH_SPECS
 
 SEED_COUNTS = [1024, 4096, 16384, 65536]
@@ -45,9 +44,7 @@ def main():
     n_measure = 2 if quick else 3
 
     platform = jax.devices()[0].platform
-    null = null_dispatch_stats()
-    print(f"# platform={platform} null_dispatch={json.dumps(null)}",
-          file=sys.stderr)
+    print(f"# platform={platform}", file=sys.stderr)
 
     rows = []
     for name, (mk, cfg_kw, _spec_seeds, max_steps) in BENCH_SPECS.items():
@@ -69,7 +66,7 @@ def main():
             rows.append(rec)
             print(json.dumps(rec), flush=True)
 
-    doc = {"platform": platform, "null_dispatch": null, "rows": rows}
+    doc = {"platform": platform, "rows": rows}
     with open(out_path, "w") as f:
         json.dump(doc, f, indent=1)
     print(f"# wrote {out_path} ({platform})", file=sys.stderr)

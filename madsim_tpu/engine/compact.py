@@ -116,6 +116,32 @@ RESULT_FIELDS = (
 SCREEN_FIELDS = ("hist_ok", "hist_fold")
 
 
+class HostFetch:
+    """``np.asarray`` that counts the bytes crossing device->host: each
+    device array once (a fetched array keeps its host copy), host
+    arrays free. ``nbytes`` is the running total."""
+
+    def __init__(self):
+        self.nbytes = 0
+        self._fetched: list = []
+
+    def __call__(self, x) -> np.ndarray:
+        if isinstance(x, jax.Array) and not any(
+            x is y for y in self._fetched
+        ):
+            self._fetched.append(x)
+            self.nbytes += int(x.nbytes)
+        return np.asarray(x)
+
+
+def row_steps(banked, fetch=np.asarray) -> tuple[int, int]:
+    """(paid, live) row-steps of one phase-program result: rows x loop
+    iterations, each phase at its own size, and those in which the row
+    had not yet halted — summed over the shards of a mesh run."""
+    paid, live = fetch(banked[-1]["_row_steps"]).reshape(-1, 2).sum(0)
+    return int(paid), int(live)
+
+
 def _phase_sizes(s0: int, shrink: int, min_size: int) -> list[int]:
     sizes = [s0]
     while sizes[-1] // shrink >= min_size:
@@ -235,21 +261,33 @@ def make_run_compacted(
         steps = jnp.int64(0)
         st = state
         banked = []
+        # row-step counters: rows x iterations paid at each phase's size,
+        # and the rows of each iteration not yet halted when it ran. The
+        # live count the loop exits on is carried from the body, so the
+        # counters add no reduction per iteration
+        live = jnp.sum(~st.halted).astype(jnp.int64)
+        paid = live_steps = jnp.int64(0)
 
-        for next_size in list(sizes[1:]) + [0]:
+        for size, next_size in zip(sizes, list(sizes[1:]) + [0]):
 
             def cond(carry, _n=next_size):
-                s, i = carry
-                live = jnp.sum(~s.halted)
-                return (i < max_steps) & (live > _n)
+                s, i, n_live, acc = carry
+                return (i < max_steps) & (n_live > _n)
 
             def body(carry):
-                s, i = carry
-                return step(s), i + 1
+                s, i, n_live, acc = carry
+                s = step(s)
+                n_live2 = jnp.sum(~s.halted).astype(jnp.int64)
+                return s, i + 1, n_live2, acc + n_live
 
-            st, steps = lax.while_loop(cond, body, (st, steps))
+            steps0 = steps
+            st, steps, live, live_steps = lax.while_loop(
+                cond, body, (st, steps, live, live_steps)
+            )
+            paid = paid + (steps - steps0) * size
             if next_size == 0:
                 banked.append(_bank(st, idx))
+                banked[-1]["_row_steps"] = jnp.stack([paid, live_steps])
                 break
             # stable partition: live rows first, halted tail banked.
             # Stability keeps the relative order of live rows, so the
@@ -270,7 +308,7 @@ def make_run_compacted(
 
     out_fields = fields if screens is None else fields + SCREEN_FIELDS
 
-    def assemble(banked) -> SimpleNamespace:
+    def assemble(banked, fetch=None) -> SimpleNamespace:
         """Device->host transfer + scatter back into original seed order.
 
         Under a ``hist_screen``, the folded history columns transfer
@@ -278,13 +316,16 @@ def make_run_compacted(
         (fetched first — one tiny counter read): the fold's whole point
         is that the big (rows, H, ...) column transfer shrinks to the
         pending-invoke prefix plus the flagged seeds' full histories.
+
+        ``fetch`` (a :class:`HostFetch`) counts the bytes copied.
         """
-        s0 = sum(np.asarray(b["_idx"]).shape[0] for b in banked)
+        fetch = HostFetch() if fetch is None else fetch
+        idxs = [fetch(b["_idx"]) for b in banked]
+        s0 = sum(i.shape[0] for i in idxs)
         trim = {}
         if screens is not None:
             kept = max(
-                (int(np.asarray(b["hist_count"]).max(initial=0))
-                 for b in banked),
+                (int(fetch(b["hist_count"]).max(initial=0)) for b in banked),
                 default=0,
             )
             trim = {"hist_word": kept, "hist_t": kept}
@@ -293,15 +334,15 @@ def make_run_compacted(
             proto = banked[0][f]
             buf = np.zeros((s0,) + tuple(proto.shape[1:]), proto.dtype)
             k = trim.get(f)
-            for b in banked:
+            for b, idx in zip(banked, idxs):
                 if k is None:
-                    buf[np.asarray(b["_idx"])] = np.asarray(b[f])
+                    buf[idx] = fetch(b[f])
                 else:
                     # device-side slice: only the surviving prefix
                     # crosses the boundary (rows past hist_count are
                     # zero by the fold, so the untransferred tail of
                     # the host buffer is value-identical)
-                    buf[np.asarray(b["_idx"]), :k] = np.asarray(b[f][:, :k])
+                    buf[idx, :k] = fetch(b[f][:, :k])
             out[f] = buf
         return SimpleNamespace(**out)
 
@@ -311,7 +352,8 @@ def make_run_compacted(
     # benchmark seam: time `compute` (device work only, block on device
     # arrays) and call `assemble` outside the window — keeps the metric
     # methodologically identical to timing the lockstep loop, where the
-    # host read also happened after the timed region
+    # host read also happened after the timed region. The banks' last
+    # entry also carries the loop's `_row_steps` (module row_steps)
     run.compute = jitted
     run.assemble = assemble
     # sharding seam: the raw phase program, for parallel.shard_run_compacted

@@ -4438,6 +4438,12 @@ def make_run_while(
     silently continues — check ``overflow == 0`` before trusting
     per-seed results. ``cold_split`` follows the make_run contract
     (cold latency bank folded batch-level only on marker steps).
+
+    ``run.counted(state) -> (final, row_steps)`` is the same loop that
+    also returns ``row_steps``, int64 ``[paid, live]``: rows x loop
+    iterations, and those in which the row had not yet halted. The
+    loop exits on the live count it carries, so counting costs no
+    reduction beyond the one the exit test makes anyway.
     """
     cold = _resolve_cold_split(wl, latency, cov_words, cold_split)
     step = jax.vmap(make_step(
@@ -4451,16 +4457,28 @@ def make_run_while(
         if cold else step
     )
 
-    def run(state: SimState) -> SimState:
+    def counted(state: SimState):
         def cond(carry):
-            s, i = carry
-            return (i < max_steps) & ~jnp.all(s.halted)
+            s, i, live, acc = carry
+            return (i < max_steps) & (live > 0)
 
         def body(carry):
-            s, i = carry
-            return advance(s), i + 1
+            s, i, live, acc = carry
+            s = advance(s)
+            live2 = jnp.sum(~s.halted).astype(jnp.int64)
+            return s, i + 1, live2, acc + live
 
-        final, _ = lax.while_loop(cond, body, (state, jnp.int64(0)))
-        return final
+        live0 = jnp.sum(~state.halted).astype(jnp.int64)
+        final, iters, _, live_steps = lax.while_loop(
+            cond, body, (state, jnp.int64(0), live0, jnp.int64(0))
+        )
+        return final, jnp.stack([iters * state.seed.shape[0], live_steps])
 
+    def run(state: SimState) -> SimState:
+        return counted(state)[0]
+
+    # jitted on its own, the counting loop keeps the runner's program
+    # name (``jit_run``) in device traces
+    counted.__name__ = counted.__qualname__ = "run"
+    run.counted = counted
     return run

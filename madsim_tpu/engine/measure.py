@@ -17,10 +17,6 @@ how much simulation rides inside it.
 ``repeats`` so a dispatch lasts >= ``target_wall_s`` (default 5 s),
 and reports the median sim-s/s over
 ``n_measure`` dispatches with min/max spread.
-
-``null_dispatch_stats`` times a trivial kernel the same way the sweep
-times real ones, quantifying the per-dispatch overhead floor once per
-artifact instead of letting it silently contaminate every cell.
 """
 
 from __future__ import annotations
@@ -40,7 +36,6 @@ __all__ = [
     "make_repeat_program",
     "measure_throughput",
     "measure_latency",
-    "null_dispatch_stats",
 ]
 
 
@@ -259,27 +254,3 @@ def measure_latency(
         "all_halted": halted_min == repeats,
     }
 
-
-def null_dispatch_stats(n: int = 20) -> dict:
-    """Per-dispatch overhead floor: time a trivial jitted kernel.
-
-    The result bounds how much of any measured cell is transport, not
-    compute — quote it alongside sweep artifacts so a reader can check
-    that cells were sized to dominate it.
-    """
-    f = jax.jit(lambda x: x + 1)
-    x = jnp.zeros((), jnp.int32)
-    jax.block_until_ready(f(x))
-    walls = []
-    for _ in range(n):
-        t0 = time.perf_counter()  # lint: allow(wall-clock)
-        jax.block_until_ready(f(x))
-        walls.append(time.perf_counter() - t0)  # lint: allow(wall-clock)
-    w = np.asarray(walls)
-    return {
-        "n": n,
-        "min_ms": round(float(w.min()) * 1e3, 3),
-        "median_ms": round(float(np.median(w)) * 1e3, 3),
-        "p90_ms": round(float(np.quantile(w, 0.9)) * 1e3, 3),
-        "max_ms": round(float(w.max()) * 1e3, 3),
-    }

@@ -36,7 +36,13 @@ import numpy as np
 
 import jax
 
-from .compact import RESULT_FIELDS, SCREEN_FIELDS, make_run_compacted
+from .compact import (
+    RESULT_FIELDS,
+    SCREEN_FIELDS,
+    HostFetch,
+    make_run_compacted,
+    row_steps,
+)
 from .core import (
     EngineConfig,
     Workload,
@@ -154,7 +160,9 @@ def make_sweep(
     invariant evaluation, and the function is jit/shard_map-traceable,
     so callers can fuse it into larger device programs (the explore
     device driver composes it with on-device mutation and admission;
-    ``search_seeds`` wraps it with the host-side report instead).
+    ``search_seeds`` wraps it with the host-side report instead). The
+    view also holds ``row_steps``, the loop's int64 ``[paid, live]``
+    row-step counters (``make_run_while``).
     """
     init, run = _build_init_run(
         wl, cfg, max_steps, layout=layout, plan_slots=plan_slots,
@@ -165,10 +173,14 @@ def make_sweep(
     )
 
     def sweep(seeds, rows=None):
-        out = run(init(seeds, rows) if plan_slots else init(seeds))
-        return {
+        out, counts = run.counted(
+            init(seeds, rows) if plan_slots else init(seeds)
+        )
+        view = {
             f.name: getattr(out, f.name) for f in dataclasses.fields(out)
         }
+        view["row_steps"] = counts
+        return view
 
     return sweep
 
@@ -215,7 +227,9 @@ def _compiled_run(wl: Workload, cfg: EngineConfig, max_steps: int, layout,
         # build wall stays inside dispatch — documented limitation)
         _RUN_CACHE[key] = (
             init,
-            run if compact else AotProgram("engine.search.run", key, run),
+            # the lockstep program returns (final state, row steps)
+            run if compact
+            else AotProgram("engine.search.run", key, run.counted),
             wl,  # keep the workload alive so id() stays unique
         )
     return _RUN_CACHE[key]
@@ -301,6 +315,14 @@ class SearchReport:
     # tl_seq/tl_parent/tl_lam columns ride report.timeline and
     # obs.causal.causal_slice computes violation cones from them
     lam: np.ndarray | None = None
+    # host-path counters (also the ``madsim.search`` span's metadata,
+    # obs.prof): bytes this call copied device->host, and the runner's
+    # row-steps — rows x loop iterations paid (the compacted runner at
+    # each phase's size) and those whose row had not yet halted, so
+    # row_steps_live / row_steps_paid is the lockstep efficiency
+    d2h_bytes: int = 0
+    row_steps_paid: int = 0
+    row_steps_live: int = 0
 
     @property
     def failing_seeds(self) -> np.ndarray:
@@ -411,17 +433,19 @@ class SearchReport:
         return "\n".join(lines)
 
 
-def _state_view(out, keep_device: tuple = ()) -> Mapping[str, np.ndarray]:
+def _state_view(out, fetch=np.asarray,
+                keep_device: tuple = ()) -> Mapping[str, np.ndarray]:
     """Host-side numpy views of EVERY final-state field, keyed by name
     (the checkpoint.py pattern) — invariants can reach anything,
     including paused/clog chaos state and the raw event pool.
     ``keep_device`` names stay as device arrays (a device-checked sweep
     never materializes the big history columns on the host — that is
-    the transfer the verdict words replace)."""
+    the transfer the verdict words replace). ``fetch`` copies the
+    others (a ``compact.HostFetch`` counts the bytes)."""
     return {
         f.name: (
             getattr(out, f.name) if f.name in keep_device
-            else np.asarray(getattr(out, f.name))
+            else fetch(getattr(out, f.name))
         )
         for f in dataclasses.fields(out)
     }
@@ -547,254 +571,291 @@ def search_seeds(
     (``report.hist_fold`` counts the folded records; flagged seeds
     keep full histories — see ``make_run_compacted``).
     """
-    if history_invariant is not None and wl.history is None:
-        raise ValueError(
-            f"history_invariant needs operation histories, but workload "
-            f"{wl.name!r} has Workload.history=None"
-        )
-    screens = None
-    if device_check is not None:
-        from ..check.device import as_screens
+    from ..obs.prof import span
 
-        screens = as_screens(device_check)
-        if wl.history is None:
+    with span("madsim.search") as sp:
+        if history_invariant is not None and wl.history is None:
             raise ValueError(
-                f"device_check judges operation histories, but workload "
+                f"history_invariant needs operation histories, but workload "
                 f"{wl.name!r} has Workload.history=None"
             )
-        if history_invariant is not None:
-            raise ValueError(
-                "pass device_check OR history_invariant, not both: they "
-                "are the same verdict on two execution paths (compare "
-                "them via check.device.screens_invariant in a test, not "
-                "in one sweep)"
-            )
-    if invariant is None and history_invariant is None and screens is None:
-        raise ValueError(
-            "need an invariant, a history_invariant or a device_check"
-        )
-    if plan is not None and plan_rows is not None:
-        raise ValueError("pass plan OR plan_rows, not both")
-    if seeds is None:
-        seeds = np.arange(seed_base, seed_base + n_seeds, dtype=np.uint64)
-    else:
-        seeds = np.asarray(seeds, np.uint64)
-        if seeds.ndim != 1:
-            raise ValueError(f"seeds must be 1-D, got shape {seeds.shape}")
-        n_seeds = len(seeds)
-    if plan is not None:
-        plan_slots = int(plan.slots)
-        if dup_rows is None:
-            dup_rows = bool(plan.uses_dup())
-        if latency is not None:
-            # a client army whose op-id range exceeds the latency
-            # columns would silently drop every out-of-range marker
-            # (counted in lat_drop, but a whole mis-sized army is a
-            # build error, not a runtime anomaly)
-            for spec in getattr(plan, "specs", ()):
-                ob = getattr(spec, "op_base", None)
-                no = getattr(spec, "n_ops", None)
-                if ob is not None and no is not None and ob + no > latency.ops:
-                    raise ValueError(
-                        f"{type(spec).__name__} op ids "
-                        f"[{ob}, {ob + no}) exceed LatencySpec.ops="
-                        f"{latency.ops}; size the spec to cover every "
-                        f"army op id"
-                    )
-        if cfg.time_limit_ns and hasattr(plan, "validate_windows"):
-            # a fault window opening after the clock cap can never fire:
-            # the sweep would silently certify the unfaulted protocol
-            # (chaos.FaultPlan.validate_windows — warn loudly here,
-            # clamp explicitly via plan.clamped(...))
-            plan.validate_windows(cfg.time_limit_ns)
-        rows = plan.compile_batch(seeds, wl=wl)
-        if plan_hash is None:
-            plan_hash = plan.hash()
-        if retry is None and hasattr(plan, "retry_spec"):
-            retry = plan.retry_spec()
-    elif plan_rows is not None:
-        rows = plan_rows
-        plan_slots = int(np.asarray(rows.time).shape[1])
-        if np.asarray(rows.time).shape[0] != n_seeds:
-            raise ValueError(
-                f"plan_rows carries {np.asarray(rows.time).shape[0]} rows "
-                f"for {n_seeds} seeds"
-            )
-        dup_rows = bool(dup_rows)
-    else:
-        rows = None
-        plan_slots = 0
-        dup_rows = bool(dup_rows)
-    init, run, _ = _compiled_run(
-        wl, cfg, max_steps, layout, compact, plan_slots, dup_rows,
-        cov_words, metrics, timeline_cap, cov_hitcount, latency,
-        pool_index,
-        # only the compacted program embeds the screen (bank-time fold);
-        # the lockstep path screens via _screen_prog, so its run cache
-        # entry must stay shared with unscreened sweeps
-        hist_screen=screens if compact else None,
-        causal=causal, retry=retry,
-    )
-    if rows is not None:
-        if resolve_time32(wl, cfg, None):
-            # the compiled rows land in the int32 offset representation:
-            # a plan event past the horizon would silently wrap
-            from .core import _T32_LIMIT
+        screens = None
+        if device_check is not None:
+            from ..check.device import as_screens
 
-            lim = _T32_LIMIT - cfg.proc_max_ns - 1
-            worst = int(np.asarray(rows.time).max(initial=0))
-            if worst > lim:
+            screens = as_screens(device_check)
+            if wl.history is None:
                 raise ValueError(
-                    f"fault-plan event at t={worst} ns exceeds the int32 "
-                    f"time horizon ({lim} ns) active for this (workload, "
-                    f"config); shrink the plan windows or disable time32"
+                    f"device_check judges operation histories, but workload "
+                    f"{wl.name!r} has Workload.history=None"
                 )
-        state0 = init(seeds, rows)
-    else:
-        state0 = init(seeds)
-    if compact:
-        out = run(state0)
-        fields = RESULT_FIELDS if screens is None else (
-            RESULT_FIELDS + SCREEN_FIELDS
-        )
-        view = {f: getattr(out, f) for f in fields}
-    else:
-        out = jax.block_until_ready(run(state0))
-        view = _state_view(
-            out,
-            keep_device=("hist_word", "hist_t") if screens is not None
-            else (),
-        )
-    if invariant is not None:
-        ok = np.asarray(invariant(view), dtype=bool)
-        if ok.shape != (n_seeds,):
+            if history_invariant is not None:
+                raise ValueError(
+                    "pass device_check OR history_invariant, not both: they "
+                    "are the same verdict on two execution paths (compare "
+                    "them via check.device.screens_invariant in a test, not "
+                    "in one sweep)"
+                )
+        if invariant is None and history_invariant is None and screens is None:
             raise ValueError(
-                f"invariant must return a ({n_seeds},) boolean array, "
-                f"got shape {ok.shape}"
+                "need an invariant, a history_invariant or a device_check"
             )
-    else:
-        ok = np.ones((n_seeds,), dtype=bool)
-    pool_overflowed = np.asarray(view["overflow"]) > 0
-    overflowed = pool_overflowed
-    dev_ok = None
-    verdict_words = None
-    flagged_idx = None
-    flagged_history = None
-    if screens is not None:
-        from ..check.device import pack_verdicts_host, unpack_verdicts
-        from ..check.history import BatchHistory
-
-        if compact:
-            # bank-time verdicts (computed on device BEFORE the fold)
-            dev_ok = np.asarray(view["hist_ok"], bool)
-            verdict_words = pack_verdicts_host(dev_ok)
+        if plan is not None and plan_rows is not None:
+            raise ValueError("pass plan OR plan_rows, not both")
+        if seeds is None:
+            seeds = np.arange(seed_base, seed_base + n_seeds, dtype=np.uint64)
         else:
-            # THE history transfer of a device-checked sweep: ceil(S/32)
-            # packed words instead of (S, H, 5) + (S, H) columns
-            verdict_words = np.asarray(
-                _screen_prog(screens)(
-                    out.hist_word, out.hist_t, out.hist_count,
-                    out.hist_drop,
+            seeds = np.asarray(seeds, np.uint64)
+            if seeds.ndim != 1:
+                raise ValueError(f"seeds must be 1-D, got shape {seeds.shape}")
+            n_seeds = len(seeds)
+        fetch = HostFetch()
+        with span("madsim.search.plan"):
+            if plan is not None:
+                plan_slots = int(plan.slots)
+                if dup_rows is None:
+                    dup_rows = bool(plan.uses_dup())
+                if latency is not None:
+                    # a client army whose op-id range exceeds the latency
+                    # columns would silently drop every out-of-range marker
+                    # (counted in lat_drop, but a whole mis-sized army is a
+                    # build error, not a runtime anomaly)
+                    for spec in getattr(plan, "specs", ()):
+                        ob = getattr(spec, "op_base", None)
+                        no = getattr(spec, "n_ops", None)
+                        if (ob is not None and no is not None
+                                and ob + no > latency.ops):
+                            raise ValueError(
+                                f"{type(spec).__name__} op ids "
+                                f"[{ob}, {ob + no}) exceed LatencySpec.ops="
+                                f"{latency.ops}; size the spec to cover every "
+                                f"army op id"
+                            )
+                if cfg.time_limit_ns and hasattr(plan, "validate_windows"):
+                    # a fault window opening after the clock cap can never
+                    # fire: the sweep would silently certify the unfaulted
+                    # protocol (chaos.FaultPlan.validate_windows — warn
+                    # loudly here, clamp explicitly via plan.clamped(...))
+                    plan.validate_windows(cfg.time_limit_ns)
+                rows = plan.compile_batch(seeds, wl=wl)
+                if plan_hash is None:
+                    plan_hash = plan.hash()
+                if retry is None and hasattr(plan, "retry_spec"):
+                    retry = plan.retry_spec()
+            elif plan_rows is not None:
+                rows = plan_rows
+                plan_slots = int(fetch(rows.time).shape[1])
+                if fetch(rows.time).shape[0] != n_seeds:
+                    raise ValueError(
+                        f"plan_rows carries {fetch(rows.time).shape[0]} rows "
+                        f"for {n_seeds} seeds"
+                    )
+                dup_rows = bool(dup_rows)
+            else:
+                rows = None
+                plan_slots = 0
+                dup_rows = bool(dup_rows)
+            if rows is not None and resolve_time32(wl, cfg, None):
+                # the compiled rows land in the int32 offset representation:
+                # a plan event past the horizon would silently wrap
+                from .core import _T32_LIMIT
+
+                lim = _T32_LIMIT - cfg.proc_max_ns - 1
+                worst = int(fetch(rows.time).max(initial=0))
+                if worst > lim:
+                    raise ValueError(
+                        f"fault-plan event at t={worst} ns exceeds the "
+                        f"int32 time horizon ({lim} ns) active for this "
+                        f"(workload, config); shrink the plan windows or "
+                        f"disable time32"
+                    )
+        with span("madsim.search.init"):
+            init, run, _ = _compiled_run(
+                wl, cfg, max_steps, layout, compact, plan_slots, dup_rows,
+                cov_words, metrics, timeline_cap, cov_hitcount, latency,
+                pool_index,
+                # only the compacted program embeds the screen (bank-time
+                # fold); the lockstep path screens via _screen_prog, so its
+                # run cache entry must stay shared with unscreened sweeps
+                hist_screen=screens if compact else None,
+                causal=causal, retry=retry,
+            )
+            state0 = init(seeds, rows) if rows is not None else init(seeds)
+        with span("madsim.search.run"):
+            if compact:
+                banked = jax.block_until_ready(run.compute(state0))
+            else:
+                out, counts = jax.block_until_ready(run(state0))
+        verdict_words = None
+        with span("madsim.search.fetch"):
+            if compact:
+                out = run.assemble(banked, fetch)
+                paid, live = row_steps(banked, fetch)
+                fields = RESULT_FIELDS if screens is None else (
+                    RESULT_FIELDS + SCREEN_FIELDS
                 )
-            )
-            dev_ok = unpack_verdicts(verdict_words, n_seeds)
-        ok = ok & dev_ok
-        # escalation: exactly the flagged (and trustworthy) seeds ship
-        # their FULL histories to the host — the Wing–Gong
-        # confirmation input, the PR-1 cross-check discipline
-        hist_drop_np = np.asarray(view["hist_drop"])
-        flagged_idx = np.nonzero(~dev_ok & ~(hist_drop_np > 0))[0]
-        w, tt = view["hist_word"], view["hist_t"]
-        flagged_history = BatchHistory(
-            # device gather + transfer of only the flagged rows on the
-            # lockstep path; plain numpy take on the compact path
-            # (whose columns arrived prefix-compacted, flagged seeds
-            # verbatim-full by construction)
-            word=np.asarray(w[flagged_idx]),
-            t=np.asarray(tt[flagged_idx]),
-            count=np.asarray(view["hist_count"])[flagged_idx],
-            drop=hist_drop_np[flagged_idx],
-        )
-    if history_invariant is not None:
-        # imported here: check is a consumer of the engine, not a
-        # dependency (engine -> check at module import would be a cycle)
-        from ..check.history import BatchHistory
+                view = {f: getattr(out, f) for f in fields}
+            else:
+                paid, live = (int(x) for x in fetch(counts))
+                view = _state_view(
+                    out, fetch,
+                    keep_device=(
+                        ("hist_word", "hist_t") if screens is not None
+                        else ()
+                    ),
+                )
+                if screens is not None:
+                    # THE history transfer of a device-checked sweep:
+                    # ceil(S/32) packed words instead of (S, H, 5) +
+                    # (S, H) columns
+                    verdict_words = fetch(
+                        _screen_prog(screens)(
+                            out.hist_word, out.hist_t, out.hist_count,
+                            out.hist_drop,
+                        )
+                    )
+        with span("madsim.search.check"):
+            if invariant is not None:
+                ok = np.asarray(invariant(view), dtype=bool)
+                if ok.shape != (n_seeds,):
+                    raise ValueError(
+                        f"invariant must return a ({n_seeds},) boolean "
+                        f"array, got shape {ok.shape}"
+                    )
+            else:
+                ok = np.ones((n_seeds,), dtype=bool)
+            pool_overflowed = np.asarray(view["overflow"]) > 0
+            overflowed = pool_overflowed
+            dev_ok = None
+            flagged_idx = None
+            flagged_history = None
+            if screens is not None:
+                from ..check.device import (
+                    pack_verdicts_host,
+                    unpack_verdicts,
+                )
+                from ..check.history import BatchHistory
 
-        bh = BatchHistory.from_view(view)
-        hist_over = np.asarray(bh.drop) > 0
-        if hist_over.any():
-            # overflowed seeds reach the invariant as EMPTY histories:
-            # their verdicts are discarded by the quarantine below, and
-            # a strict per-seed checker (BatchHistory.ops) must not
-            # crash the whole sweep on a seed it will never judge. The
-            # raw truncated columns stay available on the result view.
-            bh = BatchHistory(
-                word=bh.word, t=bh.t,
-                count=np.where(hist_over, 0, np.asarray(bh.count)).astype(
-                    np.int32
+                if compact:
+                    # bank-time verdicts (computed on device BEFORE the
+                    # fold)
+                    dev_ok = np.asarray(view["hist_ok"], bool)
+                    verdict_words = pack_verdicts_host(dev_ok)
+                else:
+                    dev_ok = unpack_verdicts(verdict_words, n_seeds)
+                ok = ok & dev_ok
+                # escalation: exactly the flagged (and trustworthy) seeds
+                # ship their FULL histories to the host — the Wing–Gong
+                # confirmation input, the PR-1 cross-check discipline
+                hist_drop_np = np.asarray(view["hist_drop"])
+                flagged_idx = np.nonzero(~dev_ok & ~(hist_drop_np > 0))[0]
+                w, tt = view["hist_word"], view["hist_t"]
+                flagged_history = BatchHistory(
+                    # device gather + transfer of only the flagged rows on
+                    # the lockstep path; plain numpy take on the compact
+                    # path (whose columns arrived prefix-compacted, flagged
+                    # seeds verbatim-full by construction)
+                    word=fetch(w[flagged_idx]),
+                    t=fetch(tt[flagged_idx]),
+                    count=np.asarray(view["hist_count"])[flagged_idx],
+                    drop=hist_drop_np[flagged_idx],
+                )
+            if history_invariant is not None:
+                # imported here: check is a consumer of the engine, not a
+                # dependency (engine -> check at module import would be a
+                # cycle)
+                from ..check.history import BatchHistory
+
+                bh = BatchHistory.from_view(view)
+                hist_over = np.asarray(bh.drop) > 0
+                if hist_over.any():
+                    # overflowed seeds reach the invariant as EMPTY
+                    # histories: their verdicts are discarded by the
+                    # quarantine below, and a strict per-seed checker
+                    # (BatchHistory.ops) must not crash the whole sweep on
+                    # a seed it will never judge. The raw truncated
+                    # columns stay available on the result view.
+                    bh = BatchHistory(
+                        word=bh.word, t=bh.t,
+                        count=np.where(
+                            hist_over, 0, np.asarray(bh.count)
+                        ).astype(np.int32),
+                        drop=np.zeros_like(np.asarray(bh.drop)),
+                    )
+                hok = np.asarray(history_invariant(bh), dtype=bool)
+                if hok.shape != (n_seeds,):
+                    raise ValueError(
+                        f"history_invariant must return a ({n_seeds},) "
+                        f"boolean array, got shape {hok.shape}"
+                    )
+                ok = ok & hok
+            hist_dropped = None
+            if wl.history is not None:
+                # dropped history records void the verdict (loud, like
+                # pool overflow) whether or not a history predicate ran
+                hist_dropped = np.asarray(view["hist_drop"]) > 0
+                overflowed = overflowed | hist_dropped
+            halted = view["halted"]
+            if require_halt:
+                ok = ok & halted
+            if timeline_cap:
+                from types import SimpleNamespace
+
+                tl = SimpleNamespace(**{
+                    f: np.asarray(view[f])
+                    for f in ("tl_count", "tl_drop", "tl_t", "tl_meta",
+                              "tl_args", "tl_pay", "tl_emit")
+                    + (("tl_seq", "tl_parent", "tl_lam") if causal else ())
+                })
+                tl_dropped = tl.tl_drop > 0
+            else:
+                tl, tl_dropped = None, None
+            report = SearchReport(
+                workload=wl.name,
+                config_hash=cfg.hash(),
+                seeds=seeds,
+                ok=ok,
+                halted=halted,
+                overflowed=overflowed,
+                traces=view["trace"],
+                steps=int(np.asarray(view["step"]).max()),
+                plan_hash=plan_hash or "",
+                build_wall_s=getattr(run, "last_build_s", 0.0),
+                cov=np.asarray(view["cov"]) if cov_words else None,
+                halt_times=np.asarray(view["halt_time"]),
+                met=np.asarray(view["met"]) if metrics else None,
+                timeline=tl,
+                pool_overflowed=pool_overflowed,
+                hist_dropped=hist_dropped,
+                tl_dropped=tl_dropped,
+                lat_hist=(
+                    np.asarray(view["lat_hist"]) if latency is not None
+                    else None
                 ),
-                drop=np.zeros_like(np.asarray(bh.drop)),
+                lat_count=(
+                    np.asarray(view["lat_count"]) if latency is not None
+                    else None
+                ),
+                lat_dropped=(
+                    np.asarray(view["lat_drop"]) > 0 if latency is not None
+                    else None
+                ),
+                screen_ok=dev_ok,
+                verdict_words=verdict_words,
+                flagged_idx=flagged_idx,
+                flagged_history=flagged_history,
+                hist_fold=(
+                    np.asarray(view["hist_fold"])
+                    if screens is not None and compact else None
+                ),
+                lam=np.asarray(view["lam"]) if causal else None,
+                d2h_bytes=fetch.nbytes,
+                row_steps_paid=paid,
+                row_steps_live=live,
             )
-        hok = np.asarray(history_invariant(bh), dtype=bool)
-        if hok.shape != (n_seeds,):
-            raise ValueError(
-                f"history_invariant must return a ({n_seeds},) boolean "
-                f"array, got shape {hok.shape}"
-            )
-        ok = ok & hok
-    hist_dropped = None
-    if wl.history is not None:
-        # dropped history records void the verdict (loud, like pool
-        # overflow) whether or not a history predicate ran
-        hist_dropped = np.asarray(view["hist_drop"]) > 0
-        overflowed = overflowed | hist_dropped
-    halted = view["halted"]
-    if require_halt:
-        ok = ok & halted
-    if timeline_cap:
-        from types import SimpleNamespace
-
-        tl = SimpleNamespace(**{
-            f: np.asarray(view[f])
-            for f in ("tl_count", "tl_drop", "tl_t", "tl_meta",
-                      "tl_args", "tl_pay", "tl_emit")
-            + (("tl_seq", "tl_parent", "tl_lam") if causal else ())
-        })
-        tl_dropped = tl.tl_drop > 0
-    else:
-        tl, tl_dropped = None, None
-    return SearchReport(
-        workload=wl.name,
-        config_hash=cfg.hash(),
-        seeds=seeds,
-        ok=ok,
-        halted=halted,
-        overflowed=overflowed,
-        traces=view["trace"],
-        steps=int(np.asarray(out.step).max()),
-        plan_hash=plan_hash or "",
-        build_wall_s=getattr(run, "last_build_s", 0.0),
-        cov=np.asarray(view["cov"]) if cov_words else None,
-        halt_times=np.asarray(view["halt_time"]),
-        met=np.asarray(view["met"]) if metrics else None,
-        timeline=tl,
-        pool_overflowed=pool_overflowed,
-        hist_dropped=hist_dropped,
-        tl_dropped=tl_dropped,
-        lat_hist=np.asarray(view["lat_hist"]) if latency is not None else None,
-        lat_count=(
-            np.asarray(view["lat_count"]) if latency is not None else None
-        ),
-        lat_dropped=(
-            np.asarray(view["lat_drop"]) > 0 if latency is not None else None
-        ),
-        screen_ok=dev_ok,
-        verdict_words=verdict_words,
-        flagged_idx=flagged_idx,
-        flagged_history=flagged_history,
-        hist_fold=(
-            np.asarray(view["hist_fold"])
-            if screens is not None and compact else None
-        ),
-        lam=np.asarray(view["lam"]) if causal else None,
-    )
+        sp.set_metadata(
+            d2h_bytes=report.d2h_bytes,
+            row_steps_paid=report.row_steps_paid,
+            row_steps_live=report.row_steps_live,
+        )
+    return report

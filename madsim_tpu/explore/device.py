@@ -80,6 +80,7 @@ from ..chaos.plan import FaultEvent, FaultPlan, LiteralPlan, stack_plan_rows
 from ..engine.core import PlanRows, resolve_time32
 from ..engine.rng import PURPOSE_EXPLORE, threefry2x32
 from ..engine.search import make_sweep
+from ..obs.prof import span
 from .driver import CorpusEntry, ExploreReport, _pad_literal
 from .mutate import (
     MODE_NODE,
@@ -552,6 +553,9 @@ def _build_programs(
             cols["met"] = view["met"]
         if latency is not None:
             cols["lat_hist"] = view["lat_hist"]
+        # this shard's [paid, live] row-steps: on a mesh they stay
+        # sharded, (2 x devices,), and the host sums them at the sync
+        cols["row_steps"] = view["row_steps"]
         return cols
 
     def _jglob():
@@ -729,11 +733,12 @@ def _build_programs(
             # them, and merge_metrics/merge_latency fold them as
             # per-device local sums (D rows to the host, no gather).
             out = {
-                k: (v if k in ("met", "lat_hist")
+                k: (v if k in ("met", "lat_hist", "row_steps")
                     else lax.with_sharding_constraint(v, rep))
                 for k, v in out.items()
             }
         cr2, summary = admission(cr, g, out)
+        summary["row_steps"] = out["row_steps"]
         if mesh is not None:
             # pin the carry's output shardings to replicated — the
             # compiled program's carry feeds straight back in next
@@ -887,6 +892,10 @@ class _CampaignSession:
             next_id0 = 0
             self.sims = 0
             self.g_start = 0
+        # row-step counters of the generations this session runs (like
+        # the wall split, a resumed campaign counts only its own)
+        self.row_steps_paid = 0
+        self.row_steps_live = 0
 
         carry = dict(
             c=_fill_store(
@@ -996,14 +1005,20 @@ class _CampaignSession:
         return fleet
 
     def consume(self, g: int, s, fleet: dict, walls: dict,
-                carry=None) -> None:
+                carry=None) -> tuple[int, int]:
         """Fold generation ``g``'s admission summary into the host
         mirrors: curve/corpus-count/violation bookkeeping, the
-        generation telemetry record (``walls`` carries the driver's
-        wall split), the log line, and the per-generation checkpoint.
-        ``carry`` is the carry AS OF after ``g`` — the pipelined driver
-        passes it explicitly because its ``self.carry`` has already
-        speculated ahead."""
+        row-step counters, the generation telemetry record (``walls``
+        carries the driver's wall split), the log line, and the
+        per-generation checkpoint. ``carry`` is the carry AS OF after
+        ``g`` — the pipelined driver passes it explicitly because its
+        ``self.carry`` has already speculated ahead. Returns the
+        generation's (paid, live) row-steps, summed over shards."""
+        paid, live = (
+            int(x) for x in np.asarray(s["row_steps"]).reshape(-1, 2).sum(0)
+        )
+        self.row_steps_paid += paid
+        self.row_steps_live += live
         if bool(s["over"]):
             raise RuntimeError(
                 f"device violation store overflowed (viol_cap={self.vcap}) "
@@ -1031,6 +1046,7 @@ class _CampaignSession:
         })
         if self.checkpoint_path is not None:
             self.snapshot(g + 1, carry=carry).save(self.checkpoint_path)
+        return paid, live
 
     # ---- materialization ------------------------------------------------
     def _entry_name(self, gen, parent, bslot, seed):
@@ -1040,7 +1056,11 @@ class _CampaignSession:
             return self.seed_corpus[bslot].name
         return f"{self.space.plan.name}@{seed}"
 
-    def _materialize(self, carry_host):
+    def _materialize(self, carry):
+        with span("madsim.explore.materialize"):
+            return self._materialize_host(jax.device_get(carry))
+
+    def _materialize_host(self, carry_host):
         cn = {k: np.asarray(v) for k, v in carry_host["c"].items()}
         vn = {k: np.asarray(v) for k, v in carry_host["v"].items()}
         n_c = int(carry_host["count"])
@@ -1070,7 +1090,7 @@ class _CampaignSession:
         from .persist import CampaignState
 
         corpus, violations, gm = self._materialize(
-            jax.device_get(self.carry if carry is None else carry)
+            self.carry if carry is None else carry
         )
         return CampaignState(
             workload=self.wl.name, config_hash=self.cfg.hash(),
@@ -1100,9 +1120,7 @@ class _CampaignSession:
 
     def report(self, *, wall_dispatch, wall_sync, wall_compile, host_syncs,
                wall_queue=0.0, wall_idle=0.0) -> ExploreReport:
-        corpus, violations, gm = self._materialize(
-            jax.device_get(self.carry)
-        )
+        corpus, violations, gm = self._materialize(self.carry)
         return ExploreReport(
             workload=self.wl.name,
             config_hash=self.cfg.hash(),
@@ -1127,6 +1145,8 @@ class _CampaignSession:
             wall_gens=self.generations,
             wall_queue_s=wall_queue,
             wall_idle_s=wall_idle,
+            row_steps_paid=self.row_steps_paid,
+            row_steps_live=self.row_steps_live,
         )
 
 
@@ -1209,19 +1229,21 @@ def run_device(
     campaigns (the ``engine.search`` rule) and every later campaign
     runs compile-free.
     """
-    sess = _CampaignSession(
-        wl, cfg, space, invariant=invariant, generations=generations,
-        batch=batch, root_seed=root_seed, max_steps=max_steps,
-        cov_words=cov_words, layout=layout, require_halt=require_halt,
-        seed_corpus=seed_corpus, select_top=select_top,
-        max_corpus=max_corpus, max_ops=max_ops,
-        inherit_seed_p=inherit_seed_p, log=log, cov_hitcount=cov_hitcount,
-        telemetry=telemetry, resume=resume,
-        checkpoint_path=checkpoint_path, latency=latency, metrics=metrics,
-        mesh=mesh, viol_cap=viol_cap, pool_index=pool_index,
-        history_check=history_check, causal=causal,
-    )
-    sess.start("device")
+    with span("madsim.explore.start"):
+        sess = _CampaignSession(
+            wl, cfg, space, invariant=invariant, generations=generations,
+            batch=batch, root_seed=root_seed, max_steps=max_steps,
+            cov_words=cov_words, layout=layout, require_halt=require_halt,
+            seed_corpus=seed_corpus, select_top=select_top,
+            max_corpus=max_corpus, max_ops=max_ops,
+            inherit_seed_p=inherit_seed_p, log=log,
+            cov_hitcount=cov_hitcount, telemetry=telemetry, resume=resume,
+            checkpoint_path=checkpoint_path, latency=latency,
+            metrics=metrics, mesh=mesh, viol_cap=viol_cap,
+            pool_index=pool_index, history_check=history_check,
+            causal=causal,
+        )
+        sess.start("device")
 
     wall_dispatch = 0.0
     wall_sync = 0.0
@@ -1229,36 +1251,41 @@ def run_device(
     host_syncs = 0
 
     for g in range(sess.g_start, sess.g_start + generations):
-        t0 = _time.monotonic()  # lint: allow(wall-clock)
-        breed = g > 0 and sess.count > 0
-        runner = sess.runner(breed)
-        sess.carry, summary, extras = runner(
-            sess.carry, jnp.uint32(g), sess.rk0, sess.rk1
-        )
-        jax.block_until_ready(summary)
-        t1 = _time.monotonic()  # lint: allow(wall-clock)
-        # trace/lower/compile share of this generation (0.0 on a warm
-        # program cache — the certified steady state), split out of
-        # dispatch so warm-vs-cold comparisons compare like with like
-        compile_wall = runner.last_build_s
-        # THE host sync: admission summary + banner counters only —
-        # per-seed state stays on device
-        s = jax.device_get(summary)
-        host_syncs += 1
-        fleet = sess.fleet(extras)
-        t2 = _time.monotonic()  # lint: allow(wall-clock)
-        wall_dispatch += (t1 - t0) - compile_wall
-        wall_sync += t2 - t1
-        wall_compile += compile_wall
-        sess.consume(g, s, fleet, {
-            "dispatch_wall_s": round((t1 - t0) - compile_wall, 3),
-            "compile_wall_s": round(compile_wall, 3),
-            "sync_wall_s": round(t2 - t1, 3),
-            # the pipeline wall split, zero by construction on the
-            # blocking schedule (the driver never enqueues ahead)
-            "queue_wall_s": 0.0,
-            "idle_wall_s": 0.0,
-        })
+        with span("madsim.explore.generation", g=g):
+            t0 = _time.monotonic()  # lint: allow(wall-clock)
+            breed = g > 0 and sess.count > 0
+            runner = sess.runner(breed)
+            sess.carry, summary, extras = runner(
+                sess.carry, jnp.uint32(g), sess.rk0, sess.rk1
+            )
+            jax.block_until_ready(summary)
+            t1 = _time.monotonic()  # lint: allow(wall-clock)
+            # trace/lower/compile share of this generation (0.0 on a
+            # warm program cache — the certified steady state), split
+            # out of dispatch so warm-vs-cold comparisons compare like
+            # with like
+            compile_wall = runner.last_build_s
+            with span("madsim.explore.sync") as sp:
+                # THE host sync: admission summary + banner counters
+                # only — per-seed state stays on device
+                s = jax.device_get(summary)
+                host_syncs += 1
+                fleet = sess.fleet(extras)
+                t2 = _time.monotonic()  # lint: allow(wall-clock)
+                wall_dispatch += (t1 - t0) - compile_wall
+                wall_sync += t2 - t1
+                wall_compile += compile_wall
+                paid, live = sess.consume(g, s, fleet, {
+                    "dispatch_wall_s": round((t1 - t0) - compile_wall, 3),
+                    "compile_wall_s": round(compile_wall, 3),
+                    "sync_wall_s": round(t2 - t1, 3),
+                    # the pipeline wall split, zero by construction on
+                    # the blocking schedule (the driver never enqueues
+                    # ahead)
+                    "queue_wall_s": 0.0,
+                    "idle_wall_s": 0.0,
+                })
+                sp.set_metadata(row_steps_paid=paid, row_steps_live=live)
 
     sess.emit({
         "event": "campaign_end", "generations": sess.g_start + generations,

@@ -129,6 +129,12 @@ class ExploreReport:
     # it. On the pipelined driver wall_dispatch_s == queue + idle.
     wall_queue_s: float = 0.0
     wall_idle_s: float = 0.0
+    # the sweeps' row-steps over the generations this run executed
+    # (engine.SearchReport): rows x loop iterations paid, and those
+    # whose row had not yet halted — live / paid is the lockstep
+    # efficiency. The device drivers sum them over a mesh's shards.
+    row_steps_paid: int = 0
+    row_steps_live: int = 0
 
     @property
     def coverage_bits(self) -> int:
@@ -469,6 +475,7 @@ def run(
     wall_dispatch = 0.0
     wall_host = 0.0
     wall_compile = 0.0
+    row_steps_paid = row_steps_live = 0
     for g in range(g_start, g_start + generations):
         t_gen = _time.monotonic()  # lint: allow(wall-clock)
         k0s, k1s = _derive_keys(root_seed, g, batch)
@@ -565,6 +572,8 @@ def run(
         compile_wall = report.build_wall_s
         dispatch_wall = (t_after - t_disp) - compile_wall
         sims += batch
+        row_steps_paid += report.row_steps_paid
+        row_steps_live += report.row_steps_live
         failing = ~report.ok & ~report.overflowed
         # overflowed seeds are quarantined from guidance too: their
         # trajectories dropped events, so their bitmaps are artifacts
@@ -671,4 +680,6 @@ def run(
         wall_host_s=wall_host,
         wall_compile_s=wall_compile,
         wall_gens=generations,
+        row_steps_paid=row_steps_paid,
+        row_steps_live=row_steps_live,
     )

@@ -53,6 +53,7 @@ import jax.numpy as jnp
 
 from ..explore.device import _CampaignSession
 from ..explore.driver import ExploreReport
+from ..obs.prof import span
 
 __all__ = ["run_pipelined"]
 
@@ -106,20 +107,22 @@ def run_pipelined(
     """
     if depth < 1:
         raise ValueError("need pipeline depth >= 1")
-    sess = _CampaignSession(
-        wl, cfg, space, invariant=invariant, generations=generations,
-        batch=batch, root_seed=root_seed, max_steps=max_steps,
-        cov_words=cov_words, layout=layout, require_halt=require_halt,
-        seed_corpus=seed_corpus, select_top=select_top,
-        max_corpus=max_corpus, max_ops=max_ops,
-        inherit_seed_p=inherit_seed_p, log=log, cov_hitcount=cov_hitcount,
-        telemetry=telemetry, resume=resume,
-        checkpoint_path=checkpoint_path, latency=latency, metrics=metrics,
-        mesh=mesh, viol_cap=viol_cap, pool_index=pool_index,
-        history_check=history_check, causal=causal,
-    )
-    sess.log_label = "pipelined"
-    sess.start("device-pipelined", pipeline_depth=depth)
+    with span("madsim.explore.start"):
+        sess = _CampaignSession(
+            wl, cfg, space, invariant=invariant, generations=generations,
+            batch=batch, root_seed=root_seed, max_steps=max_steps,
+            cov_words=cov_words, layout=layout, require_halt=require_halt,
+            seed_corpus=seed_corpus, select_top=select_top,
+            max_corpus=max_corpus, max_ops=max_ops,
+            inherit_seed_p=inherit_seed_p, log=log,
+            cov_hitcount=cov_hitcount, telemetry=telemetry, resume=resume,
+            checkpoint_path=checkpoint_path, latency=latency,
+            metrics=metrics, mesh=mesh, viol_cap=viol_cap,
+            pool_index=pool_index, history_check=history_check,
+            causal=causal,
+        )
+        sess.log_label = "pipelined"
+        sess.start("device-pipelined", pipeline_depth=depth)
 
     wall_queue = 0.0
     wall_idle = 0.0
@@ -180,25 +183,27 @@ def run_pipelined(
         t0 = _time.monotonic()  # lint: allow(wall-clock)
         jax.block_until_ready(item["summary"])  # THE consume-point sync
         t1 = _time.monotonic()  # lint: allow(wall-clock)
-        s = jax.device_get(item["summary"])
-        host_syncs += 1
-        fleet = sess.fleet(item["extras"])
-        t2 = _time.monotonic()  # lint: allow(wall-clock)
-        idle = t1 - t0
-        sync = t2 - t1
-        wall_idle += idle
-        wall_sync += sync
-        # consume against generation g's OWN carry: sess.carry has
-        # already speculated ahead, and the per-generation checkpoint
-        # must snapshot the campaign as of g (it also overlaps the
-        # device executing g+1 — the whole point of the schedule)
-        sess.consume(g, s, fleet, {
-            "dispatch_wall_s": round(item["queue_s"] + idle, 3),
-            "compile_wall_s": round(item["build_s"], 3),
-            "sync_wall_s": round(sync, 3),
-            "queue_wall_s": round(item["queue_s"], 3),
-            "idle_wall_s": round(idle, 3),
-        }, carry=item["carry"])
+        with span("madsim.explore.sync") as sp:
+            s = jax.device_get(item["summary"])
+            host_syncs += 1
+            fleet = sess.fleet(item["extras"])
+            t2 = _time.monotonic()  # lint: allow(wall-clock)
+            idle = t1 - t0
+            sync = t2 - t1
+            wall_idle += idle
+            wall_sync += sync
+            # consume against generation g's OWN carry: sess.carry has
+            # already speculated ahead, and the per-generation checkpoint
+            # must snapshot the campaign as of g (it also overlaps the
+            # device executing g+1 — the whole point of the schedule)
+            paid, live = sess.consume(g, s, fleet, {
+                "dispatch_wall_s": round(item["queue_s"] + idle, 3),
+                "compile_wall_s": round(item["build_s"], 3),
+                "sync_wall_s": round(sync, 3),
+                "queue_wall_s": round(item["queue_s"], 3),
+                "idle_wall_s": round(idle, 3),
+            }, carry=item["carry"])
+            sp.set_metadata(row_steps_paid=paid, row_steps_live=live)
 
     wall_dispatch = wall_queue + wall_idle
     sess.emit({

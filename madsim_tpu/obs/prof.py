@@ -28,6 +28,48 @@ measured quantity:
   array summed (plus the backend allocator's ``memory_stats`` where
   the platform provides one — TPU/GPU HBM; CPU returns only the
   live-array view).
+* :func:`span` — a named host span on the profiler's own clock, for an
+  operator who runs ``jax.profiler.trace`` (or ``start_trace``) around
+  a sweep or a campaign: the spans land in the host plane of the same
+  trace as the device planes, so an idle stretch of the chip is named
+  by what the program was doing on the host. With no profiler running
+  a span costs one TraceMe check.
+
+The program's spans, nested as they nest in the trace, with what each
+covers and the metadata it carries:
+
+* ``madsim.search`` — one ``engine.search_seeds`` call; at exit it
+  carries ``d2h_bytes``, ``row_steps_paid`` and ``row_steps_live``,
+  the call's ``SearchReport`` counters.
+
+  * ``madsim.search.plan`` — ``plan.compile_batch`` and the plan-row
+    checks.
+  * ``madsim.search.init`` — the (init, run) program lookup and
+    ``init(seeds[, rows])``.
+  * ``madsim.search.run`` — dispatch of the runner up to
+    ``block_until_ready``.
+  * ``madsim.search.fetch`` — the device→host copies and the scatter
+    into seed order (the lockstep path's screen verdict words too).
+  * ``madsim.search.check`` — invariant, screen verdicts, the
+    flagged-history gather, ``history_invariant`` and the report.
+
+* ``madsim.explore.start`` — campaign session construction through
+  ``start()``: validation, program-cache lookup, carry init.
+* ``madsim.explore.generation`` — one generation of
+  ``explore.run_device``; carries ``g``.
+
+  * ``madsim.explore.sync`` — the summary ``device_get``, the fleet
+    fold and ``consume``; at exit it carries the generation's
+    ``row_steps_paid`` and ``row_steps_live``.
+
+* ``madsim.explore.materialize`` — the corpus and violations turned
+  into host objects for the report or a checkpoint, its
+  ``device_get`` included.
+
+The pipelined driver (``farm.run_pipelined``) opens ``start``,
+``sync`` (with no generation parent: it consumes behind its
+dispatches) and ``materialize``; a per-generation checkpoint nests
+``materialize`` inside ``sync``.
 
 Everything here is host-side bookkeeping over wall clocks and compiled
 artifacts; nothing enters traced code (the lint matrix pins this — see
@@ -53,7 +95,16 @@ __all__ = [
     "enable",
     "profiled",
     "program_cost",
+    "span",
 ]
+
+
+def span(name: str, **meta) -> jax.profiler.TraceAnnotation:
+    """A host span ``name`` (``madsim.*``, module docstring) on the
+    profiler's clock, carrying ``meta`` as its metadata. Use it as
+    ``with span("madsim.search") as sp: ...; sp.set_metadata(k=v)`` to
+    attach counters known only at exit."""
+    return jax.profiler.TraceAnnotation(name, **meta)
 
 
 def digest(key) -> str:

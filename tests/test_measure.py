@@ -13,7 +13,6 @@ from madsim_tpu.engine.measure import (
     make_repeat_program,
     measure_latency,
     measure_throughput,
-    null_dispatch_stats,
 )
 from madsim_tpu.models import make_microbench, make_raft
 
@@ -72,12 +71,6 @@ def test_measure_latency_reports_quotable_cell():
     assert rec["sim_s_per_s"] > 0
     assert len(rec["dispatch_walls_s"]) == 2
     assert rec["repeats"] >= 32
-
-
-def test_null_dispatch_stats_shape():
-    s = null_dispatch_stats(n=5)
-    assert s["n"] == 5
-    assert 0 <= s["min_ms"] <= s["median_ms"] <= s["max_ms"]
 
 
 def test_bench_configs_mirror_bench_specs():

@@ -226,7 +226,10 @@ def _compiled_run(wl: Workload, cfg: EngineConfig, max_steps: int, layout,
         # make_run_compacted jits internally per growth stage (its
         # build wall stays inside dispatch — documented limitation)
         _RUN_CACHE[key] = (
-            init,
+            # compiled once per batch shape and plan-row structure
+            # (jit_init on the device trace): run eagerly, the vmapped
+            # init dispatches each of its ~130 primitives on its own
+            AotProgram("engine.search.init", key, init),
             # the lockstep program returns (final state, row steps)
             run if compact
             else AotProgram("engine.search.run", key, run.counted),
@@ -255,12 +258,12 @@ class SearchReport:
     # fault-plan hash when the sweep ran under a chaos plan: the repro
     # key is then (seed, config, plan) — all three printed in the banner
     plan_hash: str = ""
-    # wall this call spent building (trace + lower + compile) its run
-    # program — nonzero only on a cold compiled-run cache entry or a
-    # signature change. Callers timing the dispatch subtract this to
-    # get pure execution wall (the explore drivers' compile_wall_s
-    # split); 0.0 on the compact path, whose staged internal jits are
-    # not separable.
+    # wall this call spent building (trace + lower + compile) its init
+    # and run programs — nonzero only on a cold compiled-run cache entry
+    # or a signature change. Callers timing the dispatch subtract this
+    # to get pure execution wall (the explore drivers' compile_wall_s
+    # split); on the compact path only the init's share, as the staged
+    # internal jits of its runner are not separable.
     build_wall_s: float = 0.0
     # per-seed coverage bitmaps, (S, cov_words) uint32 — None unless the
     # sweep ran with cov_words > 0 (madsim_tpu.explore)
@@ -820,7 +823,9 @@ def search_seeds(
                 traces=view["trace"],
                 steps=int(np.asarray(view["step"]).max()),
                 plan_hash=plan_hash or "",
-                build_wall_s=getattr(run, "last_build_s", 0.0),
+                build_wall_s=(
+                    init.last_build_s + getattr(run, "last_build_s", 0.0)
+                ),
                 cov=np.asarray(view["cov"]) if cov_words else None,
                 halt_times=np.asarray(view["halt_time"]),
                 met=np.asarray(view["met"]) if metrics else None,

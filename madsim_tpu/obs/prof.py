@@ -44,8 +44,8 @@ covers and the metadata it carries:
 
   * ``madsim.search.plan`` — ``plan.compile_batch`` and the plan-row
     checks.
-  * ``madsim.search.init`` — the (init, run) program lookup and
-    ``init(seeds[, rows])``.
+  * ``madsim.search.init`` — the (init, run) program lookup and the
+    dispatch of the compiled ``init(seeds[, rows])`` (``jit_init``).
   * ``madsim.search.run`` — dispatch of the runner up to
     ``block_until_ready``.
   * ``madsim.search.fetch`` — the device→host copies and the scatter

@@ -2,11 +2,15 @@
 with per-seed repro — the engine-scale multi-seed runner
 (builder.rs:110-148 analog, BASELINE.md config 5)."""
 
+import dataclasses
+
 import numpy as np
 import pytest
 
-from madsim_tpu.engine import EngineConfig, search_seeds
+from madsim_tpu.chaos import CrashStorm, FaultPlan
+from madsim_tpu.engine import EngineConfig, make_init, search_seeds
 from madsim_tpu.models import make_kvchaos, make_microbench, make_raft
+from madsim_tpu.obs import prof
 
 
 def test_healthy_workload_has_no_violations():
@@ -99,6 +103,73 @@ def test_search_reuses_compiled_run():
     search_seeds(wl, cfg, inv, n_seeds=32, max_steps=200)
     search_seeds(wl, cfg, inv, n_seeds=32, max_steps=200)
     assert len(search._RUN_CACHE) == before + 1
+
+
+def _cached_init(wl):
+    """The init program search_seeds cached for workload ``wl``."""
+    from madsim_tpu.engine import search
+
+    (init,) = [e[0] for e in search._RUN_CACHE.values() if e[2] is wl]
+    return init
+
+
+@pytest.mark.parametrize("case", ["raft", "kv_plan", "kv_plan_rows_no_node"])
+def test_cached_init_equals_eager_init(case):
+    """The compiled init a sweep builds its batch with writes the same
+    SimState, field for field and dtype for dtype, as the eager
+    ``make_init`` — with no plan, with plan rows, and with pre-army plan
+    rows whose ``node`` column is None (another pytree structure)."""
+    seeds = np.arange(1, 2**40, 2**34, dtype=np.uint64)[:24]
+    inv = lambda v: np.ones(len(seeds), bool)  # noqa: E731
+    if case == "raft":
+        wl, cfg, rows, kw = make_raft(), EngineConfig(pool_size=48), None, {}
+    else:
+        wl = make_kvchaos(writes=5, record=True, bug=True)
+        cfg = EngineConfig(pool_size=40, loss_p=0.02)
+        plan = FaultPlan((CrashStorm(targets=(1, 2, 3, 4), n=2),), name="kv")
+        rows = plan.compile_batch(seeds, wl=wl)
+        assert rows.node is not None
+        if case == "kv_plan":
+            kw = dict(plan=plan)
+        else:
+            rows = dataclasses.replace(rows, node=None)
+            kw = dict(plan_rows=rows, dup_rows=plan.uses_dup())
+    search_seeds(wl, cfg, inv, seeds=seeds, max_steps=40, **kw)
+    init = _cached_init(wl)
+    assert isinstance(init, prof.AotProgram) and init.builds == 1
+    eager = make_init(wl, cfg, plan_slots=0 if rows is None
+                      else rows.time.shape[1])
+    args = (seeds,) if rows is None else (seeds, rows)
+    got, want = init(*args), eager(*args)
+    assert init.builds == 1  # the search's own build served this call
+    for f in dataclasses.fields(want):
+        g, w = np.asarray(getattr(got, f.name)), np.asarray(getattr(want, f.name))
+        assert g.dtype == w.dtype and g.shape == w.shape, f.name
+        np.testing.assert_array_equal(g, w, err_msg=f.name)
+
+
+def test_cached_init_builds_once_per_batch_shape():
+    """Repeated sweeps at one batch size build the init program once, a
+    second size builds it once more (the AotProgram counter and the
+    profiler's retrace table agree), and the program is not named like
+    the compacted runner (``jit_compiled``), which the device trace's
+    runner metrics select by name."""
+    wl, cfg = make_raft(), EngineConfig(pool_size=48)
+    inv = lambda v: v["halted"]  # noqa: E731
+    with prof.profiled() as p:
+        for base in (0, 100, 200):
+            search_seeds(wl, cfg, inv, n_seeds=16, seed_base=base,
+                         max_steps=40, compact=True, require_halt=False)
+        init = _cached_init(wl)
+        assert init.builds == 1
+        assert list(p.retraces("engine.search.init").values()) == [1]
+        search_seeds(wl, cfg, inv, n_seeds=32, max_steps=40, compact=True,
+                     require_halt=False)
+        assert init.builds == 2
+        assert list(p.retraces("engine.search.init").values()) == [2]
+    assert init.name == "engine.search.init"
+    for exe in init._exes.values():
+        assert exe.as_text().split()[1].rstrip(",") == "jit_init"
 
 
 @pytest.mark.slow

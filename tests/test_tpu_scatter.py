@@ -67,6 +67,22 @@ def test_engine_runner(name, layout):
     assert batched_bool_scatters(jaxpr.jaxpr) == []
 
 
+@pytest.mark.parametrize("name", sorted(BENCH_SPECS) + ["kvchaos_plan"])
+def test_sweep_init(name):
+    # search_seeds runs the batch's init as one compiled program
+    seeds = np.arange(8, dtype=np.uint64)
+    if name == "kvchaos_plan":
+        wl, cfg = kvchaos()
+        plan = FaultPlan((CrashStorm(targets=(1, 2, 3, 4), n=2),), name="kv")
+        args = (seeds, plan.compile_batch(seeds, wl=wl))
+        init = make_init(wl, cfg, plan_slots=plan.slots)
+    else:
+        factory, cfg_kw, _, _ = BENCH_SPECS[name]
+        args, init = (seeds,), make_init(factory(), EngineConfig(**cfg_kw))
+    jaxpr = jax.make_jaxpr(init)(*args)
+    assert batched_bool_scatters(jaxpr.jaxpr) == []
+
+
 def test_history_fold():
     wl, cfg = kvchaos()
     st = jax.eval_shape(make_init(wl, cfg), np.arange(8, dtype=np.uint64))

@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import numpy as np
 
+from harness import BENCH
 from reference.history import clean
 from reference.oracle import Reference
 
@@ -27,15 +28,17 @@ def reference_answer(ref: Reference, config: dict, seed: int, rows) -> dict:
             "halt_t": r["halt_time"] if r["halted"] else 0,
             "overflowed": over,
             "violating": (not over and bool(checks)
-                          and not clean(r["hist"], checks))}
+                          and not clean(r["hist"], checks, ref.bench))}
 
 
-def compare(config: dict, schedules: list, control_quantum: int = 1):
+def compare(config: dict, schedules: list, control_quantum: int = 1,
+            bench: str = BENCH):
     """(mismatched, details) over ``schedules``: dicts with ``seed``,
     ``rows`` (plan rows or None) and the program's answers under the
-    keys of ``config['compared']`` plus ``overflowed``."""
-    ref = Reference(config)
-    ctl = Reference(config, time_quantum=control_quantum) \
+    keys of ``config['compared']`` plus ``overflowed``. ``bench`` is the
+    benchmark directory whose ``reference/`` is compared with."""
+    ref = Reference(config, bench=bench)
+    ctl = Reference(config, time_quantum=control_quantum, bench=bench) \
         if control_quantum > 1 else None
     keys = list(config["compared"]) + ["overflowed"]
     bad, details = 0, []

@@ -1,15 +1,23 @@
-"""Plain per-schedule history checks for the kvchaos deployment.
+"""Plain per-schedule history checks, each found by name.
 
 One history is a list of rows [op, key, arg, client, ok] in append
-order. A successful read must return at least the newest version whose
-write had completed before the read was invoked: by any client
-(``stale_reads``) or by the reading client (``read_your_writes``). The
-r-th read response of a (client, key) pairs with its r-th read invoke; a
-response with no invoke at all is judged at its own position, and one
-whose paired invoke comes after it constrains nothing.
+order. A check is ``checks/<name>.py`` with ``violated(hist, **args)``;
+a configuration's ``reference.checks`` names each, as a name or as
+``{"name": ..., "args": {...}}``.
+
+The floor checks shared here: a successful read must return at least
+the newest version whose write had completed before the read was
+invoked: by any client (``stale_reads``) or by the reading client
+(``read_your_writes``). The r-th read response of a (client, key) pairs
+with its r-th read invoke; a response with no invoke at all is judged at
+its own position, and one whose paired invoke comes after it constrains
+nothing.
 """
 
 from __future__ import annotations
+
+from harness import BENCH
+from reference import named
 
 OP_WRITE, OP_READ = 1, 2
 OK_PENDING, OK_OK = -1, 1
@@ -57,7 +65,9 @@ def _later_invoke(hist, pos, client, key, rank):
     return False
 
 
-def clean(hist, checks) -> bool:
-    """True when every named check passes on this history."""
-    return not any(_floor_violated(hist, own_writes_only=(c == "read_your_writes"))
-                   for c in checks)
+def clean(hist, checks, bench: str = BENCH) -> bool:
+    """True when every named check passes on this history; BenchError
+    for a check with no file."""
+    found = [(named.load("checks", name, bench).violated, args)
+             for name, args in map(named.entry, checks)]
+    return not any(violated(hist, **args) for violated, args in found)

@@ -1,17 +1,16 @@
 // The benchmark's plain reference: a single-seed C++ simulator of the
-// configurations the benchmark runs (raft election, kvchaos), kept with
-// the benchmark so that no program change can move it.
+// configurations the benchmark runs, kept with the benchmark so that no
+// program change can move it. This file is the engine: the step loop,
+// the plan channel, the history and the control; each workload is a file
+// of its own under workloads/, found by the name it registers (sim.h).
 //
-// Copied from native/oracle.cpp (PR 21 tree), trimmed to the two
-// workloads, and extended with what the kvchaos deployment needs and
-// the program's own oracle lacks:
+// Copied from native/oracle.cpp and extended with what the benchmark's
+// deployments need and the program's own oracle lacks:
 //   * the fault-plan channel: plan rows pre-seeded into pool slots
 //     [n_nodes, n_nodes + P) at init (engine/core.py make_init);
 //   * operation-history records (Emits.record): row [op, key, arg,
 //     client, ok] stamped with the handling node and the dispatch clock,
 //     appended at hist_count, counted in hist_drop once the buffer is full;
-//   * the kvchaos record mode (READ / READRESP probes) and the planted
-//     lost-write bug (models/kvchaos.py record=True, bug=True);
 //   * the control: `time_quantum` > 1 holds every scheduled event time
 //     at that resolution (rounded down), the coarser clock a narrower
 //     time representation would tempt. 1 = exact.
@@ -19,163 +18,18 @@
 
 #include <cstdint>
 #include <cstring>
+#include <map>
+#include <string>
 #include <vector>
 
+#include "sim.h"
+
+namespace benchref {
 namespace {
-
-// ---- threefry2x32 (engine/rng.py threefry2x32) --------------------------
-constexpr uint32_t kParity = 0x1BD11BDA;
-constexpr int kRot[8] = {13, 15, 26, 6, 17, 29, 16, 24};
-
-inline uint32_t rotl32(uint32_t x, int r) {
-  return (x << r) | (x >> (32 - r));
-}
-
-void threefry2x32(uint32_t k0, uint32_t k1, uint32_t x0, uint32_t x1,
-                  uint32_t* o0, uint32_t* o1) {
-  uint32_t ks[3] = {k0, k1, static_cast<uint32_t>(k0 ^ k1 ^ kParity)};
-  x0 += ks[0];
-  x1 += ks[1];
-  for (int chunk = 0; chunk < 5; chunk++) {
-    const int* rots = (chunk % 2 == 0) ? kRot : kRot + 4;
-    for (int j = 0; j < 4; j++) {
-      x0 += x1;
-      x1 = rotl32(x1, rots[j]);
-      x1 ^= x0;
-    }
-    x0 += ks[(chunk + 1) % 3];
-    x1 += ks[(chunk + 2) % 3] + static_cast<uint32_t>(chunk + 1);
-  }
-  *o0 = x0;
-  *o1 = x1;
-}
-
-// ---- draw discipline (engine/rng.py Draw) -------------------------------
-constexpr uint32_t kPurposePollCost = 0;
-constexpr uint32_t kPurposeClogJitter = 1;
-constexpr uint32_t kPurposeLatency = 8;
-constexpr uint32_t kPurposeLoss = 64;
-constexpr uint32_t kPurposeUser = 128;
-
-struct Draw {
-  uint32_t k0, k1, step;
-  uint32_t bits(uint32_t purpose) const {
-    uint32_t a, b;
-    threefry2x32(k0, k1, step, purpose, &a, &b);
-    return a;
-  }
-  // both lanes of one block (engine Draw.bits2): the per-emit latency
-  // (lane 0) and loss (lane 1) draws share the PURPOSE_LATENCY+slot
-  // counter
-  void bits2(uint32_t purpose, uint32_t* a, uint32_t* b) const {
-    threefry2x32(k0, k1, step, purpose, a, b);
-  }
-  // uniform int64 in [lo, hi): modulo reduction, same bias as the spec
-  int64_t uniform_int(int64_t lo, int64_t hi, uint32_t purpose) const {
-    uint32_t span = static_cast<uint32_t>(hi - lo);
-    if (span == 0) span = 1;
-    return lo + static_cast<int64_t>(bits(purpose) % span);
-  }
-  // two uniform int64s from ONE block (engine Draw.uniform_int2):
-  // lane 0 -> [lo_a, hi_a), lane 1 -> [lo_b, hi_b)
-  void uniform_int2(int64_t lo_a, int64_t hi_a, int64_t lo_b, int64_t hi_b,
-                    uint32_t purpose, int64_t* out_a, int64_t* out_b) const {
-    uint32_t a, b;
-    bits2(purpose, &a, &b);
-    uint32_t span_a = static_cast<uint32_t>(hi_a - lo_a);
-    if (span_a == 0) span_a = 1;
-    uint32_t span_b = static_cast<uint32_t>(hi_b - lo_b);
-    if (span_b == 0) span_b = 1;
-    *out_a = lo_a + static_cast<int64_t>(a % span_a);
-    *out_b = lo_b + static_cast<int64_t>(b % span_b);
-  }
-  uint32_t user(uint32_t purpose) const { return bits(kPurposeUser + purpose); }
-  int64_t user_int(int64_t lo, int64_t hi, uint32_t purpose) const {
-    return uniform_int(lo, hi, kPurposeUser + purpose);
-  }
-};
-
-// ---- event kinds (engine/core.py) ---------------------------------------
-constexpr int32_t KIND_KILL = 0;
-constexpr int32_t KIND_RESTART = 1;
-constexpr int32_t KIND_CLOG = 2;
-constexpr int32_t KIND_UNCLOG = 3;
-constexpr int32_t KIND_CLOG_NODE = 4;
-constexpr int32_t KIND_UNCLOG_NODE = 5;
-constexpr int32_t KIND_HALT = 6;
-constexpr int32_t KIND_NOP = 7;
-constexpr int32_t KIND_PAUSE = 8;
-constexpr int32_t KIND_RESUME = 9;
-constexpr int32_t FIRST_USER_KIND = 10;
 
 constexpr int64_t kInf = int64_t{1} << 62;
 constexpr uint64_t kTracePrime = 0x100000001B3ull;
 constexpr uint64_t kTraceMix = 0x9E3779B97F4A7C15ull;
-
-struct Config {  // EngineConfig
-  int64_t pool_size;
-  int64_t lat_min_ns, lat_max_ns;
-  uint64_t loss_u32;  // in [0, 2^32]; 2^32 = always drop (loss_p=1.0)
-  int64_t proc_min_ns, proc_max_ns;
-  int64_t clog_backoff_min_ns, clog_backoff_max_ns;
-  int64_t time_limit_ns;  // 0 = unlimited
-};
-
-// payload arena width cap (Workload.payload_words; engine events carry
-// W int32 words — engine/core.py ev_pay)
-constexpr int32_t kMaxPay = 4;
-
-struct Event {
-  int64_t time;
-  bool valid;
-  int32_t kind, node, src, epoch, retry;
-  int32_t args[4];
-  int32_t pay[kMaxPay] = {0, 0, 0, 0};
-};
-
-// one emit row (Emits)
-struct Emit {
-  bool valid = false;
-  bool send = false;
-  int32_t kind = 0, dst = 0;
-  int64_t delay = 0;
-  int32_t args[4] = {0, 0, 0, 0};
-  int32_t pay[kMaxPay] = {0, 0, 0, 0};
-};
-
-// one history record (Emits.record): when, [op, key, arg, ok]
-struct Rec {
-  bool when;
-  int32_t op, key, arg, ok;
-};
-
-struct Effects {
-  std::vector<Emit> emits;
-  std::vector<Rec> recs;
-  int32_t kill = -1, restart = -1;
-  int32_t clog_a = -1, clog_b = -1, clog_set = -1;
-  int32_t pause_node = -1, pause_set = -1;
-  bool halt = false;
-};
-
-struct Ctx {
-  int64_t now;
-  int32_t node;
-  const int32_t* state;  // (U,)
-  const int32_t* args;   // (4,)
-  int32_t src;
-  Draw draw;
-  const int32_t* pay = nullptr;  // (W,) the event's payload words
-};
-
-// Workload interface: mirrors engine Workload. new_state is written by
-// the handler; the engine applies it only when the event dispatches.
-struct Workload {
-  int32_t n_nodes, state_width, n_handlers, max_emits;
-  // handler(h, ctx, new_state_out, effects_out)
-  void (*handler)(int32_t h, const Ctx&, int32_t*, Effects*);
-  int32_t payload_words = 0;  // engine Workload.payload_words
-};
 
 // ---- the step loop (engine/core.py make_step) ---------------------------
 struct Sim {
@@ -455,390 +309,53 @@ struct Sim {
   }
 };
 
-// ---- workloads (mirrors of models/*.py) ---------------------------------
-
-inline Emit mk_send(int32_t dst, int32_t kind, int32_t a0 = 0, int32_t a1 = 0,
-                    bool when = true) {
-  Emit e;
-  e.valid = when;
-  e.send = true;
-  e.kind = kind;
-  e.dst = dst;
-  e.args[0] = a0;
-  e.args[1] = a1;
-  return e;
-}
-
-inline Emit mk_after(int64_t delay, int32_t kind, int32_t dst, int32_t a0 = 0,
-                     bool when = true) {
-  Emit e;
-  e.valid = when;
-  e.send = false;
-  e.kind = kind;
-  e.dst = dst;
-  e.delay = delay;
-  e.args[0] = a0;
-  return e;
-}
-
-// raft election (models/raft.py)
-struct RaftParams {
-  int32_t n_nodes;
-  int64_t timeout_min, timeout_max;
+struct Entry {
+  SetParams set_params;
+  MakeWorkload make;
 };
-RaftParams g_raft{5, 150000000, 300000000};
 
-void raft_handler(int32_t h, const Ctx& ctx, int32_t* ns, Effects* eff) {
-  const int32_t ROLE = 0, TERM = 1, VOTED = 2, VOTES = 3, TSEQ = 4;
-  const int32_t FOLLOWER = 0, CANDIDATE = 1, LEADER = 2;
-  const int32_t K_TIMEOUT = FIRST_USER_KIND + 1, K_REQVOTE = FIRST_USER_KIND + 2,
-                K_GRANT = FIRST_USER_KIND + 3, K_HEARTBEAT = FIRST_USER_KIND + 4;
-  const int32_t majority = g_raft.n_nodes / 2 + 1;
-  const int32_t N = g_raft.n_nodes;
-  auto arm = [&](int32_t new_seq, bool when) {
-    int64_t d = ctx.draw.user_int(g_raft.timeout_min, g_raft.timeout_max, 0);
-    eff->emits.push_back(mk_after(d, K_TIMEOUT, ctx.node, new_seq, when));
-  };
-  switch (h) {
-    case 0: {  // on_init
-      arm(1, true);
-      ns[TSEQ] = 1;
-      break;
-    }
-    case 1: {  // on_timeout
-      const int32_t* st = ctx.state;
-      bool fire = ctx.args[0] == st[TSEQ] && st[ROLE] != LEADER;
-      int32_t term = st[TERM] + 1;
-      if (fire) {
-        ns[ROLE] = CANDIDATE;
-        ns[TERM] = term;
-        ns[VOTED] = term;
-        ns[VOTES] = 1;
-        ns[TSEQ] = st[TSEQ] + 1;
-      }
-      for (int32_t p = 0; p < N; p++)
-        eff->emits.push_back(
-            mk_send(p, K_REQVOTE, term, ctx.node, fire && p != ctx.node));
-      arm(st[TSEQ] + 1, fire);
-      break;
-    }
-    case 2: {  // on_reqvote
-      const int32_t* st = ctx.state;
-      int32_t term = ctx.args[0], cand = ctx.args[1];
-      int32_t st1[8];
-      std::memcpy(st1, st, sizeof(int32_t) * 5);
-      bool newer = term > st[TERM];
-      if (newer) {
-        st1[TERM] = term;
-        st1[ROLE] = FOLLOWER;
-        st1[VOTES] = 0;
-      }
-      bool grant = term == st1[TERM] && st1[VOTED] < term;
-      std::memcpy(ns, st1, sizeof(int32_t) * 5);
-      if (grant) {
-        ns[VOTED] = term;
-        ns[TSEQ] = st1[TSEQ] + 1;
-      }
-      eff->emits.push_back(mk_send(cand, K_GRANT, term, 0, grant));
-      {
-        int64_t d = ctx.draw.user_int(g_raft.timeout_min, g_raft.timeout_max, 0);
-        eff->emits.push_back(
-            mk_after(d, K_TIMEOUT, ctx.node, st1[TSEQ] + 1, grant));
-      }
-      break;
-    }
-    case 3: {  // on_grant
-      const int32_t* st = ctx.state;
-      int32_t term = ctx.args[0];
-      bool counts = st[ROLE] == CANDIDATE && term == st[TERM];
-      int32_t votes = counts ? st[VOTES] + 1 : st[VOTES];
-      bool wins = counts && votes >= majority;
-      ns[VOTES] = votes;
-      if (wins) ns[ROLE] = LEADER;
-      for (int32_t p = 0; p < N; p++)
-        eff->emits.push_back(
-            mk_send(p, K_HEARTBEAT, term, 0, wins && p != ctx.node));
-      eff->emits.push_back(mk_after(0, KIND_HALT, 0, 0, wins));
-      break;
-    }
-    case 4: {  // on_heartbeat
-      const int32_t* st = ctx.state;
-      int32_t term = ctx.args[0];
-      bool accept = term >= st[TERM];
-      if (accept) {
-        ns[TERM] = term;
-        ns[ROLE] = FOLLOWER;
-        ns[TSEQ] = st[TSEQ] + 1;
-      }
-      arm(st[TSEQ] + 1, accept);
-      break;
-    }
-  }
-}
-
-// kvchaos (models/kvchaos.py): primary-backup KV store under a scheduled
-// replica kill/restart; payload mode carries two client-drawn value words
-// through WRITE/REPL messages (state_width 6, payload_words 2). Record
-// mode (record=1) adds the client's write/read history and the READ
-// probe; bug=1 plants the lost-write fault (on_join forgets the commit
-// point).
-struct KvParams {
-  int32_t writes, n_replicas;
-  int64_t retx_ns, client_retx_ns;
-  int32_t chaos, payload, record, bug;
-};
-KvParams g_kv{20, 4, 40000000, 100000000, 1, 0, 0, 0};
-
-// check/history.py op and status codes
-constexpr int32_t OP_WRITE = 1, OP_READ = 2;
-constexpr int32_t OK_PENDING = -1, OK_OK = 1;
-
-void kvchaos_handler(int32_t h, const Ctx& ctx, int32_t* ns, Effects* eff) {
-  const int32_t PRIMARY = 0;
-  const int32_t K_WRITE = FIRST_USER_KIND + 1, K_REPL = FIRST_USER_KIND + 2,
-                K_ACK = FIRST_USER_KIND + 3, K_COMMIT = FIRST_USER_KIND + 4,
-                K_RETX = FIRST_USER_KIND + 5, K_CRETX = FIRST_USER_KIND + 6,
-                K_FIN = FIRST_USER_KIND + 7, K_JOIN = FIRST_USER_KIND + 8,
-                K_JRETX = FIRST_USER_KIND + 9, K_READ = FIRST_USER_KIND + 10,
-                K_READRESP = FIRST_USER_KIND + 11;
-  const bool record = g_kv.record != 0;
-  auto rec = [&](int32_t op, int32_t arg, int32_t ok, bool when) {
-    if (record) eff->recs.push_back(Rec{when, op, 0, arg, ok});
-  };
-  const int32_t P_KILL_AT = 0, P_KILL_WHO = 1, P_REVIVE = 2;
-  const int32_t P_VAL0 = 8, P_VAL1 = 9;
-  const int32_t R = g_kv.n_replicas;
-  const int32_t client = R + 1;
-  const int32_t majority = R / 2 + 1;
-  const int32_t full_mask = (1 << R) - 1;
-  const bool payload = g_kv.payload != 0;
-  auto client_value = [&](int32_t* v0, int32_t* v1) {
-    *v0 = static_cast<int32_t>(ctx.draw.user(P_VAL0));
-    *v1 = static_cast<int32_t>(ctx.draw.user(P_VAL1));
-  };
-  auto send_pay = [&](Emit e, int32_t p0, int32_t p1) {
-    if (payload) {
-      e.pay[0] = p0;
-      e.pay[1] = p1;
-    }
-    eff->emits.push_back(e);
-  };
-  // slots 0..R-1: REPL sends gated per-replica on the ack mask
-  auto replicate = [&](int32_t seq, bool when, int32_t mask, int32_t p0,
-                       int32_t p1) {
-    for (int32_t idx = 0; idx < R; idx++)
-      send_pay(mk_send(idx + 1, K_REPL, seq, 0,
-                       when && (((mask >> idx) & 1) == 0)),
-               p0, p1);
-  };
-  auto maybe_halt = [&](int32_t committed, int32_t mask, int32_t fin) {
-    eff->emits.push_back(mk_after(
-        0, KIND_HALT, 0, 0,
-        committed >= g_kv.writes && mask == full_mask && fin > 0));
-  };
-  switch (h) {
-    case 0: {  // on_init
-      bool is_client = ctx.node == client;
-      bool is_replica = ctx.node >= 1 && ctx.node <= R;
-      int32_t v0 = 0, v1 = 0;
-      if (payload) client_value(&v0, &v1);
-      send_pay(mk_send(PRIMARY, K_WRITE, 1, 0, is_client), v0, v1);
-      rec(OP_WRITE, 1, OK_PENDING, is_client);
-      eff->emits.push_back(
-          mk_after(g_kv.client_retx_ns, K_CRETX, client, 0, is_client));
-      eff->emits.push_back(
-          mk_send(PRIMARY, K_JOIN, ctx.node, 0, is_replica));
-      eff->emits.push_back(
-          mk_after(g_kv.retx_ns, K_JRETX, ctx.node, 0, is_replica));
-      if (g_kv.chaos) {
-        int64_t who = ctx.draw.user_int(1, 1 + R, P_KILL_WHO);
-        int64_t at = ctx.draw.user_int(20000000, 300000000, P_KILL_AT);
-        int64_t revive = ctx.draw.user_int(100000000, 600000000, P_REVIVE);
-        eff->emits.push_back(mk_after(
-            at, KIND_KILL, 0, static_cast<int32_t>(who), is_client));
-        eff->emits.push_back(mk_after(
-            at + revive, KIND_RESTART, 0, static_cast<int32_t>(who), is_client));
-      }
-      break;
-    }
-    case 1: {  // on_write at primary
-      int32_t seq = ctx.args[0];
-      const int32_t* st = ctx.state;
-      bool fresh = seq > st[0] && seq > st[1];
-      if (fresh) {
-        ns[1] = seq;
-        ns[2] = 0;
-        if (payload) {
-          // the first WRITE to arrive for a seq fixes its value
-          ns[4] = ctx.pay[0];
-          ns[5] = ctx.pay[1];
-        }
-      }
-      int32_t p0 = payload ? ns[4] : 0, p1 = payload ? ns[5] : 0;
-      replicate(seq, fresh, 0, p0, p1);
-      eff->emits.push_back(
-          mk_after(g_kv.retx_ns, K_RETX, PRIMARY, seq, fresh));
-      break;
-    }
-    case 2: {  // on_repl at replica
-      int32_t seq = ctx.args[0];
-      const int32_t* st = ctx.state;
-      bool fresh = seq > st[0];
-      ns[0] = st[0] > seq ? st[0] : seq;
-      ns[1] = st[1] + 1;
-      if (payload && fresh) {
-        ns[2] = ctx.pay[0];
-        ns[3] = ctx.pay[1];
-      }
-      eff->emits.push_back(mk_send(PRIMARY, K_ACK, seq, ctx.node));
-      break;
-    }
-    case 3: {  // on_ack at primary
-      int32_t seq = ctx.args[0], who = ctx.args[1];
-      const int32_t* st = ctx.state;
-      int32_t bit = int32_t{1} << (who - 1);
-      bool current = seq == st[1];
-      int32_t mask = current ? (st[2] | bit) : st[2];
-      int32_t acks = 0;
-      for (int32_t idx = 0; idx < R; idx++) acks += (mask >> idx) & 1;
-      bool committed_now = current && seq > st[0] && acks >= majority;
-      int32_t committed = committed_now ? seq : st[0];
-      ns[0] = committed;
-      ns[2] = mask;
-      eff->emits.push_back(mk_send(client, K_COMMIT, committed, 0,
-                                   current && committed >= seq));
-      maybe_halt(committed, mask, st[3]);
-      break;
-    }
-    case 4: {  // on_commit at client
-      int32_t seq = ctx.args[0];
-      const int32_t* st = ctx.state;
-      bool fresh = seq > st[0];
-      if (fresh) ns[0] = seq;
-      bool done = seq >= g_kv.writes;
-      int32_t v0 = 0, v1 = 0;
-      if (payload) client_value(&v0, &v1);
-      send_pay(mk_send(PRIMARY, K_WRITE, seq + 1, 0, fresh && !done), v0, v1);
-      eff->emits.push_back(mk_send(PRIMARY, K_FIN, 0, 0, fresh && done));
-      if (record) {
-        // close the pending write, then probe it with a READ
-        rec(OP_WRITE, seq, OK_OK, fresh);
-        rec(OP_READ, 0, OK_PENDING, fresh);
-        eff->emits.push_back(mk_send(PRIMARY, K_READ, seq, 0, fresh));
-        rec(OP_WRITE, seq + 1, OK_PENDING, fresh && !done);
-      }
-      break;
-    }
-    case 5: {  // on_retx at primary
-      int32_t seq = ctx.args[0];
-      const int32_t* st = ctx.state;
-      bool current = seq == st[1];
-      bool pending_repl = current && st[2] != full_mask;
-      bool pending_commit = current && st[0] >= seq;
-      replicate(seq, pending_repl, st[2], payload ? st[4] : 0,
-                payload ? st[5] : 0);
-      eff->emits.push_back(
-          mk_send(client, K_COMMIT, st[0], 0, pending_commit));
-      eff->emits.push_back(mk_after(g_kv.retx_ns, K_RETX, PRIMARY, seq,
-                                    pending_repl || pending_commit));
-      break;
-    }
-    case 6: {  // on_cretx at client
-      const int32_t* st = ctx.state;
-      bool waiting = st[0] < g_kv.writes;
-      int32_t v0 = 0, v1 = 0;
-      if (payload) client_value(&v0, &v1);
-      send_pay(mk_send(PRIMARY, K_WRITE, st[0] + 1, 0, waiting), v0, v1);
-      eff->emits.push_back(mk_send(PRIMARY, K_FIN, 0, 0, !waiting));
-      eff->emits.push_back(
-          mk_after(g_kv.client_retx_ns, K_CRETX, client, 0, true));
-      break;
-    }
-    case 7: {  // on_fin at primary
-      const int32_t* st = ctx.state;
-      ns[3] = 1;
-      maybe_halt(st[0], st[2], 1);
-      break;
-    }
-    case 8: {  // on_join at primary
-      int32_t who = ctx.args[0];
-      const int32_t* st = ctx.state;
-      int32_t bit = int32_t{1} << (who - 1);
-      ns[2] = st[2] & ~bit;
-      if (g_kv.bug) ns[0] = 0;  // planted: re-admission forgets the commit point
-      // the retx timer may have died while the mask was full: re-arm
-      eff->emits.push_back(
-          mk_after(g_kv.retx_ns, K_RETX, PRIMARY, st[1], st[1] > 0));
-      break;
-    }
-    case 9: {  // on_jretx at replica
-      const int32_t* st = ctx.state;
-      bool behind = st[0] == 0;
-      eff->emits.push_back(mk_send(PRIMARY, K_JOIN, ctx.node, 0, behind));
-      eff->emits.push_back(
-          mk_after(g_kv.retx_ns, K_JRETX, ctx.node, 0, behind));
-      break;
-    }
-    case 10: {  // on_read at primary: answer with the commit point
-      eff->emits.push_back(
-          mk_send(client, K_READRESP, ctx.args[0], ctx.state[0]));
-      break;
-    }
-    case 11: {  // on_readresp at client: stale-rseq gate
-      int32_t rseq = ctx.args[0], committed = ctx.args[1];
-      bool fresh_r = rseq > ctx.state[1];
-      if (fresh_r) ns[1] = rseq;
-      rec(OP_READ, committed, OK_OK, fresh_r);
-      break;
-    }
-  }
-}
-
-// workload ids of this reference (benchmark/reference/oracle.py)
-Workload make_workload(int32_t id) {
-  switch (id) {
-    case 0:  // raft election
-      return Workload{g_raft.n_nodes, 6, 5, g_raft.n_nodes + 1, raft_handler};
-    case 1: {  // kvchaos: max_emits = max(n_replicas + 2, 6)
-      int32_t k = g_kv.n_replicas + 2;
-      if (k < 6) k = 6;
-      return Workload{g_kv.n_replicas + 2, g_kv.payload ? 6 : 4, 12, k,
-                      kvchaos_handler, g_kv.payload ? 2 : 0};
-    }
-    default:
-      return Workload{0, 0, 0, 0, nullptr};
-  }
+// function-local, so that it exists before any workload file's registrar
+std::map<std::string, Entry>& registry() {
+  static std::map<std::string, Entry> r;
+  return r;
 }
 
 }  // namespace
 
+bool register_workload(const char* name, SetParams set_params,
+                       MakeWorkload make) {
+  registry()[name] = Entry{set_params, make};
+  return true;
+}
+
+}  // namespace benchref
+
 extern "C" {
 
-void ref_set_raft(int32_t n_nodes, int64_t tmin, int64_t tmax) {
-  g_raft = {n_nodes, tmin, tmax};
-}
-
-void ref_set_kvchaos(int32_t writes, int32_t n_replicas, int64_t retx_ns,
-                     int64_t client_retx_ns, int32_t chaos, int32_t record,
-                     int32_t bug) {
-  g_kv = {writes, n_replicas, retx_ns, client_retx_ns, chaos, 0, record, bug};
-}
-
-// Run one seed for n_steps. cfg = {pool_size, lat_min, lat_max, loss_u32,
-// proc_min, proc_max, backoff_min, backoff_max, time_limit}. Plan rows:
-// n_plan engine-kind events (time, kind, node, a0, a1, valid). History
-// buffers: hist_word (hist_cap * 5) and hist_t (hist_cap), may be null
-// when hist_cap is 0. out = {now, halted, halt_time, overflow, msg_count,
-// hist_count, hist_drop}; returns 0, or nonzero on a refused input.
-int32_t ref_run(int32_t workload_id, uint64_t seed, int64_t n_steps,
+// Run one seed of the workload registered as `workload` for n_steps,
+// with its params (workloads/<name>.py pack order). cfg = {pool_size,
+// lat_min, lat_max, loss_u32, proc_min, proc_max, backoff_min,
+// backoff_max, time_limit}. Plan rows: n_plan engine-kind events (time,
+// kind, node, a0, a1, valid). History buffers: hist_word (hist_cap * 5)
+// and hist_t (hist_cap), may be null when hist_cap is 0. out = {now,
+// halted, halt_time, overflow, msg_count, hist_count, hist_drop}; returns
+// 0, or nonzero on a refused input: 1 no workload of that name, 2 more
+// plan rows than the pool holds, 3 a plan row of a user kind, 4 params
+// the workload does not take.
+__attribute__((visibility("default")))
+int32_t ref_run(const char* workload, const int64_t* params, int64_t n_params,
+                uint64_t seed, int64_t n_steps,
                 const int64_t* cfg, int64_t time_quantum, int64_t n_plan,
                 const int64_t* plan_time, const int32_t* plan_kind,
                 const int32_t* plan_node, const int32_t* plan_a0,
                 const int32_t* plan_a1, const uint8_t* plan_valid,
                 int64_t hist_cap, int32_t* hist_word, int64_t* hist_t,
                 uint64_t* out_trace, int64_t* out) {
-  Workload wl = make_workload(workload_id);
-  if (wl.n_nodes == 0) return 1;
+  using namespace benchref;
+  auto found = registry().find(workload);
+  if (found == registry().end()) return 1;
+  if (!found->second.set_params(params, n_params)) return 4;
+  Workload wl = found->second.make();
   if (wl.n_nodes + n_plan > cfg[0]) return 2;
   Sim sim;
   sim.cfg = Config{cfg[0], cfg[1], cfg[2], static_cast<uint64_t>(cfg[3]),
@@ -872,4 +389,3 @@ int32_t ref_run(int32_t workload_id, uint64_t seed, int64_t n_steps,
 }
 
 }  // extern "C"
-

@@ -1,55 +1,72 @@
-"""ctypes bridge to the benchmark's plain reference simulator (oracle.cpp).
+"""ctypes bridge to the benchmark's plain reference simulator.
 
 Imports nothing of madsim_tpu: the deployment comes from the
 configuration file, the fault plan is compiled here from its parameters
-(``plan.py``), and the library is built from this directory's own source
-into ``benchmark/.build/`` of the checkout (a fixed path, so the build
-is paid once per checkout).
+(``plan.py``). The library is built from ``oracle.cpp`` (the engine) and
+every ``workloads/*.cpp`` (one workload each, registered under its own
+name) into ``.build/`` of the benchmark's directory. Its file name
+carries a hash of every source, so an added or changed file is built
+anew and a build is paid once per checkout.
+
+A configuration's ``reference.workload`` names ``workloads/<name>.cpp``
+and ``workloads/<name>.py``; the latter's ``pack(params)`` gives the
+configuration's ``reference.params`` in the order the former reads them.
 """
 
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
 
 import numpy as np
 
-_HERE = os.path.dirname(os.path.abspath(__file__))
-_SRC = os.path.join(_HERE, "oracle.cpp")
-_BUILD = os.path.join(os.path.dirname(_HERE), ".build")
-_LIB = os.path.join(_BUILD, "libbenchref.so")
+from harness import BENCH, BenchError
+from reference import named
 
-WORKLOADS = {"raft": 0, "kvchaos": 1}
+_CXX = ["g++", "-O2", "-std=c++17", "-fPIC", "-shared", "-fvisibility=hidden"]
 _I64P = ctypes.POINTER(ctypes.c_int64)
 _I32P = ctypes.POINTER(ctypes.c_int32)
-_lib = None
+_libs: dict[str, ctypes.CDLL] = {}
+_REFUSED = {1: "no workload is registered under that name",
+            2: "the plan has more rows than the pool holds",
+            3: "a plan row has a user kind",
+            4: "the workload does not take that many params"}
 
 
-def _load() -> ctypes.CDLL:
-    global _lib
-    if _lib is None:
-        if (not os.path.exists(_LIB)
-                or os.path.getmtime(_LIB) < os.path.getmtime(_SRC)):
-            os.makedirs(_BUILD, exist_ok=True)
-            tmp = f"{_LIB}.tmp{os.getpid()}"
-            subprocess.run(["g++", "-O2", "-std=c++17", "-fPIC", "-shared",
-                            "-o", tmp, _SRC], check=True)
-            os.replace(tmp, _LIB)
-        lib = ctypes.CDLL(_LIB)
-        lib.ref_set_raft.argtypes = [ctypes.c_int32, ctypes.c_int64,
-                                     ctypes.c_int64]
-        lib.ref_set_kvchaos.argtypes = [
-            ctypes.c_int32, ctypes.c_int32, ctypes.c_int64, ctypes.c_int64,
-            ctypes.c_int32, ctypes.c_int32, ctypes.c_int32]
+def _sources(bench: str) -> list[str]:
+    ref = os.path.join(bench, "reference")
+    return ([os.path.join(ref, "sim.h"), os.path.join(ref, "oracle.cpp")]
+            + sorted(glob.glob(os.path.join(ref, "workloads", "*.cpp"))))
+
+
+def _load(bench: str) -> ctypes.CDLL:
+    """The library built from ``bench``'s sources, built if need be."""
+    srcs = _sources(bench)
+    h = hashlib.sha256(" ".join(_CXX).encode())
+    for p in srcs:
+        with open(p, "rb") as f:
+            h.update(os.path.basename(p).encode() + b"\0" + f.read() + b"\0")
+    build = os.path.join(bench, ".build")
+    lib_path = os.path.join(build, f"libbenchref-{h.hexdigest()[:16]}.so")
+    if lib_path not in _libs:
+        if not os.path.exists(lib_path):
+            os.makedirs(build, exist_ok=True)
+            tmp = f"{lib_path}.tmp{os.getpid()}"
+            subprocess.run(_CXX + ["-o", tmp] + srcs[1:], check=True)
+            os.replace(tmp, lib_path)
+        lib = ctypes.CDLL(lib_path)
         lib.ref_run.restype = ctypes.c_int32
         lib.ref_run.argtypes = [
-            ctypes.c_int32, ctypes.c_uint64, ctypes.c_int64, _I64P,
-            ctypes.c_int64, ctypes.c_int64, _I64P, _I32P, _I32P, _I32P, _I32P,
-            ctypes.POINTER(ctypes.c_uint8), ctypes.c_int64, _I32P, _I64P,
-            ctypes.POINTER(ctypes.c_uint64), _I64P]
-        _lib = lib
-    return _lib
+            ctypes.c_char_p, _I64P, ctypes.c_int64, ctypes.c_uint64,
+            ctypes.c_int64, _I64P, ctypes.c_int64, ctypes.c_int64, _I64P,
+            _I32P, _I32P, _I32P, _I32P, ctypes.POINTER(ctypes.c_uint8),
+            ctypes.c_int64, _I32P, _I64P, ctypes.POINTER(ctypes.c_uint64),
+            _I64P]
+        _libs[lib_path] = lib
+    return _libs[lib_path]
 
 
 def loss_threshold(p: float) -> int:
@@ -69,11 +86,23 @@ class Reference:
     """One configuration's reference: ``run(seed, plan_rows)`` -> result.
 
     ``time_quantum`` > 1 is the control: the same simulation with every
-    scheduled event time held at that resolution."""
+    scheduled event time held at that resolution. ``bench`` is the
+    benchmark directory whose ``reference/`` holds the sources."""
 
-    def __init__(self, config: dict, time_quantum: int = 1):
+    def __init__(self, config: dict, time_quantum: int = 1,
+                 bench: str = BENCH):
         self.ref = config["reference"]
-        self.wid = WORKLOADS[self.ref["workload"]]
+        name = self.ref["workload"]
+        files = [named.path("workloads", name, ext, bench)
+                 for ext in (".cpp", ".py")]
+        if not all(os.path.isfile(p) for p in files):
+            raise BenchError(f"no reference workload {name!r}: it needs "
+                             f"both {files[0]} and {files[1]}")
+        pack = named.load("workloads", name, bench).pack
+        self.params = np.array(pack(self.ref["params"]), np.int64)
+        self.name = name
+        self.bench = bench
+        self.lib = _load(bench)
         e = config["engine"]
         self.cfg = np.array([
             e["pool_size"], e["lat_min_ns"], e["lat_max_ns"],
@@ -84,20 +113,8 @@ class Reference:
         self.hist_cap = int(self.ref.get("hist_capacity", 0))
         self.quantum = int(time_quantum)
 
-    def _set_params(self, lib) -> None:
-        p = self.ref["params"]
-        if self.ref["workload"] == "raft":
-            lib.ref_set_raft(p["n_nodes"], p["timeout_min_ns"],
-                             p["timeout_max_ns"])
-        else:
-            lib.ref_set_kvchaos(p["writes"], p["n_replicas"], p["retx_ns"],
-                                p["client_retx_ns"], int(p["chaos"]),
-                                int(p["record"]), int(p["bug"]))
-
     def run(self, seed: int, plan_rows: dict | None = None) -> dict:
         """``plan_rows``: {time, kind, node, a0, a1, valid} 1-D columns."""
-        lib = _load()
-        self._set_params(lib)
         rows = plan_rows or {"time": [], "kind": [], "node": [], "a0": [],
                              "a1": [], "valid": []}
         t = np.ascontiguousarray(rows["time"], np.int64)
@@ -106,13 +123,16 @@ class Reference:
         a0 = np.ascontiguousarray(rows["a0"], np.int32)
         a1 = np.ascontiguousarray(rows["a1"], np.int32)
         v = np.ascontiguousarray(rows["valid"], np.uint8)
+        if not len(t) == len(k) == len(nd) == len(a0) == len(a1) == len(v):
+            raise ValueError("plan row columns differ in length")
         cap = self.hist_cap
         hw = np.zeros((max(cap, 1), 5), np.int32)
         ht = np.zeros((max(cap, 1),), np.int64)
         trace = ctypes.c_uint64()
         out = np.zeros(7, np.int64)
-        rc = lib.ref_run(
-            self.wid, ctypes.c_uint64(int(seed) & (2**64 - 1)),
+        rc = self.lib.ref_run(
+            self.name.encode(), _ptr(self.params, ctypes.c_int64),
+            len(self.params), ctypes.c_uint64(int(seed) & (2**64 - 1)),
             self.max_steps, _ptr(self.cfg, ctypes.c_int64), self.quantum,
             len(t), _ptr(t, ctypes.c_int64), _ptr(k, ctypes.c_int32),
             _ptr(nd, ctypes.c_int32), _ptr(a0, ctypes.c_int32),
@@ -120,9 +140,10 @@ class Reference:
             _ptr(hw, ctypes.c_int32), _ptr(ht, ctypes.c_int64),
             ctypes.byref(trace), _ptr(out, ctypes.c_int64))
         if rc != 0:
-            raise ValueError(f"reference refused the input (rc={rc})")
+            raise BenchError(f"the reference refused {self.name!r}: "
+                             f"{_REFUSED.get(rc, f'rc {rc}')}")
         n = int(out[5])
         return {"trace": trace.value, "now": int(out[0]),
                 "halted": bool(out[1]), "halt_time": int(out[2]),
-                "overflow": int(out[3]), "hist": hw[:n].tolist(),
-                "hist_drop": int(out[6])}
+                "overflow": int(out[3]), "msg_count": int(out[4]),
+                "hist": hw[:n].tolist(), "hist_drop": int(out[6])}

@@ -1,11 +1,17 @@
 """Plain compilation of the configurations' fault plans and campaign seeds.
 
-Pure Python integers: threefry-2x32 (20 rounds), the (seed, plan-slot)
-draw stream and the CrashStorm kill/restart rows, as the engine's
-published semantics define them. Imports nothing of madsim_tpu.
+Pure Python integers: threefry-2x32 (20 rounds) and the (seed,
+plan-slot) draw stream, as the engine's published semantics define them.
+Each spec type is ``plans/<type>.py``, with ``rows(spec, seed, slot)``
+(its engine-kind rows, drawn from plan slots from ``slot`` on) and
+``slots(spec)`` (how many slots it draws from). Imports nothing of
+madsim_tpu.
 """
 
 from __future__ import annotations
+
+from harness import BENCH
+from reference import named
 
 M32 = 0xFFFFFFFF
 _ROT = (13, 15, 26, 6, 17, 29, 16, 24)
@@ -32,7 +38,7 @@ def threefry2x32(k0: int, k1: int, x0: int, x1: int) -> tuple[int, int]:
     return x0, x1
 
 
-class _Stream:
+class Stream:
     """Draw j of plan slot ``slot`` for one seed (chaos/plan.py _Stream)."""
 
     def __init__(self, seed: int, slot: int):
@@ -49,29 +55,15 @@ class _Stream:
         return options[self.bits(j) % len(options)]
 
 
-def _crash_storm(spec: dict, seed: int, slot: int) -> list:
-    st = _Stream(seed, slot)
-    rows = []
-    for i in range(spec["n"]):
-        who = st.pick(spec["targets"], 3 * i)
-        at = st.uniform(spec["t_min_ns"], spec["t_max_ns"], 3 * i + 1)
-        down = st.uniform(spec["down_min_ns"], spec["down_max_ns"], 3 * i + 2)
-        rows.append((at, KIND_KILL, 0, who, 0, True))
-        rows.append((at + down, KIND_RESTART, 0, who, 0, True))
-    return rows
-
-
-_SPECS = {"CrashStorm": (_crash_storm, lambda s: 2 * s["n"])}
-
-
-def compile_plan(plan: dict, seed: int) -> dict:
+def compile_plan(plan: dict, seed: int, bench: str = BENCH) -> dict:
     """One seed's plan rows: {time, kind, node, a0, a1, valid} lists.
-    Spec i draws from plan slots [offset_i, offset_i + slots_i)."""
+    Spec i draws from plan slots [offset_i, offset_i + slots_i). A type
+    with no file under ``plans/`` is a BenchError."""
     rows, off = [], 0
     for spec in plan["specs"]:
-        build, slots = _SPECS[spec["type"]]
-        rows += build(spec, seed, off)
-        off += slots(spec)
+        mod = named.load("plans", spec["type"], bench)
+        rows += mod.rows(spec, seed, off)
+        off += mod.slots(spec)
     cols = ("time", "kind", "node", "a0", "a1", "valid")
     return {c: [r[i] for r in rows] for i, c in enumerate(cols)}
 

@@ -4,7 +4,10 @@ This is the one module of the benchmark that imports madsim_tpu: it
 turns the configuration's ``program`` section into the workload, engine
 config, fault plan and device screens the program takes, and refuses a
 build in which the program would depart from what the configuration
-states (its event-time width).
+states (its event-time width). A ``screens`` entry names a screen of
+``madsim_tpu.check.device``, as a name or as ``{"name": ..., "args":
+{...}}`` for a screen that takes arguments (``election_safety``'s op
+code).
 """
 
 from __future__ import annotations
@@ -12,6 +15,7 @@ from __future__ import annotations
 from dataclasses import dataclass
 
 from harness import BenchError
+from reference import named
 
 
 @dataclass
@@ -42,7 +46,7 @@ def build(config: dict) -> System:
                 for k, v in s.items() if k != "type"})
             for s in config["plan"]["specs"])
         plan = chaos.FaultPlan(specs, name=config["plan"]["name"])
-    screens = tuple(getattr(dc, s)() for s in config["screens"]) or None
+    screens = tuple(_screen(dc, s) for s in config["screens"]) or None
     import jax
 
     # the stated width is the chip's; the CPU backend (tests) resolves
@@ -54,3 +58,11 @@ def build(config: dict) -> System:
             f"the configuration states event times as {config['event_time']}")
     return System(wl, cfg, plan, screens, int(config["max_steps"]),
                   bool(config["require_halt"]), int(config.get("cov_words", 0)))
+
+
+def _screen(dc, entry):
+    name, args = named.entry(entry)
+    make = getattr(dc, name, None)
+    if not callable(make):
+        raise BenchError(f"no screen {name!r} in madsim_tpu.check.device")
+    return make(**args)

@@ -115,6 +115,8 @@ def test_readers_are_silent_without_program_spans(tmp_path, monkeypatch):
             assert _read(name, rec) is None, name
     # a recorded chip trace of the parent: no trace file to read either
     for path in glob.glob(os.path.join(DATA, "*.json.gz")):
+        if path.endswith("reference_pins.json.gz"):
+            continue
         with gzip.open(path, "rt") as f:
             red = tracing.reduce(json.load(f)["events"])
         rec = {"kind": "sweep", "trace": red, "units": [], "chips": 1}

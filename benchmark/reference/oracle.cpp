@@ -13,7 +13,10 @@
 //     appended at hist_count, counted in hist_drop once the buffer is full;
 //   * the control: `time_quantum` > 1 holds every scheduled event time
 //     at that resolution (rounded down), the coarser clock a narrower
-//     time representation would tempt. 1 = exact.
+//     time representation would tempt. 1 = exact;
+//   * payloads as wide as the workload declares, up to kMaxPay words,
+//     held per run beside the pool, and the restart-surviving columns
+//     the workload declares (Workload.durable_cols).
 // Integer arithmetic only: uint32 threefry, int64 ns clocks, uint64 trace.
 
 #include <cstdint>
@@ -44,6 +47,7 @@ struct Sim {
   int32_t overflow = 0;
   int64_t msg_count = 0;
   std::vector<Event> ev;
+  std::vector<int32_t> ev_pay;      // (pool, W) each pooled event's payload
   std::vector<uint8_t> alive;
   std::vector<uint8_t> paused;
   std::vector<int32_t> epoch;
@@ -67,6 +71,7 @@ struct Sim {
     }
     // plan rows ride slots [n, n+p): engine kinds, timer source, epoch 0
     for (size_t j = 0; j < plan.size(); j++) ev[wl.n_nodes + j] = plan[j];
+    ev_pay.assign(static_cast<size_t>(cfg.pool_size) * wl.payload_words, 0);
     hist_word.assign(static_cast<size_t>(hist_cap) * 5, 0);
     hist_t.assign(static_cast<size_t>(hist_cap), 0);
     alive.assign(wl.n_nodes, 1);
@@ -121,8 +126,10 @@ struct Sim {
     int32_t kind = ev[i].kind, dst = ev[i].node, src = ev[i].src;
     int32_t args[4];
     std::memcpy(args, ev[i].args, sizeof(args));
+    const int32_t W = wl.payload_words;
     int32_t pay[kMaxPay];  // copied now: the slot may be reused below
-    std::memcpy(pay, ev[i].pay, sizeof(pay));
+    if (W > 0)
+      std::memcpy(pay, &ev_pay[static_cast<size_t>(i) * W], W * sizeof(int32_t));
     bool is_engine = kind < FIRST_USER_KIND;
     bool is_msg = src >= 0;
     bool live = alive[dst] && epoch[dst] == ev[i].epoch;
@@ -298,7 +305,9 @@ struct Sim {
                  : (e.dst >= 0 && e.dst < wl.n_nodes ? epoch[e.dst] : 0);
       ne.retry = 0;
       std::memcpy(ne.args, e.args, sizeof(ne.args));
-      std::memcpy(ne.pay, e.pay, sizeof(ne.pay));
+      if (W > 0)
+        std::memcpy(&ev_pay[static_cast<size_t>(j) * W], e.pay,
+                    W * sizeof(int32_t));
     }
     msg_count += n_sends;
     if (dispatch) {
@@ -341,7 +350,9 @@ extern "C" {
 // halted, halt_time, overflow, msg_count, hist_count, hist_drop}; returns
 // 0, or nonzero on a refused input: 1 no workload of that name, 2 more
 // plan rows than the pool holds, 3 a plan row of a user kind, 4 params
-// the workload does not take.
+// the workload does not take, 5 a payload wider than kMaxPay words (out
+// = {the workload's width, kMaxPay}), 6 a durable column outside the
+// state row (out[0] = that column).
 __attribute__((visibility("default")))
 int32_t ref_run(const char* workload, const int64_t* params, int64_t n_params,
                 uint64_t seed, int64_t n_steps,
@@ -356,6 +367,17 @@ int32_t ref_run(const char* workload, const int64_t* params, int64_t n_params,
   if (found == registry().end()) return 1;
   if (!found->second.set_params(params, n_params)) return 4;
   Workload wl = found->second.make();
+  if (wl.payload_words < 0 || wl.payload_words > kMaxPay) {
+    out[0] = wl.payload_words;
+    out[1] = kMaxPay;
+    return 5;
+  }
+  for (int32_t c : wl.durable_cols) {
+    if (c < 0 || c >= wl.state_width) {
+      out[0] = c;
+      return 6;
+    }
+  }
   if (wl.n_nodes + n_plan > cfg[0]) return 2;
   Sim sim;
   sim.cfg = Config{cfg[0], cfg[1], cfg[2], static_cast<uint64_t>(cfg[3]),
@@ -371,6 +393,7 @@ int32_t ref_run(const char* workload, const int64_t* params, int64_t n_params,
   }
   sim.hist_cap = hist_cap;
   sim.durable.assign(wl.state_width, 0);
+  for (int32_t c : wl.durable_cols) sim.durable[c] = 1;
   sim.init();
   for (int64_t s = 0; s < n_steps; s++) sim.do_step();
   if (hist_cap > 0) {

@@ -33,7 +33,10 @@ _libs: dict[str, ctypes.CDLL] = {}
 _REFUSED = {1: "no workload is registered under that name",
             2: "the plan has more rows than the pool holds",
             3: "a plan row has a user kind",
-            4: "the workload does not take that many params"}
+            4: "the workload does not take that many params",
+            5: "its payload of {0} words is wider than the {1} words the "
+               "reference holds",
+            6: "its durable column {0} lies outside the state row"}
 
 
 def _sources(bench: str) -> list[str]:
@@ -140,8 +143,8 @@ class Reference:
             _ptr(hw, ctypes.c_int32), _ptr(ht, ctypes.c_int64),
             ctypes.byref(trace), _ptr(out, ctypes.c_int64))
         if rc != 0:
-            raise BenchError(f"the reference refused {self.name!r}: "
-                             f"{_REFUSED.get(rc, f'rc {rc}')}")
+            why = _REFUSED.get(rc, f"rc {rc}").format(*out.tolist())
+            raise BenchError(f"the reference refused {self.name!r}: {why}")
         n = int(out[5])
         return {"trace": trace.value, "now": int(out[0]),
                 "halted": bool(out[1]), "halt_time": int(out[2]),

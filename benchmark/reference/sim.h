@@ -116,26 +116,28 @@ struct Config {  // EngineConfig
   int64_t time_limit_ns;  // 0 = unlimited
 };
 
-// payload arena width cap (Workload.payload_words; engine events carry
-// W int32 words — engine/core.py ev_pay)
-constexpr int32_t kMaxPay = 4;
+// payload width cap (Workload.payload_words; engine events carry W
+// int32 words — engine/core.py ev_pay). A pooled event's W words are
+// held per run, beside the pool (oracle.cpp); ref_run refuses a
+// workload that declares more than this.
+constexpr int32_t kMaxPay = 64;
 
 struct Event {
   int64_t time;
   bool valid;
   int32_t kind, node, src, epoch, retry;
   int32_t args[4];
-  int32_t pay[kMaxPay] = {0, 0, 0, 0};
 };
 
-// one emit row (Emits)
+// one emit row (Emits); words past the workload's payload_words are
+// never read
 struct Emit {
   bool valid = false;
   bool send = false;
   int32_t kind = 0, dst = 0;
   int64_t delay = 0;
   int32_t args[4] = {0, 0, 0, 0};
-  int32_t pay[kMaxPay] = {0, 0, 0, 0};
+  int32_t pay[kMaxPay] = {};
 };
 
 // one history record (Emits.record): when, [op, key, arg, ok]
@@ -165,11 +167,20 @@ struct Ctx {
 
 // Workload interface: mirrors engine Workload. new_state is written by
 // the handler; the engine applies it only when the event dispatches.
+//
+// durable_cols are the state columns that survive a restart (engine
+// Workload.durable_cols): a restarted node keeps them and takes every
+// other column from its initial row. The program's two-phase sync
+// discipline (Workload.durable_sync) has no model here: with no injected
+// disk fault, a workload that syncs every durable write in the dispatch
+// that made it follows the same trajectory as one whose durable columns
+// survive verbatim, which is what this engine implements.
 struct Workload {
   int32_t n_nodes, state_width, n_handlers, max_emits;
   // handler(h, ctx, new_state_out, effects_out)
   void (*handler)(int32_t h, const Ctx&, int32_t*, Effects*);
-  int32_t payload_words = 0;  // engine Workload.payload_words
+  int32_t payload_words = 0;  // engine Workload.payload_words, <= kMaxPay
+  std::vector<int32_t> durable_cols = {};
 };
 
 inline Emit mk_send(int32_t dst, int32_t kind, int32_t a0 = 0, int32_t a1 = 0,

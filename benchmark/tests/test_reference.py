@@ -111,6 +111,13 @@ def _program_raft5_answers(n: int) -> list:
              "overflowed": bool(rep.overflowed[i])} for i in range(n)]
 
 
+def _bench_copy(tmp_path):
+    bench = tmp_path / "benchmark"
+    shutil.copytree(BENCH, bench, ignore=shutil.ignore_patterns(
+        ".jax_cache", ".build", ".out", "__pycache__"))
+    return bench
+
+
 def test_workload_check_and_plan_spec_added_by_files_alone(tmp_path):
     """A copy of raft registered under another name, a history check and
     a plan spec are added to a copy of the benchmark as new files, with a
@@ -120,9 +127,7 @@ def test_workload_check_and_plan_spec_added_by_files_alone(tmp_path):
     from reference.oracle import Reference
     from reference.plan import compile_plan
 
-    bench = tmp_path / "benchmark"
-    shutil.copytree(BENCH, bench, ignore=shutil.ignore_patterns(
-        ".jax_cache", ".build", ".out", "__pycache__"))
+    bench = _bench_copy(tmp_path)
     before = _digests(bench)
     raft5 = _config("raft5")
     Reference(raft5, bench=str(bench))  # a build without the new files
@@ -160,8 +165,99 @@ def test_workload_check_and_plan_spec_added_by_files_alone(tmp_path):
         [orig.run(s["seed"]) for s in sched[:4]]
 
 
+KEEPER_CPP = """// one node, three columns; bit c of the one param makes column c
+// durable. Each init records every column, then writes 7, 8, 9 to them;
+// the init after a restart halts.
+#include "../sim.h"
+
+namespace benchref {
+namespace {
+int64_t g_mask = 0;
+
+void keeper(int32_t, const Ctx& ctx, int32_t* ns, Effects* eff) {
+  for (int32_t u = 0; u < 3; u++) {
+    eff->recs.push_back(Rec{true, 1, u, ctx.state[u], 1});
+    ns[u] = 7 + u;
+  }
+  eff->emits.push_back(mk_after(0, KIND_HALT, 0, 0, ctx.now > 0));
+}
+
+bool set_params(const int64_t* p, int64_t n) {
+  if (n != 1) return false;
+  g_mask = p[0];
+  return true;
+}
+
+Workload make() {
+  Workload wl{1, 3, 1, 1, keeper};
+  for (int32_t c = 0; c < 8; c++)
+    if ((g_mask >> c) & 1) wl.durable_cols.push_back(c);
+  return wl;
+}
+
+const bool kRegistered = register_workload("keeper", &set_params, &make);
+}  // namespace
+}  // namespace benchref
+"""
+
+KEEPER_PY = """def pack(params):
+    return [params["durable_mask"]]
+"""
+
+
+@pytest.mark.parametrize("mask", [0b000, 0b001, 0b101, 0b111, 0b1000])
+def test_durable_columns_survive_a_restart(tmp_path, mask):
+    """A workload added as new files declares its durable columns: after
+    a kill and a restart the node comes back with those columns as it
+    left them and every other column at its initial row (zeros); a
+    column outside the state row is refused."""
+    from reference.oracle import Reference
+
+    bench = _bench_copy(tmp_path)
+    (bench / "reference" / "workloads" / "keeper.cpp").write_text(KEEPER_CPP)
+    (bench / "reference" / "workloads" / "keeper.py").write_text(KEEPER_PY)
+    raft5 = _config("raft5")
+    conf = dict(raft5, max_steps=20, reference={
+        "workload": "keeper", "params": {"durable_mask": mask},
+        "hist_capacity": 8})
+    kill_restart = {"time": [1_000_000, 2_000_000], "kind": [0, 1],
+                    "node": [0, 0], "a0": [0, 0], "a1": [0, 0],
+                    "valid": [True, True]}
+    ref = Reference(conf, bench=str(bench))
+    if mask >> 3:
+        with pytest.raises(BenchError, match="durable column 3 lies outside"):
+            ref.run(5, kill_restart)
+        return
+    r = ref.run(5, kill_restart)
+    assert r["halted"] and r["hist_drop"] == 0
+    kept = [7 + u if (mask >> u) & 1 else 0 for u in range(3)]
+    assert r["hist"] == ([[1, u, 0, 0, 1] for u in range(3)]
+                         + [[1, u, kept[u], 0, 1] for u in range(3)])
+
+
+@pytest.mark.parametrize("n_writes", [64, 65])
+def test_payload_width_up_to_the_cap(n_writes):
+    """raftlog carries its whole log in each payload: 64 words run, 65
+    are refused with a BenchError that names the width."""
+    from reference.oracle import Reference
+
+    params = dict(n_nodes=5, n_writes=n_writes, timeout_min_ns=150_000_000,
+                  timeout_max_ns=300_000_000, propose_ns=20_000_000,
+                  retx_ns=60_000_000, chaos=True, durable=True, record=False)
+    conf = dict(_config("raft5"), max_steps=4000,
+                reference={"workload": "raftlog", "params": params})
+    ref = Reference(conf)
+    if n_writes > 64:
+        with pytest.raises(BenchError, match="payload of 65 words is wider "
+                                             "than the 64 words"):
+            ref.run(3)
+    else:
+        r = ref.run(3)
+        assert r["now"] > 0 and r["msg_count"] > 0
+
+
 @pytest.mark.parametrize("what", ["workload", "params", "check",
-                                  "election_safety", "plan_spec", "screen"])
+                                  "lease_safety", "plan_spec", "screen"])
 def test_unknown_names_are_refused(what):
     """An unknown workload, check, plan spec or screen name is a
     BenchError; so is a check that exists only as a device screen, which
@@ -181,8 +277,8 @@ def test_unknown_names_are_refused(what):
             ref.run(1)
         elif what == "check":
             clean([], ["stale_reads", "no_such_check"])
-        elif what == "election_safety":
-            clean([[1, 0, 1, 0, 1]], ["election_safety"])
+        elif what == "lease_safety":
+            clean([[1, 0, 1, 0, 1]], ["lease_safety"])
         elif what == "plan_spec":
             compile_plan({"specs": [{"type": "NoSuchStorm", "n": 1}]}, 1)
         else:

@@ -118,8 +118,8 @@ def engine_phase(name: str, n_seeds: int, n_steps: int, n_sample: int,
     t0 = time.perf_counter()  # lint: allow(wall-clock)
     prog = run.compute.lower(st).compile()
     compile_s = time.perf_counter() - t0  # lint: allow(wall-clock)
-    banked, run_s = timed(prog, st)
-    res = run.assemble(banked)
+    packed, run_s = timed(prog, st)
+    res = run.assemble(packed)
     halted, overflow = int(res.halted.sum()), int(res.overflow.sum())
     log(f"engine:{name}", seeds=n_seeds, max_steps=n_steps, layout=layout,
         time32=time32, compile_s=compile_s, init_s=init_s, run_s=run_s,
@@ -143,10 +143,10 @@ def engine_phase(name: str, n_seeds: int, n_steps: int, n_sample: int,
 
     cpu = jax.devices("cpu")[0]
     with jax.default_device(cpu):
-        ref_banked, cpu_s = timed(run.compute, init(seeds[pick]))
-    if ref_banked[0]["trace"].devices() != {cpu}:
+        ref_packed, cpu_s = timed(run.compute, init(seeds[pick]))
+    if any(x.devices() != {cpu} for x in jax.tree.leaves(ref_packed)):
         raise AssertionError(f"{name}: the reference run left the CPU")
-    ref = run.assemble(ref_banked)
+    ref = run.assemble(ref_packed)
     for f in SAME_FIELDS:
         if not np.array_equal(getattr(res, f)[pick], getattr(ref, f)):
             raise AssertionError(

@@ -35,6 +35,12 @@ halted rows too, while compaction stops counting once a row is banked.
 The counter is the RNG coordinate (engine/rng.py) and halted rows make
 no further draws, so nothing downstream can observe the difference.
 
+Results: after the last phase the program concatenates the banks,
+gathers them back into seed order by the inverse of their row indices,
+and packs every result field into one flat uint32 buffer
+(:class:`Packed`), so the host makes one copy — not one per field per
+bank followed by a scatter — and gets each field as a view.
+
 The total-step cap is shared across phases (one counter threaded through
 all while_loops), so ``max_steps`` means the same thing as in
 ``make_run_while``: rows still live when the cap hits are frozen
@@ -44,6 +50,8 @@ mid-flight exactly like the lockstep loop would leave them.
 from __future__ import annotations
 
 import dataclasses
+import functools
+import math
 from types import SimpleNamespace
 
 import numpy as np
@@ -117,12 +125,13 @@ SCREEN_FIELDS = ("hist_ok", "hist_fold")
 
 
 class HostFetch:
-    """``np.asarray`` that counts the bytes crossing device->host: each
-    device array once (a fetched array keeps its host copy), host
-    arrays free. ``nbytes`` is the running total."""
+    """``np.asarray`` that counts what crosses device->host: each device
+    array once (a fetched array keeps its host copy), host arrays free.
+    ``nbytes`` is the running total of bytes, ``transfers`` of arrays."""
 
     def __init__(self):
         self.nbytes = 0
+        self.transfers = 0
         self._fetched: list = []
 
     def __call__(self, x) -> np.ndarray:
@@ -131,14 +140,123 @@ class HostFetch:
         ):
             self._fetched.append(x)
             self.nbytes += int(x.nbytes)
+            self.transfers += 1
         return np.asarray(x)
+
+
+@jax.tree_util.register_pytree_node_class
+class Packed:
+    """A compacted run's results in original seed order, packed on the
+    device into one flat uint32 buffer ``buf``; ``loose`` holds the
+    columns that cross on their own (a screened run's
+    ``hist_word``/``hist_t``, trimmed first), and the static ``layout``
+    says where each packed field lies: one ``(name, word offset,
+    shape, dtype)`` entry per field."""
+
+    def __init__(self, buf, loose: dict, layout: tuple):
+        self.buf, self.loose, self.layout = buf, loose, layout
+
+    def tree_flatten(self):
+        return (self.buf, self.loose), self.layout
+
+    @classmethod
+    def tree_unflatten(cls, layout, children):
+        return cls(*children, layout)
+
+    def fields(self, host: np.ndarray) -> dict:
+        """Every packed field as a view of ``host``, the host copy of
+        ``buf``."""
+        return {name: _view(host[off:], shape, dt)
+                for name, off, shape, dt in self.layout}
+
+
+def _view(words: np.ndarray, shape, dtype) -> np.ndarray:
+    """The array of ``shape`` and ``dtype`` whose bytes lead ``words``
+    (C-contiguous uint32)."""
+    dt = np.dtype(dtype)
+    n = math.prod(shape) * dt.itemsize
+    return words.reshape(-1).view(np.uint8)[:n].view(dt).reshape(shape)
+
+
+def _flat_words(x) -> jnp.ndarray:
+    """The bytes of ``x`` as uint32 words (a value of under four bytes
+    shares its word, the last word zero-padded)."""
+    if x.dtype.itemsize >= 4:
+        return lax.bitcast_convert_type(x, jnp.uint32).reshape(-1)
+    b = (x.astype(jnp.uint8) if x.dtype == jnp.bool_
+         else lax.bitcast_convert_type(x, jnp.uint8)).reshape(-1)
+    b = jnp.pad(b, (0, -b.size % 4)).reshape(-1, 4)
+    return lax.bitcast_convert_type(b, jnp.uint32)
+
+
+def _row_words(x) -> jnp.ndarray:
+    """(rows, ...) -> (rows, t) uint32 words of each row: an 8-byte
+    value as two words, a narrower one widened to one."""
+    if x.dtype.itemsize < 4:
+        x = x.astype(jnp.int32 if jnp.issubdtype(x.dtype, jnp.signedinteger)
+                     else jnp.uint32)
+    return lax.bitcast_convert_type(x, jnp.uint32).reshape(x.shape[0], -1)
+
+
+def _from_row_words(w, like) -> jnp.ndarray:
+    """Inverse of :func:`_row_words` for an array shaped like ``like``."""
+    if like.dtype.itemsize < 4:
+        wide = (jnp.int32 if jnp.issubdtype(like.dtype, jnp.signedinteger)
+                else jnp.uint32)
+        return lax.bitcast_convert_type(w, wide).reshape(like.shape).astype(
+            like.dtype)
+    pair = (2,) if like.dtype.itemsize == 8 else ()
+    return lax.bitcast_convert_type(w.reshape(like.shape + pair), like.dtype)
+
+
+def _unpermute(cols: dict, idx) -> dict:
+    """Every (rows, ...) column gathered into the order ``argsort(idx)``
+    by one row gather of a single uint32 word matrix: on the chip a
+    gather per column costs several times more (64-bit columns most),
+    and a gather, unlike a scatter, is safe for bools there."""
+    live = {f: x for f, x in cols.items() if x.size}
+    words = {f: _row_words(x) for f, x in live.items()}
+    mat = jnp.concatenate(list(words.values()), axis=1)[jnp.argsort(idx)]
+    out, o = dict(cols), 0
+    for f, x in live.items():
+        t = words[f].shape[1]
+        out[f] = _from_row_words(mat[:, o:o + t], x)
+        o += t
+    return out
+
+
+def _pack(cols: dict, loose: tuple) -> Packed:
+    """Flatten every column but the ``loose`` ones into one uint32
+    buffer, field after field, the widest dtypes first so that each
+    field lies aligned to its item size on the host."""
+    names = sorted((f for f in cols if f not in loose),
+                   key=lambda f: -cols[f].dtype.itemsize)
+    parts, layout, off = [], [], 0
+    for f in names:
+        x = cols[f]
+        parts.append(_flat_words(x))
+        layout.append((f, off, x.shape, np.dtype(x.dtype).str))
+        off += parts[-1].size
+    return Packed(jnp.concatenate(parts), {f: cols[f] for f in loose},
+                  tuple(layout))
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _prefix_words(x, k: int):
+    # compiled once per distinct surviving record count; kept 2-D, as
+    # the chip's compiler takes minutes to flatten such an input
+    return _row_words(x[:, :k])
 
 
 def row_steps(banked, fetch=np.asarray) -> tuple[int, int]:
     """(paid, live) row-steps of one phase-program result: rows x loop
     iterations, each phase at its own size, and those in which the row
     had not yet halted — summed over the shards of a mesh run."""
-    paid, live = fetch(banked[-1]["_row_steps"]).reshape(-1, 2).sum(0)
+    if isinstance(banked, Packed):
+        counts = banked.fields(fetch(banked.buf))["_row_steps"]
+    else:
+        counts = fetch(banked[-1]["_row_steps"])
+    paid, live = counts.reshape(-1, 2).sum(0)
     return int(paid), int(live)
 
 
@@ -177,7 +295,11 @@ def make_run_compacted(
     ``make_init`` and returns numpy arrays (one per name in ``fields``,
     leading axis = the original seed order) — the same fields bench.py
     and the verify tools read off a ``SimState``, minus the live event
-    pool (which only straggler rows still meaningfully own).
+    pool (which only straggler rows still meaningfully own). Its
+    ``compute`` is the jitted program, returning a :class:`Packed`
+    result that ``assemble`` copies to the host; ``phases`` is the
+    untransformed phase program (a list of banks, for a mesh run's
+    ``shard_map``) and ``assemble_banks`` their host assembly.
 
     ``shrink``/``min_size`` set the static phase schedule; with
     ``min_size >= n_seeds`` the program degenerates to exactly one
@@ -250,11 +372,13 @@ def make_run_compacted(
         out["_idx"] = idx
         return out
 
-    def compiled(state: SimState, idx_offset=0):
-        """The phase program. Shapes are static per input size, so the
-        same traced function serves the full batch (make_run_compacted)
-        or one device's shard (parallel.shard_run_compacted, which
-        passes the shard's global row offset as ``idx_offset``)."""
+    def phases(state: SimState, idx_offset=0):
+        """The phase program: a list of banks, each holding its rows'
+        original positions as ``_idx``. Shapes are static per input
+        size, so the same traced function serves the full batch
+        (``compiled`` below) or one device's shard
+        (parallel.shard_run_compacted, which passes the shard's global
+        row offset as ``idx_offset``)."""
         s0 = state.seed.shape[0]
         sizes = _phase_sizes(s0, shrink, min_size)
         idx = jnp.arange(s0, dtype=jnp.int32) + jnp.asarray(idx_offset, jnp.int32)
@@ -301,24 +425,66 @@ def make_run_compacted(
 
         return banked
 
+    out_fields = fields if screens is None else fields + SCREEN_FIELDS
+    # a screened run's history columns cross trimmed to the longest
+    # surviving record count, known only once the counts are on the host
+    loose = ("hist_word", "hist_t") if screens is not None else ()
+
+    def compiled(state: SimState) -> Packed:
+        """The phase program with its banks put back in seed order and
+        packed into one buffer on the device, so the host makes one
+        copy instead of one per field per bank and a scatter."""
+        banked = phases(state)
+        cols = {
+            f: jnp.concatenate([b[f] for b in banked]) for f in out_fields
+        }
+        if len(banked) > 1:
+            cols = _unpermute(
+                cols, jnp.concatenate([b["_idx"] for b in banked])
+            )
+        cols["_row_steps"] = banked[-1]["_row_steps"]
+        if screens is not None:
+            cols["_kept"] = jnp.max(cols["hist_count"], initial=0)
+        return _pack(cols, loose)
+
     # no donate_argnums: banked phase-0 rows alias the input buffers, so
     # XLA can't actually reuse them (it would only warn); the one extra
     # input-sized allocation is cheap next to the loop carries
     jitted = jax.jit(compiled)
 
-    out_fields = fields if screens is None else fields + SCREEN_FIELDS
-
-    def assemble(banked, fetch=None) -> SimpleNamespace:
-        """Device->host transfer + scatter back into original seed order.
+    def assemble(packed: Packed, fetch=None) -> SimpleNamespace:
+        """Device->host transfer of a :class:`Packed` result: one copy,
+        each field a view of it (the copy duplicated once on the host
+        where the transfer is read-only, so every field is writable).
 
         Under a ``hist_screen``, the folded history columns transfer
-        only up to the longest surviving record count across the banks
-        (fetched first — one tiny counter read): the fold's whole point
-        is that the big (rows, H, ...) column transfer shrinks to the
-        pending-invoke prefix plus the flagged seeds' full histories.
+        only up to the longest surviving record count (read from the
+        packed buffer): the fold's whole point is that the big (rows,
+        H, ...) column transfer shrinks to the pending-invoke prefix
+        plus the flagged seeds' full histories.
 
-        ``fetch`` (a :class:`HostFetch`) counts the bytes copied.
+        ``fetch`` (a :class:`HostFetch`) counts what crossed.
         """
+        fetch = HostFetch() if fetch is None else fetch
+        host = fetch(packed.buf)
+        out = packed.fields(host if host.flags.writeable else host.copy())
+        for f, x in packed.loose.items():
+            k = int(out["_kept"])
+            got = _view(fetch(_prefix_words(x, k)),
+                        (x.shape[0], k) + x.shape[2:], x.dtype)
+            if k == x.shape[1]:
+                out[f] = got.copy()
+            else:
+                # rows past hist_count are zero by the fold, so the
+                # untransferred tail is value-identical
+                out[f] = np.zeros(x.shape, x.dtype)
+                out[f][:, :k] = got
+        return SimpleNamespace(**{f: out[f] for f in out_fields})
+
+    def assemble_banks(banked, fetch=None) -> SimpleNamespace:
+        """:func:`assemble` for the banks of :func:`phases` (a mesh run's,
+        per shard): one copy per field per bank, scattered into seed
+        order on the host."""
         fetch = HostFetch() if fetch is None else fetch
         idxs = [fetch(b["_idx"]) for b in banked]
         s0 = sum(i.shape[0] for i in idxs)
@@ -328,7 +494,7 @@ def make_run_compacted(
                 (int(fetch(b["hist_count"]).max(initial=0)) for b in banked),
                 default=0,
             )
-            trim = {"hist_word": kept, "hist_t": kept}
+            trim = dict.fromkeys(loose, kept)
         out = {}
         for f in out_fields:
             proto = banked[0][f]
@@ -338,10 +504,6 @@ def make_run_compacted(
                 if k is None:
                     buf[idx] = fetch(b[f])
                 else:
-                    # device-side slice: only the surviving prefix
-                    # crosses the boundary (rows past hist_count are
-                    # zero by the fold, so the untransferred tail of
-                    # the host buffer is value-identical)
                     buf[idx, :k] = fetch(b[f][:, :k])
             out[f] = buf
         return SimpleNamespace(**out)
@@ -352,10 +514,12 @@ def make_run_compacted(
     # benchmark seam: time `compute` (device work only, block on device
     # arrays) and call `assemble` outside the window — keeps the metric
     # methodologically identical to timing the lockstep loop, where the
-    # host read also happened after the timed region. The banks' last
-    # entry also carries the loop's `_row_steps` (module row_steps)
+    # host read also happened after the timed region. The packed result
+    # also carries the loop's `_row_steps` (module row_steps)
     run.compute = jitted
     run.assemble = assemble
-    # sharding seam: the raw phase program, for parallel.shard_run_compacted
-    run.phases = compiled
+    # sharding seam: the raw phase program and its banks' assembly, for
+    # parallel.shard_run_compacted
+    run.phases = phases
+    run.assemble_banks = assemble_banks
     return run

@@ -319,11 +319,14 @@ class SearchReport:
     # obs.causal.causal_slice computes violation cones from them
     lam: np.ndarray | None = None
     # host-path counters (also the ``madsim.search`` span's metadata,
-    # obs.prof): bytes this call copied device->host, and the runner's
-    # row-steps — rows x loop iterations paid (the compacted runner at
-    # each phase's size) and those whose row had not yet halted, so
-    # row_steps_live / row_steps_paid is the lockstep efficiency
+    # obs.prof): bytes and arrays this call copied device->host (a
+    # compacted sweep's results cross as one packed buffer,
+    # engine/compact.py), and the runner's row-steps — rows x loop
+    # iterations paid (the compacted runner at each phase's size) and
+    # those whose row had not yet halted, so row_steps_live /
+    # row_steps_paid is the lockstep efficiency
     d2h_bytes: int = 0
+    d2h_transfers: int = 0
     row_steps_paid: int = 0
     row_steps_live: int = 0
 
@@ -686,14 +689,14 @@ def search_seeds(
             state0 = init(seeds, rows) if rows is not None else init(seeds)
         with span("madsim.search.run"):
             if compact:
-                banked = jax.block_until_ready(run.compute(state0))
+                packed = jax.block_until_ready(run.compute(state0))
             else:
                 out, counts = jax.block_until_ready(run(state0))
         verdict_words = None
         with span("madsim.search.fetch"):
             if compact:
-                out = run.assemble(banked, fetch)
-                paid, live = row_steps(banked, fetch)
+                out = run.assemble(packed, fetch)
+                paid, live = row_steps(packed, fetch)
                 fields = RESULT_FIELDS if screens is None else (
                     RESULT_FIELDS + SCREEN_FIELDS
                 )
@@ -855,11 +858,13 @@ def search_seeds(
                 ),
                 lam=np.asarray(view["lam"]) if causal else None,
                 d2h_bytes=fetch.nbytes,
+                d2h_transfers=fetch.transfers,
                 row_steps_paid=paid,
                 row_steps_live=live,
             )
         sp.set_metadata(
             d2h_bytes=report.d2h_bytes,
+            d2h_transfers=report.d2h_transfers,
             row_steps_paid=report.row_steps_paid,
             row_steps_live=report.row_steps_live,
         )

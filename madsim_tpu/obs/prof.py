@@ -39,8 +39,9 @@ The program's spans, nested as they nest in the trace, with what each
 covers and the metadata it carries:
 
 * ``madsim.search`` — one ``engine.search_seeds`` call; at exit it
-  carries ``d2h_bytes``, ``row_steps_paid`` and ``row_steps_live``,
-  the call's ``SearchReport`` counters.
+  carries ``d2h_bytes``, ``d2h_transfers`` (device arrays copied to
+  the host), ``row_steps_paid`` and ``row_steps_live``, the call's
+  ``SearchReport`` counters.
 
   * ``madsim.search.plan`` — ``plan.compile_batch`` and the plan-row
     checks.
@@ -48,8 +49,10 @@ covers and the metadata it carries:
     dispatch of the compiled ``init(seeds[, rows])`` (``jit_init``).
   * ``madsim.search.run`` — dispatch of the runner up to
     ``block_until_ready``.
-  * ``madsim.search.fetch`` — the device→host copies and the scatter
-    into seed order (the lockstep path's screen verdict words too).
+  * ``madsim.search.fetch`` — the device→host copies: a compacted
+    sweep's one packed buffer, already in seed order, and its trimmed
+    history columns (the lockstep path's fields and screen verdict
+    words).
   * ``madsim.search.check`` — invariant, screen verdicts, the
     flagged-history gather, ``history_invariant`` and the report.
 

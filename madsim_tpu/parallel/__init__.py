@@ -316,8 +316,8 @@ def shard_run_compacted(
         return sharded(state)
 
     def run(state):
-        return base.assemble(jax.block_until_ready(compute(state)))
+        return base.assemble_banks(jax.block_until_ready(compute(state)))
 
     run.compute = compute
-    run.assemble = base.assemble
+    run.assemble = base.assemble_banks
     return run

@@ -3,12 +3,13 @@
 ``obs.prof.span`` puts ``madsim.*`` spans on the profiler's clock at the
 layer boundaries of ``search_seeds`` and the device campaign drivers;
 ``SearchReport`` / ``ExploreReport`` carry the counters beside them:
-``d2h_bytes`` (what crossed device->host) and ``row_steps_paid`` /
-``row_steps_live`` (rows x loop iterations the runner paid, and those
-whose row had not yet halted). These tests read a real profiler trace
-back, recount the row-steps on the host from per-iteration halted
-masks, spy on every device->host copy, and pin that neither the spans
-nor the counters change a trace, a verdict or a corpus entry.
+``d2h_bytes`` and ``d2h_transfers`` (what crossed device->host) and
+``row_steps_paid`` / ``row_steps_live`` (rows x loop iterations the
+runner paid, and those whose row had not yet halted). These tests read
+a real profiler trace back, recount the row-steps on the host from
+per-iteration halted masks, spy on every device->host copy, and pin
+that neither the spans nor the counters change a trace, a verdict or a
+corpus entry.
 """
 
 import glob
@@ -207,6 +208,39 @@ def test_d2h_bytes_equal_what_crossed(case, monkeypatch):
         assert len(rep.flagged_idx), "the planted bug must flag seeds"
 
 
+@pytest.mark.parametrize("case, most", [("raft_compact", 1),
+                                        ("kv_compact_screened", 3)])
+def test_compacted_results_cross_in_one_buffer(case, most, monkeypatch):
+    """A compacted sweep's results cross packed in one buffer (plus,
+    under screens, the two trimmed history columns): ``d2h_transfers``
+    counts each device array the spy saw copied."""
+    from jax._src.array import ArrayImpl
+
+    crossed = {}
+    real = ArrayImpl._value
+
+    def spy(self):
+        crossed[id(self)] = self  # held: ids unique
+        return real.fget(self)
+
+    def no_buffer(self, flags):
+        raise BufferError("device memory exports no buffer")
+
+    if case == "raft_compact":
+        wl, cfg, inv, kw = RAFT_WL, RAFT_CFG, (lambda v: v["halted"]), {}
+    else:
+        wl, cfg, inv = KV_WL, KV_CFG, None
+        kw = dict(plan=KV_PLAN, device_check=KV_SCREENS)
+    run = dict(seeds=np.arange(64, dtype=np.uint64), max_steps=600,
+               compact=True, **kw)
+    search_seeds(wl, cfg, inv, **run)  # warm
+    monkeypatch.setattr(ArrayImpl, "_value", property(spy))
+    monkeypatch.setattr(ArrayImpl, "__buffer__", no_buffer)
+    rep = search_seeds(wl, cfg, inv, **run)
+    monkeypatch.undo()
+    assert rep.d2h_transfers == len(crossed) <= most
+
+
 # ---------------------------------------------------------------------------
 # spans in a real trace
 # ---------------------------------------------------------------------------
@@ -268,6 +302,7 @@ def test_search_spans_nest_and_carry_the_counters(tmp_path_factory):
     ]
     assert len([s for s in spans if s[0].startswith("madsim.search")]) == 6
     assert top[3] == {"d2h_bytes": rep.d2h_bytes,
+                      "d2h_transfers": rep.d2h_transfers,
                       "row_steps_paid": rep.row_steps_paid,
                       "row_steps_live": rep.row_steps_live}
     assert 0 < rep.row_steps_live <= rep.row_steps_paid

@@ -18,7 +18,12 @@ from jax.extend import core as jax_core
 from madsim_tpu import explore, models
 from madsim_tpu.chaos import CrashStorm, FaultPlan
 from madsim_tpu.check import device as dc
-from madsim_tpu.engine import EngineConfig, make_init, make_run_while
+from madsim_tpu.engine import (
+    EngineConfig,
+    make_init,
+    make_run_compacted,
+    make_run_while,
+)
 from madsim_tpu.models import BENCH_SPECS, make_kvchaos
 from madsim_tpu.obs import prof
 
@@ -64,6 +69,21 @@ def test_engine_runner(name, layout):
         wl, cfg = getattr(models, f"make_{name}")(), EngineConfig(pool_size=64)
     st = jax.eval_shape(make_init(wl, cfg), np.arange(8, dtype=np.uint64))
     jaxpr = jax.make_jaxpr(make_run_while(wl, cfg, 10, layout=layout))(st)
+    assert batched_bool_scatters(jaxpr.jaxpr) == []
+
+
+@pytest.mark.parametrize("name", sorted(BENCH_SPECS) + ["kvchaos_screened"])
+def test_compacted_runner(name):
+    # the sweep's phase program with its banks put back in seed order
+    # and packed on the device; 8 rows bank three times
+    if name == "kvchaos_screened":
+        (wl, cfg), kw = kvchaos(), {"hist_screen": SCREENS}
+    else:
+        factory, cfg_kw, _, _ = BENCH_SPECS[name]
+        (wl, cfg), kw = (factory(), EngineConfig(**cfg_kw)), {}
+    st = jax.eval_shape(make_init(wl, cfg), np.arange(8, dtype=np.uint64))
+    run = make_run_compacted(wl, cfg, 10, shrink=2, min_size=2, **kw)
+    jaxpr = jax.make_jaxpr(run.compute)(st)
     assert batched_bool_scatters(jaxpr.jaxpr) == []
 
 

@@ -149,6 +149,7 @@ def child(config: str) -> None:
     import numpy as np
 
     from madsim_tpu.engine import (
+        Build,
         EngineConfig,
         make_init,
         make_run_compacted,
@@ -168,8 +169,8 @@ def child(config: str) -> None:
     # the accelerator default, and measured ~8% faster on CPU too —
     # the bench quotes the engine's fastest value-identical program,
     # exactly as it does for layout and compaction
-    t32 = True if time32_eligible(wl, cfg) else None
-    init = make_init(wl, cfg, time32=t32)
+    build = Build(time32=True if time32_eligible(wl, cfg) else None)
+    init = make_init(wl, cfg, build)
 
     # one min_size policy for BOTH platforms, so a config's accelerator
     # and CPU numbers describe the same compaction program
@@ -193,7 +194,7 @@ def child(config: str) -> None:
         # into multi-second dispatches, median wall-per-sim).
         from madsim_tpu.engine.measure import measure_latency
 
-        rec = measure_latency(wl, cfg, n_steps, seed_mod=seed_mod, time32=t32)
+        rec = measure_latency(wl, cfg, n_steps, seed_mod=seed_mod, build=build)
         if rec["overflow"] or not rec["all_halted"]:
             print(
                 json.dumps(
@@ -231,7 +232,7 @@ def child(config: str) -> None:
         # wall is ~CPU_CELL_TARGET_S (capped at the spec seed count) —
         # measure_throughput then packs repeats if the batch is shorter
         run = make_run_compacted(
-            wl, cfg, n_steps, time32=t32,
+            wl, cfg, n_steps, build,
             min_size=_min_size(CPU_CALIBRATE_SEEDS), fields=("now",),
         )
         jax.block_until_ready(
@@ -255,7 +256,7 @@ def child(config: str) -> None:
         wl, cfg, n_steps, n_seeds,
         target_wall_s=5.0 if accel else 3.5,
         n_measure=5 if accel else 3,
-        seed_mod=seed_mod, min_size=_min_size(n_seeds), time32=t32,
+        seed_mod=seed_mod, min_size=_min_size(n_seeds), build=build,
     )
     # the small pool sizes are only valid while nothing overflows; a
     # silent drop would skew the metric. Reported as a JSON error row;

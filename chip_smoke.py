@@ -47,12 +47,12 @@ from madsim_tpu import explore  # noqa: E402
 from madsim_tpu.chaos import CrashStorm, FaultPlan  # noqa: E402
 from madsim_tpu.check import device as dc  # noqa: E402
 from madsim_tpu.engine import (  # noqa: E402
+    Build,
     EngineConfig,
     make_init,
     make_run_compacted,
     search_seeds,
 )
-from madsim_tpu.engine.core import resolve_layout, resolve_time32  # noqa: E402
 from madsim_tpu.engine.oracle import run_oracle  # noqa: E402
 from madsim_tpu.models import BENCH_SPECS, make_kvchaos  # noqa: E402
 from madsim_tpu import parallel  # noqa: E402
@@ -106,11 +106,12 @@ def engine_phase(name: str, n_seeds: int, n_steps: int, n_sample: int,
     ``layout``/``time32`` None = what the default backend resolves."""
     factory, cfg_kw, _, _ = BENCH_SPECS[name]
     wl, cfg = factory(), EngineConfig(**cfg_kw)
-    layout = resolve_layout(layout)
-    time32 = resolve_time32(wl, cfg, time32)
+    # resolved once, on the default backend: the CPU reference below
+    # runs the same lowering
+    build = Build(layout=layout, time32=time32).resolve(wl, cfg)
     dev = jax.devices()[0]
-    init = jax.jit(make_init(wl, cfg, time32=time32))
-    run = make_run_compacted(wl, cfg, n_steps, layout=layout, time32=time32,
+    init = jax.jit(make_init(wl, cfg, build))
+    run = make_run_compacted(wl, cfg, n_steps, build,
                              min_size=min(2048, max(n_seeds // 4, 1)))
     seeds = np.arange(n_seeds, dtype=np.uint64)
 
@@ -121,8 +122,9 @@ def engine_phase(name: str, n_seeds: int, n_steps: int, n_sample: int,
     packed, run_s = timed(prog, st)
     res = run.assemble(packed)
     halted, overflow = int(res.halted.sum()), int(res.overflow.sum())
-    log(f"engine:{name}", seeds=n_seeds, max_steps=n_steps, layout=layout,
-        time32=time32, compile_s=compile_s, init_s=init_s, run_s=run_s,
+    log(f"engine:{name}", seeds=n_seeds, max_steps=n_steps,
+        layout=build.layout, time32=build.time32, compile_s=compile_s,
+        init_s=init_s, run_s=run_s,
         sim_seconds=float(res.now.sum()) / 1e9, halted=halted,
         overflow=overflow, peak_bytes_in_use=mem(dev))
     if halted != n_seeds or overflow:

@@ -18,7 +18,7 @@ PURPOSE_PLAN + plan-slot)`` — a pure function of its coordinates, so
   (seed, plan-slot) pair, no serial state anywhere);
 * compilation is a vectorized numpy pass over the whole seed batch
   (``compile_batch``), feeding the batched engine's pre-seeded pool rows
-  (``engine.make_init(plan_slots=...)``);
+  (``engine.Build(plan_slots=...)``);
 * the same plan drives the single-seed asyncio runtime byte-identically
   at the event level (chaos/nemesis.py) — dual-mode parity.
 
@@ -913,7 +913,7 @@ class ClientArmy:
 
     def retry_spec(self) -> "RetrySpec":
         """The compiled engine-side spec of this army's retry policy
-        (``engine.make_step(retry=...)``). Raises when no policy is
+        (``engine.Build(retry=...)``). Raises when no policy is
         attached — callers use :meth:`FaultPlan.retry_spec` which maps
         None-policy plans to None."""
         if self.retry is None:

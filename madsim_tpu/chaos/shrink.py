@@ -31,14 +31,13 @@ import numpy as np
 import jax
 
 from ..engine.core import (
-    _T32_LIMIT,
+    Build,
     EngineConfig,
     SimState,
     Workload,
-    resolve_time32,
-    make_init,
-    make_run_while,
+    check_plan_horizon,
 )
+from ..engine.search import _compiled_run
 from .plan import LiteralPlan
 
 __all__ = ["ShrinkResult", "shrink_plan"]
@@ -139,25 +138,16 @@ def shrink_plan(
     base = LiteralPlan(events=tuple(events)).compile_batch(
         np.full((b,), seed, np.uint64)
     )
-    if resolve_time32(wl, cfg, None):
-        # same guard as search_seeds(plan=...): under the int32 offset
-        # representation an over-horizon event time would silently wrap
-        lim = _T32_LIMIT - cfg.proc_max_ns - 1
-        worst = max(e.t for e in events)
-        if worst > lim:
-            raise ValueError(
-                f"fault-plan event at t={worst} ns exceeds the int32 "
-                f"time horizon ({lim} ns) active for this (workload, "
-                f"config); shrink the plan windows or disable time32"
-            )
-    dup = plan.uses_dup()
     if retry is None and hasattr(plan, "retry_spec"):
         retry = plan.retry_spec()
-    init = make_init(wl, cfg, plan_slots=p, latency=latency, retry=retry)
-    run = jax.jit(make_run_while(
-        wl, cfg, max_steps, layout=layout, dup_rows=dup, latency=latency,
-        retry=retry,
-    ))
+    build = Build(
+        layout=layout, plan_slots=p, dup_rows=plan.uses_dup(),
+        latency=latency, retry=retry,
+    ).resolve(wl, cfg)
+    # same guard as search_seeds(plan=...): under the int32 offset
+    # representation an over-horizon event time would silently wrap
+    check_plan_horizon(build, cfg, max(e.t for e in events))
+    init, run, _ = _compiled_run(wl, cfg, max_steps, build)
     seeds_b = np.full((b,), seed, np.uint64)
     tested = 0
 
@@ -168,7 +158,7 @@ def shrink_plan(
         tested += nb
         rows = dataclasses.replace(base, valid=np.zeros((b, p), bool))
         rows.valid[:nb] = masks
-        out = jax.block_until_ready(run(init(seeds_b, rows)))
+        out, _ = jax.block_until_ready(run(init(seeds_b, rows)))
         view = {
             f.name: np.asarray(getattr(out, f.name))
             for f in dataclasses.fields(SimState)

@@ -80,13 +80,14 @@ from .core import (  # noqa: E402,F401
     ColumnContract,
     StateContract,
     SLOW_MULT_MAX,
+    Build,
     build_pool_index,
+    check_plan_horizon,
     column_contracts,
     core_fields,
     derived_fields,
     pool_index_eligible,
     pool_tile,
-    resolve_rank_place_max_pool,
     make_init,
     make_run,
     make_run_while,

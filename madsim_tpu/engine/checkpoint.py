@@ -24,9 +24,10 @@ import jax.numpy as jnp
 
 from .core import (
     POOL_INDEX_STATE_FIELDS,
+    Build,
     EngineConfig,
     SimState,
-    _resolve_pool_index,
+    Workload,
     build_pool_index,
     pool_tile,
 )
@@ -90,37 +91,31 @@ def save(path: str, state: SimState, cfg: EngineConfig) -> None:
 
 
 def load(
-    path: str,
-    cfg: EngineConfig,
-    time32: bool | None = None,
-    pool_index: bool | None = None,
-    retry=None,
+    path: str, wl: Workload, cfg: EngineConfig, build: Build = Build()
 ) -> SimState:
-    """Load a SimState; refuses a checkpoint taken under another config.
+    """Load a SimState for a run built from ``build`` (resolved here, so
+    pass the spec the run is built from); refuses a checkpoint taken
+    under another config or another build.
 
-    ``time32``: the representation the resumed run will use (what you
-    will pass to make_run/make_run_while/make_run_compacted). time32
-    auto-resolution is platform-dependent (int32 on accelerators when
-    eligible, int64 on CPU), so a checkpoint saved on one platform can
-    silently mismatch the builder on another; passing it here turns the
-    later step-time dtype TypeError into an immediate, explained error.
-    None skips the check (the manifest still records the saved dtype).
+    ``time32``: the saved ``ev_time`` dtype must be the resolved
+    representation. time32 auto-resolution is platform-dependent (int32
+    on accelerators when eligible, int64 on CPU), so a checkpoint saved
+    on one platform can mismatch the build on another; the refusal here
+    names it rather than a step-time dtype error later.
 
-    ``pool_index``: whether the resumed run carries the readiness-index
-    tile summaries (pass the same value you pass the run builders;
-    None = the same auto rule). The summaries are never read from the
-    file — they are REBUILT here from the loaded pool columns
-    (``engine.build_pool_index``), which is what makes them derived
-    state: the checkpoint format carries only ground truth.
+    ``pool_index``: the readiness-index tile summaries are never read
+    from the file — they are REBUILT here from the loaded pool columns
+    (``engine.build_pool_index``) when the resolved build carries them,
+    which is what makes them derived state: the checkpoint format
+    carries only ground truth.
 
-    ``retry``: the RetrySpec the resumed run will use (what you will
-    pass to the run builders), or None for an off-policy resume. The
-    retry columns are CORE state (armed deadlines are history), so a
-    checkpoint taken under one policy shape cannot resume under
-    another: a mismatch between the saved ``rt_done`` width and the
-    declared ``retry.n_ops`` is refused here with the shape named,
+    ``retry``: the retry columns are CORE state (armed deadlines are
+    history), so a checkpoint taken under one policy shape cannot
+    resume under another: a mismatch between the saved ``rt_done``
+    width and ``retry.n_ops`` is refused here with the shape named,
     rather than surfacing as a jit shape error mid-resume.
     """
+    b = build.resolve(wl, cfg)
     with np.load(path) as data:
         manifest = json.loads(bytes(data[_MANIFEST_KEY]).decode())
         if manifest.get("format") != _FORMAT:
@@ -136,7 +131,7 @@ def load(
             for f in dataclasses.fields(SimState)
             if f.name not in POOL_INDEX_STATE_FIELDS
         }
-    if _resolve_pool_index(cfg, pool_index):
+    if b.pool_index:
         fields["tile_min"], fields["tile_cnt"] = build_pool_index(
             fields["ev_time"], fields["ev_valid"], pool_tile(cfg.pool_size)
         )
@@ -146,18 +141,17 @@ def load(
         fields["tile_cnt"] = jnp.zeros(s, jnp.int32)
     state = SimState(**fields)
     saved_dt = manifest.get("ev_time_dtype", str(np.asarray(state.ev_time).dtype))
-    if time32 is not None:
-        want_dt = "int32" if time32 else "int64"
-        if saved_dt != want_dt:
-            raise ValueError(
-                f"checkpoint ev_time dtype is {saved_dt} but the resumed run "
-                f"was declared time32={time32} ({want_dt}); pass the matching "
-                "explicit time32= to make_run/make_run_while/"
-                "make_run_compacted (auto-resolution is platform-dependent, "
-                "so a checkpoint saved on another platform will not resume "
-                "under the default)"
-            )
+    want_dt = "int32" if b.time32 else "int64"
+    if saved_dt != want_dt:
+        raise ValueError(
+            f"checkpoint ev_time dtype is {saved_dt} but the resumed run "
+            f"resolves time32={b.time32} ({want_dt}); pass the build the "
+            "checkpoint was saved under (auto-resolution is "
+            "platform-dependent, so a checkpoint saved on another platform "
+            "will not resume under the default)"
+        )
     saved_ops = int(np.asarray(state.rt_done).shape[-1])
+    retry = b.retry
     want_ops = 0 if retry is None else int(retry.n_ops)
     if saved_ops != want_ops:
         raise ValueError(
@@ -166,7 +160,7 @@ def load(
             f"{'no retry policy' if retry is None else f'retry.n_ops={want_ops}'}"
             "; armed retry deadlines are core state, so resume with the "
             "checkpoint's own RetrySpec (or an off-policy checkpoint "
-            "off-policy) — pass the matching retry= here and to "
-            "make_run/make_run_while/make_run_compacted"
+            "off-policy) — pass a Build with the matching retry here and "
+            "to the run"
         )
     return state

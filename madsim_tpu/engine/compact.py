@@ -60,7 +60,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .core import EngineConfig, SimState, Workload, make_step
+from .core import Build, EngineConfig, SimState, Workload, make_step
 
 __all__ = ["make_run_compacted"]
 
@@ -271,23 +271,12 @@ def make_run_compacted(
     wl: Workload,
     cfg: EngineConfig,
     max_steps: int,
-    layout: str | None = None,
-    time32: bool | None = None,
+    build: Build = Build(),
+    *,
+    fields: tuple = RESULT_FIELDS,
     shrink: int = 4,
     min_size: int = 2048,
-    fields: tuple = RESULT_FIELDS,
-    dup_rows: bool = False,
-    cov_words: int = 0,
-    metrics: bool = False,
-    timeline_cap: int = 0,
-    cov_hitcount: bool = False,
-    latency=None,
-    placement: str | None = None,
-    pool_index: bool | None = None,
-    rank_place_max_pool: int | None = None,
     hist_screen=None,
-    causal: bool = False,
-    retry=None,
 ):
     """Build ``run(state) -> SimpleNamespace`` of per-original-seed results.
 
@@ -299,7 +288,8 @@ def make_run_compacted(
     ``compute`` is the jitted program, returning a :class:`Packed`
     result that ``assemble`` copies to the host; ``phases`` is the
     untransformed phase program (a list of banks, for a mesh run's
-    ``shard_map``) and ``assemble_banks`` their host assembly.
+    ``shard_map``) and ``assemble_banks`` their host assembly. The
+    state must come from an init built with the same ``build``.
 
     ``shrink``/``min_size`` set the static phase schedule; with
     ``min_size >= n_seeds`` the program degenerates to exactly one
@@ -320,11 +310,7 @@ def make_run_compacted(
     exact-checker escalation (Wing–Gong over flagged seeds) is
     unaffected by construction. Requires ``wl.history``.
     """
-    step = jax.vmap(make_step(
-        wl, cfg, layout, time32, dup_rows, cov_words,
-        metrics, timeline_cap, cov_hitcount, latency, placement,
-        pool_index, rank_place_max_pool, causal, retry=retry,
-    ))
+    step = jax.vmap(make_step(wl, cfg, build))
     all_names = [f.name for f in dataclasses.fields(SimState)]
     for f in fields:
         if f not in all_names:

@@ -52,7 +52,6 @@ replay checker, runtime/mod.rs:165-190).
 from __future__ import annotations
 
 import dataclasses
-import os
 from typing import Callable
 
 import numpy as np
@@ -76,6 +75,7 @@ from .rng import (
 )
 
 __all__ = [
+    "Build",
     "EngineConfig",
     "HistorySpec",
     "LatencySpec",
@@ -136,6 +136,8 @@ __all__ = [
     "make_init",
     "make_step",
     "make_run",
+    "make_run_while",
+    "check_plan_horizon",
     "time32_eligible",
     "DERIVED_STATE_FIELDS",
     "STORAGE_STATE_FIELDS",
@@ -153,7 +155,6 @@ __all__ = [
     "pool_index_eligible",
     "resolve_layout",
     "build_pool_index",
-    "resolve_rank_place_max_pool",
 ]
 
 _INF_NS = np.int64(2**62)
@@ -161,53 +162,17 @@ _INF_32 = np.int32(2**31 - 1)
 _T32_LIMIT = 2**31 - 1  # max future-event offset representable in int32
 
 # Pool-size crossover for the scatter layout's two placement lowerings
-# (see make_step's ``placement``). Rank-matched placement costs fused
+# (see Build's ``placement``). Rank-matched placement costs fused
 # vector passes over the whole pool (O(E) elements, ~60 ns per pass per
 # seed per 64 slots on CPU); scatter-store placement costs one serial
 # row-update per emit slot (~110 ns per row on XLA CPU, independent of
 # E). Measured crossover sits near E ≈ 1k; 512 keeps headroom for wider
-# emit rows (tools/profile_step.py re-measures it per config). This is
-# the DEFAULT of the documented ``make_step(rank_place_max_pool=)``
-# knob; the env var below overrides the default without touching call
-# sites (a deployment knob — program-shaping, so callers that CACHE
-# compiled runs must key on the resolved value: engine.search's
-# _RUN_CACHE folds resolve_rank_place_max_pool() into its key).
+# emit rows (tools/profile_step.py re-measures it per config).
 _RANK_PLACE_MAX_POOL = 512
-_RANK_PLACE_ENV = "MADSIM_RANK_PLACE_MAX_POOL"
-
-
-def _env_int(name: str, default: int) -> int:
-    """A validated non-negative int env override (a deployment typo
-    must name the variable, not crash as a bare int() error or pass
-    through as silent nonsense)."""
-    env = os.environ.get(name)
-    if not env:
-        return default
-    try:
-        val = int(env)
-    except ValueError:
-        raise ValueError(f"{name}={env!r} is not an integer") from None
-    if val < 0:
-        raise ValueError(f"{name}={env!r} must be >= 0")
-    return val
-
-
-def resolve_rank_place_max_pool(override: int | None = None) -> int:
-    """Resolve the rank-placement pool-size crossover (the ``placement``
-    default in :func:`make_step`): explicit ``override`` beats the
-    ``MADSIM_RANK_PLACE_MAX_POOL`` env var beats the measured module
-    default (512). Pinned by tests/test_pool_index.py."""
-    if override is not None:
-        if override < 0:
-            raise ValueError(
-                f"rank_place_max_pool must be >= 0, got {override}"
-            )
-        return int(override)
-    return _env_int(_RANK_PLACE_ENV, _RANK_PLACE_MAX_POOL)
 
 
 # ---------------------------------------------------------------------------
-# Readiness-partitioned pool index (make_step's ``pool_index``). The E
+# Readiness-partitioned pool index (Build's ``pool_index``). The E
 # pool slots split into fixed tiles of T rows; SimState carries per-tile
 # summary columns (tile_min = earliest VALID time in the tile, tile_cnt
 # = number of valid slots), maintained incrementally by the step. The
@@ -242,11 +207,6 @@ POOL_TILE_CANDIDATES = (64, 32, 16, 8)
 # today's lowering — the interleaved A/B (BENCH_AB_r07.txt) measured
 # the crossover between 1024 and 2048 on CPU.
 _POOL_INDEX_MIN_POOL = 1024
-_POOL_INDEX_ENV = "MADSIM_POOL_INDEX_MIN_POOL"
-
-
-def _pool_index_min_pool() -> int:
-    return _env_int(_POOL_INDEX_ENV, _POOL_INDEX_MIN_POOL)
 
 
 def pool_tile(pool_size: int) -> int:
@@ -266,11 +226,9 @@ def pool_index_eligible(cfg: "EngineConfig") -> bool:
 
 
 def resolve_layout(layout: str | None) -> str:
-    """THE layout default (make_step's ``layout=None`` rule): scatter
-    on the CPU backend, dense elsewhere. Shared with every caller that
-    must pre-resolve a build flag against the layout a run will
-    actually compile (engine.search resolves ``pool_index`` through
-    it), so the rule cannot silently fork."""
+    """THE layout default (Build's ``layout=None`` rule): scatter on
+    the CPU backend, dense elsewhere. Called only by
+    :meth:`Build.resolve`."""
     if layout is None:
         return "scatter" if jax.default_backend() == "cpu" else "dense"
     if layout not in ("dense", "scatter"):
@@ -279,27 +237,20 @@ def resolve_layout(layout: str | None) -> str:
 
 
 def _resolve_pool_index(
-    cfg: "EngineConfig", pool_index: bool | None, dense: bool | None = None
+    cfg: "EngineConfig", pool_index: bool | None, dense: bool
 ) -> bool:
-    """Shared by make_init (dense=None: the backend rule, mirroring the
-    layout default) and make_step (dense = the resolved layout). Auto
-    (None) turns the index on only where it wins: the scatter layout's
-    large pools ON THE CPU BACKEND — the backend conjunct keeps a
-    forced ``layout="scatter"`` on an accelerator consistent with
-    make_init's layout-blind resolution (a mismatch the other way —
-    CPU init auto-on + a forced dense step — is absorbed by the
-    off-step's index-preserving rebuild, see make_step). Explicit True
-    on an ineligible pool or under the dense layout is an error, never
-    a silent fallback."""
+    """THE pool-index rule, for the resolved layout (``dense``). Called
+    only by :meth:`Build.resolve`. Auto (None) turns the index on only
+    where it wins: the scatter layout's large pools on the CPU backend.
+    Explicit True on an ineligible pool or under the dense layout is an
+    error, never a silent fallback."""
     tile = pool_tile(cfg.pool_size)
     if pool_index is None:
-        if dense is None:
-            dense = jax.default_backend() != "cpu"
         return (
             bool(tile)
             and not dense
             and jax.default_backend() == "cpu"
-            and cfg.pool_size > _pool_index_min_pool()
+            and cfg.pool_size > _POOL_INDEX_MIN_POOL
         )
     if pool_index:
         if not tile:
@@ -702,14 +653,10 @@ def _retry_backoff_tables(rt: RetrySpec):
     return tuple(boff), tuple(bjit)
 
 
-def _check_retry(wl: "Workload", retry: "RetrySpec | None") -> int:
-    """Validate a retry build parameter; returns n_ops (0 = off).
-
-    Shared by make_init and make_step so no mismatched pair of builders
-    can be constructed (the _check_obs discipline).
-    """
+def _check_retry(wl: "Workload", retry: "RetrySpec | None") -> None:
+    """Validate a retry build parameter (run by :meth:`Build.resolve`)."""
     if retry is None:
-        return 0
+        return
     if not isinstance(retry, RetrySpec):
         raise TypeError(
             f"retry must be a RetrySpec or None, got {type(retry).__name__}"
@@ -721,7 +668,6 @@ def _check_retry(wl: "Workload", retry: "RetrySpec | None") -> int:
             "disarmed by the op's lat_end marker, so a model that never "
             "marks responses would retry forever"
         )
-    return retry.n_ops
 
 
 # MET_HALT_CODE values
@@ -1165,6 +1111,171 @@ class EngineConfig:
         return hashlib.sha256(s).hexdigest()[:16]
 
 
+@dataclasses.dataclass(frozen=True)
+class Build:
+    """Everything a program is built from besides the workload and the
+    config: the lowering and the program-shaping axes.
+
+    Each of :func:`make_init`, :func:`make_step`, :func:`make_run`,
+    :func:`make_run_while`, ``make_run_compacted`` and ``make_sweep``
+    takes one and resolves it first, so an init and a run built from
+    the same Build cannot disagree. Frozen and hashable: a resolved
+    Build keys the compiled-program caches whole.
+
+    The lowering — four value-identical choices of how the same program
+    is written (the oracle suite and the goldens pin the identity);
+    ``None`` means automatic:
+
+    * ``layout`` picks the lowering of the per-event reads/writes.
+      ``"dense"``: one-hot masked reductions and rank-match placement,
+      no gather/scatter ops — TPU lowers batched scatter/gather to
+      serial loops (measured 96% of step wall time,
+      examples/profile_step.py), so dense is ~70x faster there.
+      ``"scatter"``: row-indexed gathers plus ``placement``-selected
+      pool writes, the fast CPU lowering. Auto: scatter on the CPU
+      backend, dense elsewhere.
+    * ``placement`` picks the scatter layout's pool-WRITE lowering
+      (dense ignores it — its one-hot placement is already
+      rank-matched). ``"rank"``: the j-th ready emit pairs with the
+      j-th free slot by cumsum rank, pool columns update through fused
+      gather+select passes — no scatter ops in the hot path (XLA CPU
+      lowers a batched scatter to a serial per-row loop, ~110 ns/row).
+      ``"scatter"``: ``.at[].set`` stores, O(emit rows) each whatever
+      the pool size, so they win once the pool is large. Auto:
+      ``"rank"`` at or below 512 slots when the pool index is off,
+      else ``"scatter"``.
+    * ``pool_index`` adds the two-level readiness index to the pool:
+      per-tile ``tile_min``/``tile_cnt`` summary columns ride SimState
+      (derived by construction, rebuilt on checkpoint restore), the pop
+      runs argmin over E/T tile minima then inside the one winning
+      tile, and placement's free-slot search runs over per-tile free
+      counts — O(E/T + T + emits) per event instead of O(E). Requires
+      a tile-divisible pool (:func:`pool_tile`;
+      ``chaos.FaultPlan.min_pool_size`` sizes army pools aligned) and
+      the scatter layout. Auto: on for CPU scatter pools larger than
+      1024 slots.
+    * ``time32`` picks the representation of pool event times: int32
+      offsets from ``now`` rebased each step (native-width on TPU; v5e
+      emulates 64-bit lanes at ~2x cost; needs
+      :func:`time32_eligible` bounds) or absolute int64 nanoseconds.
+      Auto: :func:`resolve_time32`.
+
+    The axes — each off by default, where it compiles away and values
+    are bit-identical to the plain program:
+
+    * ``plan_slots=P`` reserves P pool rows per seed for a compiled
+      fault plan (madsim_tpu.chaos): ``init(seeds, plan)`` then takes a
+      :class:`PlanRows`. Only init reads it.
+    * ``dup_rows=True`` compiles the message-duplication path
+      (KIND_DUP_ON chaos): each user emit row gets a shadow row that,
+      while the seed's ``dup`` flag is set, inserts a second delivery
+      with an independent latency/loss draw (purpose PURPOSE_DUP+slot).
+    * ``cov_words=CW`` compiles the coverage taps (madsim_tpu.explore):
+      each dispatched event folds behavior features — user (node,
+      previous kind, kind) transitions, (kind, coarse time phase)
+      markers for engine/chaos events, appended history records — into
+      a CW*32-bit per-seed bitmap. ``cov_hitcount=True`` upgrades it to
+      AFL-style hit-count bucketing (a saturating per-feature counter;
+      the bitmap bit is keyed by (feature, bucket class)).
+    * ``metrics=True`` folds the MET_* fleet counters into
+      ``SimState.met``; ``timeline_cap=T`` records the dispatched-event
+      stream into a T-slot ring per seed (overflow counted in
+      ``tl_drop``, decoded by ``obs.decode_timeline``).
+    * ``latency=LatencySpec(...)`` compiles the tail-latency tap:
+      handlers mark client-army op invokes/responses, the engine stamps
+      per-op clocks and folds each completed op into the per-seed
+      log-linear sketch of its invoke-time window; with coverage on,
+      each completion also folds a (window, latency-bucket) feature.
+    * ``causal=True`` folds exact causal provenance: each dispatch's
+      sequence number becomes the ``ev_parent`` of every event it
+      emits, the destination's Lamport clock folds, and — with the
+      ring on — each captured row banks ``tl_seq``/``tl_parent``/
+      ``tl_lam`` (decoded by ``obs.causal``).
+    * ``retry=RetrySpec(...)`` arms the client-retry timers (core
+      state: armed deadlines are history, unlike the taps above).
+    """
+
+    layout: str | None = None
+    placement: str | None = None
+    pool_index: bool | None = None
+    time32: bool | None = None
+    plan_slots: int = 0
+    dup_rows: bool = False
+    cov_words: int = 0
+    cov_hitcount: bool = False
+    metrics: bool = False
+    timeline_cap: int = 0
+    latency: LatencySpec | None = None
+    causal: bool = False
+    retry: RetrySpec | None = None
+
+    def resolve(self, wl: "Workload", cfg: EngineConfig) -> "Build":
+        """This spec with its lowering made concrete for ``(wl, cfg)``
+        on the current backend, after the build checks. Idempotent:
+        resolving a resolved spec returns an equal one."""
+        _check_cov_words(self.cov_words)
+        _check_obs(
+            self.cov_words, self.cov_hitcount, self.timeline_cap,
+            self.latency,
+        )
+        _check_retry(wl, self.retry)
+        layout = resolve_layout(self.layout)
+        pool_index = _resolve_pool_index(
+            cfg, self.pool_index, dense=layout == "dense"
+        )
+        placement = self.placement
+        if placement is None:
+            placement = (
+                "rank"
+                if not pool_index and cfg.pool_size <= _RANK_PLACE_MAX_POOL
+                else "scatter"
+            )
+        if placement not in ("rank", "scatter"):
+            raise ValueError(f"unknown placement {placement!r}")
+        time32 = resolve_time32(wl, cfg, self.time32)
+        if self.retry is not None and time32:
+            # a retry timer's delay must fit the int32 offset form like
+            # any declared timer bound (the delay_bound_ns eligibility
+            # rule)
+            boff, bjit = _retry_backoff_tables(self.retry)
+            worst = int(self.retry.timeout_ns) + boff[-1] + bjit[-1]
+            lim32 = _T32_LIMIT - cfg.proc_max_ns - 1
+            if worst > lim32:
+                raise ValueError(
+                    f"retry timeout + max backoff + max jitter = {worst} ns "
+                    f"exceeds the int32 offset horizon ({lim32} ns); shrink "
+                    f"the policy or build with time32=False"
+                )
+        return dataclasses.replace(
+            self, layout=layout, placement=placement,
+            pool_index=pool_index, time32=time32,
+        )
+
+
+def check_plan_horizon(
+    build: Build, cfg: EngineConfig, worst_ns: int, *, space: bool = False
+) -> None:
+    """Refuse plan event times past the int32 offset horizon of a
+    resolved ``time32`` build, where they would silently wrap.
+    ``space=True`` words the error for a plan space's windows."""
+    if not build.time32:
+        return
+    lim = _T32_LIMIT - cfg.proc_max_ns - 1
+    if worst_ns <= lim:
+        return
+    if space:
+        raise ValueError(
+            f"plan-space window reaches t={worst_ns} ns, past the int32 "
+            f"time horizon ({lim} ns) active for this (workload, "
+            f"config); shrink the windows or disable time32"
+        )
+    raise ValueError(
+        f"fault-plan event at t={worst_ns} ns exceeds the int32 time "
+        f"horizon ({lim} ns) active for this (workload, config); shrink "
+        f"the plan windows or disable time32"
+    )
+
+
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass
 class Emits:
@@ -1304,7 +1415,7 @@ class EmitBuilder:
         self.after(0, KIND_UNSLOW, 0, (a, pack_slow_arg(b, 1)), when)
 
     def dup_on(self, when=True):
-        """Start duplicating messages (needs make_step(dup_rows=True))."""
+        """Start duplicating messages (needs a Build with dup_rows=True)."""
         self.after(0, KIND_DUP_ON, 0, (), when)
 
     def dup_off(self, when=True):
@@ -1870,7 +1981,7 @@ class SimState:
     lat_hist: jnp.ndarray  # (P, B) int32 latency sketch
     lat_count: jnp.ndarray  # () int32 completed ops folded into the sketch
     lat_drop: jnp.ndarray  # () int32 markers with out-of-range op ids (loud)
-    # client-retry columns (make_step's ``retry``; CR = RetrySpec.n_ops,
+    # client-retry columns (Build's ``retry``; CR = RetrySpec.n_ops,
     # 0 with no policy — zero-size, zero cost, bit-identical). CORE
     # state, not derived: rt_done gates re-delivery, so retried
     # trajectories depend on it (see RETRY_STATE_FIELDS). rt_deadline is
@@ -1879,7 +1990,7 @@ class SimState:
     rt_done: jnp.ndarray  # (CR,) bool: response recorded for op
     rt_attempt: jnp.ndarray  # (CR,) int32: last DELIVERED attempt id
     rt_deadline: jnp.ndarray  # (CR,) int64: armed deadline, absolute ns
-    # readiness-partitioned pool index (make_step's ``pool_index``; NT =
+    # readiness-partitioned pool index (Build's ``pool_index``; NT =
     # pool_size/tile when on, else 0 — zero-size, zero cost, the usual
     # off discipline). Derived by construction from (ev_time, ev_valid)
     # — build_pool_index is the definition, checkpoint restore rebuilds
@@ -1924,8 +2035,8 @@ def time32_eligible(wl: Workload, cfg: EngineConfig) -> bool:
 
 
 def resolve_time32(wl: Workload, cfg: EngineConfig, time32: bool | None) -> bool:
-    """THE time32 default (make_step's ``time32=None`` rule), the twin
-    of :func:`resolve_layout`: int32 event times on accelerators
+    """THE time32 default (Build's ``time32=None`` rule), the twin of
+    :func:`resolve_layout`: int32 event times on accelerators
     whenever the bounds allow, int64 on the CPU backend."""
     if time32 is None:
         # int64 is native on CPU; accelerators (v5e has no 64-bit lanes)
@@ -1948,7 +2059,7 @@ class PlanRows:
     """Per-seed fault-plan events, compiled to pre-seeded event-pool rows.
 
     Produced host-side by ``madsim_tpu.chaos`` (FaultPlan.compile_batch)
-    and consumed by the ``init`` built with ``make_init(plan_slots=P)``:
+    and consumed by the ``init`` built with ``Build(plan_slots=P)``:
     slot ``j`` of seed ``s`` becomes pool row ``n_nodes + j`` — an engine
     (or extended-chaos) event at the given absolute time. Invalid rows
     are skipped, so the per-seed event *count* may vary under one static
@@ -1980,8 +2091,8 @@ def _check_obs(
     timeline_cap: int,
     latency: "LatencySpec | None" = None,
 ) -> None:
-    """Observability build-parameter validation — shared by make_init and
-    make_step so no mismatched pair of builders can be constructed."""
+    """Observability build-parameter validation (run by
+    :meth:`Build.resolve`)."""
     if cov_hitcount and not cov_words:
         raise ValueError(
             "cov_hitcount=True needs coverage enabled (cov_words > 0): "
@@ -1996,77 +2107,43 @@ def _check_obs(
         )
 
 
-def make_init(
-    wl: Workload,
-    cfg: EngineConfig,
-    time32: bool | None = None,
-    plan_slots: int = 0,
-    cov_words: int = 0,
-    metrics: bool = False,
-    timeline_cap: int = 0,
-    cov_hitcount: bool = False,
-    latency: LatencySpec | None = None,
-    pool_index: bool | None = None,
-    causal: bool = False,
-    retry: "RetrySpec | None" = None,
-):
+def make_init(wl: Workload, cfg: EngineConfig, build: Build = Build()):
     """Build ``init(seeds) -> SimState`` (batched over the seeds array).
 
     Seeds every node with an on_init event at t=0, mirroring the builder
-    running each node's init task at simulation start. ``time32`` must
-    match the value resolved by :func:`make_step` (both default to the
-    same automatic rule, so callers normally pass neither).
-
-    ``plan_slots=P`` reserves P pool rows per seed for a compiled fault
-    plan (madsim_tpu.chaos): the returned ``init(seeds, plan)`` then
+    running each node's init task at simulation start. The columns are
+    sized by ``build`` (:class:`Build`): build the step from the same
+    spec. With ``plan_slots=P`` the returned ``init(seeds, plan)``
     requires a :class:`PlanRows` whose arrays carry the (S, P) events.
-
-    ``cov_words=CW`` sizes the per-seed coverage bitmap (CW*32 bits,
-    madsim_tpu.explore); must match the step builder's value. 0 (the
-    default) compiles recording away entirely.
-
-    ``metrics``/``timeline_cap``/``cov_hitcount``/``latency`` size the
-    observability columns (madsim_tpu.obs; see the make_step docstring);
-    each must match the step builder's value, and each defaults to off
-    (zero-size arrays, zero cost, bit-identical values).
-
-    ``pool_index`` sizes the readiness-index tile summaries (see the
-    make_step docstring) and must match the step builder's value; both
-    default to the same automatic rule (on for CPU scatter pools larger
-    than the crossover threshold), so callers normally pass neither —
-    but a caller forcing a non-default ``layout`` on an accelerator
-    should pass it explicitly to both, exactly like ``time32``.
-
-    ``causal=True`` sizes the causal-provenance columns (``lam``,
-    ``ev_parent``/``ev_lam`` and — with the ring on — the
-    ``tl_seq``/``tl_parent``/``tl_lam`` ring columns); must match the
-    step builder's value. Plan rows are classed by sentinel at init:
+    Under ``causal`` plan rows are classed by sentinel:
     engine/chaos rows get :data:`PARENT_PLAN`, client-army USER rows
     :data:`PARENT_ARMY`, on_init rows :data:`PARENT_NONE`.
     """
-    n, u, e, k = wl.n_nodes, wl.state_width, cfg.pool_size, wl.max_emits
-    p = plan_slots
+    b = build.resolve(wl, cfg)
+    n, u, e = wl.n_nodes, wl.state_width, cfg.pool_size
+    p = b.plan_slots
     if e < n + p:
         raise ValueError(
             f"pool_size={e} must hold one on_init event per node ({n}) "
             f"plus the {p} fault-plan rows"
         )
     _check_meta_ranges(wl)
-    _check_cov_words(cov_words)
-    _check_obs(cov_words, cov_hitcount, timeline_cap, latency)
-    rt_c = _check_retry(wl, retry)
-    del k
+    cov_words, metrics, timeline_cap = b.cov_words, b.metrics, b.timeline_cap
+    cov_hitcount, latency, causal, retry = (
+        b.cov_hitcount, b.latency, b.causal, b.retry
+    )
+    rt_c = retry.n_ops if retry is not None else 0
     w = wl.payload_words
     h = wl.history.capacity if wl.history is not None else 0
     lat_c = latency.ops if latency is not None else 0
     lat_p = latency.phases if latency is not None else 0
-    tdtype = jnp.int32 if resolve_time32(wl, cfg, time32) else jnp.int64
+    tdtype = jnp.int32 if b.time32 else jnp.int64
     base_state = jnp.asarray(wl.initial_state())
     # sync discipline: a fresh node's disk holds the initial image (the
     # durable columns of init_state are what a cold start reads back)
     d = n if wl.durable_sync else 0
     # readiness index: tile width + count (0 tiles = index off)
-    p_tile = pool_tile(e) if _resolve_pool_index(cfg, pool_index) else 0
+    p_tile = pool_tile(e) if b.pool_index else 0
     n_tiles = e // p_tile if p_tile else 0
 
     def init_one(seed, pt=None, pk=None, pa=None, pv=None, pn=None) -> SimState:
@@ -2270,154 +2347,23 @@ def _trace_fold(trace, now, kind, node, args, pay=None):
     return trace * _TRACE_PRIME + h
 
 
-def make_step(
-    wl: Workload,
-    cfg: EngineConfig,
-    layout: str | None = None,
-    time32: bool | None = None,
-    dup_rows: bool = False,
-    cov_words: int = 0,
-    metrics: bool = False,
-    timeline_cap: int = 0,
-    cov_hitcount: bool = False,
-    latency: LatencySpec | None = None,
-    placement: str | None = None,
-    pool_index: bool | None = None,
-    rank_place_max_pool: int | None = None,
-    causal: bool = False,
-    retry: "RetrySpec | None" = None,
-    _lat_export: bool = False,
-):
+def make_step(wl: Workload, cfg: EngineConfig, build: Build = Build()):
     """Build the single-seed ``step(SimState) -> SimState`` function.
 
     Pops the earliest pending event, dispatches it through
     ``lax.switch`` (engine kinds + user handlers), applies chaos effects,
     and inserts emitted events. ``jax.vmap`` over the seed axis and
-    ``lax.scan`` over steps give the batched run loop.
-
-    ``layout`` picks the *lowering* of the per-event reads/writes — the
-    VALUES are bit-identical either way (the oracle suite asserts it):
-
-    * ``"dense"`` — one-hot masked reductions and rank-match placement,
-      no gather/scatter ops. TPU lowers batched scatter/gather to
-      serial loops (measured 96% of step wall time,
-      examples/profile_step.py), so dense is ~70x faster there.
-    * ``"scatter"`` — row-indexed: gathers for the per-event reads plus
-      ``placement``-selected pool writes, the fast CPU lowering.
-    * ``None`` (default) — scatter on the CPU backend, dense elsewhere.
-
-    ``placement`` picks the scatter layout's pool-WRITE lowering (a
-    third value-identical choice; dense ignores it — its one-hot
-    placement is already rank-matched):
-
-    * ``"rank"`` — rank-matched vector placement: the j-th ready emit
-      pairs with the j-th free slot by cumsum rank, pool columns update
-      through fused gather+select passes and the popped slot is
-      consumed by a masked select. No scatter ops anywhere in the hot
-      path — XLA CPU lowers a batched scatter to a SERIAL per-row
-      update loop (~110 ns/row, measured: ~123 such rows/step were 90%
-      of the pre-PR-8 step wall), while the select forms stay fused
-      vector code.
-    * ``"scatter"`` — the historical ``.at[].set`` stores. Each store
-      costs O(emit rows), independent of pool size, so it WINS once
-      the pool is large (client-army pools, thousands of slots) and
-      the O(E) vector passes dominate instead.
-    * ``None`` (default) — ``"rank"`` when ``cfg.pool_size`` <=
-      :func:`resolve_rank_place_max_pool` (the documented crossover knob:
-      ``rank_place_max_pool=`` here, the ``MADSIM_RANK_PLACE_MAX_POOL``
-      env var, or the measured module default 512) and the readiness
-      index is off, else ``"scatter"``. Under ``pool_index`` the
-      default is ``"scatter"``: placement writes are then O(emits)
-      element stores whatever the pool size, and the measured CPU
-      crossover favors them over the within-tile select chains
-      (``"rank"`` under the index).
-
-    ``pool_index`` adds the two-level readiness index to the pool (the
-    ISSUE-13 tentpole): per-tile ``tile_min``/``tile_cnt`` summary
-    columns ride SimState (derived by construction, rebuilt on
-    checkpoint restore, format unchanged), the pop runs argmin over
-    E/T tile minima then argmin inside the ONE winning tile, and
-    placement's free-slot search runs over per-tile free counts plus a
-    rank match inside the target tiles — O(E/T + T + emits) per event
-    instead of O(E), value-identical by construction (the goldens pin
-    it). ``None`` (default) resolves on for CPU scatter pools larger
-    than 1024 slots (``MADSIM_POOL_INDEX_MIN_POOL`` overrides the
-    threshold), off otherwise — small-pool programs are exactly
-    today's lowering. Requires a tile-divisible pool
-    (:func:`pool_tile`; ``chaos.FaultPlan.min_pool_size`` sizes army
-    pools aligned) and the scatter layout. Must match the ``init``
-    builder's value, like ``time32``.
-
-    ``time32`` picks the *representation* of pool event times — again
-    value-identical (tests/test_engine.py asserts it):
-
-    * ``True`` — ``ev_time`` holds int32 offsets from ``now``, rebased
-      by the clock advance each step. Every per-slot time op (the
-      argmin, the placement selects) becomes native-width on TPU (v5e
-      emulates 64-bit lanes at ~2x cost). Requires
-      :func:`time32_eligible` bounds.
-    * ``False`` — absolute int64 nanoseconds, the natural CPU form.
-    * ``None`` (default) — int32 on accelerators when eligible.
-
-    ``dup_rows=True`` compiles the message-duplication path (KIND_DUP_ON
-    chaos): each user emit row gets a shadow row that, while the seed's
-    ``dup`` flag is set, inserts a second delivery of every send with an
-    independent latency/loss draw (purpose PURPOSE_DUP+slot). The shadow
-    rows cost pool-placement work every step, so they are compiled only
-    when a fault plan actually uses duplication; with the flag off (or
-    ``dup`` never set) values are bit-identical to the plain step.
-
-    ``cov_words=CW`` compiles the coverage taps (madsim_tpu.explore):
-    each dispatched event folds behavior features into the seed's
-    CW*32-bit bitmap — (node, previous kind, kind) transition pairs for
-    user events, (kind, coarse time phase) markers for engine/chaos
-    events (so injected crash/partition phases are coverage), and the
-    (op, key, arg, ok) words of appended history records (term bumps
-    and leader changes become bits). Coverage is derived state only:
-    with CW=0 (default) the block compiles away and values are
-    bit-identical to the pre-coverage step.
-
-    The three observability taps (madsim_tpu.obs) follow the exact same
-    derived-state-only discipline — off (the defaults) means zero-size
-    arrays, zero ops, bit-identical values; on means extra columns that
-    never feed back into the trajectory, the RNG, or the trace:
-
-    * ``metrics=True`` folds the MET_* fleet counters (messages sent/
-      delivered/lost, crashes, pauses, clog-blocked attempts, timer
-      fires, history records, RNG blocks, halt reason) into
-      ``SimState.met`` per seed, sized for on-device fleet reduction.
-    * ``timeline_cap=T`` records the dispatched-event stream — the
-      tuples the trace hash folds — into a T-slot ring per seed
-      (``tl_t``/``tl_meta``/``tl_args``), overflow counted loudly in
-      ``tl_drop``. Decoded host-side by ``obs.decode_timeline``.
-    * ``cov_hitcount=True`` upgrades the coverage taps from set-only to
-      AFL-style hit-count bucketing: each feature keeps a saturating
-      per-seed counter (``cov_hits``) and its bitmap bit is keyed by
-      (feature, bucket class 1/2/3/4-7/8-15/16-31/32-127/128+), so a
-      behavior recurring an order of magnitude more often is new
-      coverage. Changes which bits mean what — campaigns must not mix
-      flag states — but never the trajectory.
-    * ``latency=LatencySpec(ops=C, ...)`` compiles the tail-latency
-      tap: handlers mark client-army op invokes/responses
-      (``EmitBuilder.lat_start/lat_end``), the engine stamps dispatch
-      clocks into the per-op ``lat_inv``/``lat_resp`` columns and folds
-      each completed op's latency into the per-seed log-linear sketch
-      ``lat_hist`` (one histogram per measurement window — the
-      invoke-time phase). When coverage is also on, each completion
-      folds a (window, latency-bucket) feature, so "the tail moved"
-      is new coverage the guided hunt can chase. Out-of-range op ids
-      count loudly in ``lat_drop``.
-    * ``causal=True`` folds exact causal provenance: each dispatch's
-      event-sequence number (``SimState.step`` at dispatch) becomes the
-      ``ev_parent`` of every event it emits, the destination node's
-      Lamport clock folds ``lam[dst] = max(lam[dst], lam_at_emit) + 1``,
-      and — with the ring on — each captured row banks its own seq
-      (``tl_seq``), its parent's seq (``tl_parent``) and the folded
-      clock (``tl_lam``): the exact event-derivation DAG, decoded by
-      ``obs.causal``. When coverage is also on, each dispatch folds a
-      (Lamport-depth bucket, cross-node-jump bucket) feature, so deeper
-      or wider causality is new coverage.
+    ``lax.scan`` over steps give the batched run loop. ``build``
+    (:class:`Build`) picks the lowering and the compiled axes; the
+    state must come from an init built with the same spec.
     """
+    b = build.resolve(wl, cfg)
+    layout, placement, pool_index, time32 = (
+        b.layout, b.placement, b.pool_index, b.time32
+    )
+    dup_rows, cov_words, cov_hitcount = b.dup_rows, b.cov_words, b.cov_hitcount
+    metrics, timeline_cap, latency = b.metrics, b.timeline_cap, b.latency
+    causal, retry = b.causal, b.retry
     n = wl.n_nodes
     k = wl.max_emits
     w = wl.payload_words
@@ -2445,9 +2391,7 @@ def make_step(
     # user purposes prefetched into the per-dispatch RNG block (static)
     user_purposes = tuple(int(p) for p in (wl.draw_purposes or ()))
     _check_meta_ranges(wl)
-    _check_cov_words(cov_words)
-    _check_obs(cov_words, cov_hitcount, timeline_cap, latency)
-    rt_c = _check_retry(wl, retry)
+    rt_c = retry.n_ops if retry is not None else 0
     if rt_c:
         rt_boff, rt_bjit = _retry_backoff_tables(retry)
         rt_kind = np.int32(retry.kind)
@@ -2455,20 +2399,9 @@ def make_step(
         rt_base = np.int32(retry.op_base)
         rt_max = np.int32(retry.max_attempts)
         rt_timeout = np.int64(retry.timeout_ns)
-    layout = resolve_layout(layout)
     dense = layout == "dense"
-    pool_index = _resolve_pool_index(cfg, pool_index, dense=dense)
     p_tile = pool_tile(cfg.pool_size) if pool_index else 0
     n_tiles = cfg.pool_size // p_tile if p_tile else 0
-    if placement is None:
-        placement = (
-            "rank"
-            if (not pool_index)
-            and cfg.pool_size <= resolve_rank_place_max_pool(rank_place_max_pool)
-            else "scatter"
-        )
-    if placement not in ("rank", "scatter"):
-        raise ValueError(f"unknown placement {placement!r}")
     # rank-matched pool writes (scatter layout only; dense has its own
     # one-hot placement). Single-row appends (timeline ring, latency
     # clocks) stay .at[] stores either way — one serial row per step is
@@ -2476,36 +2409,6 @@ def make_step(
     # readiness index, "rank" means the within-tile select-chain
     # variant: the full-pool passes never run.
     rank_place = (not dense) and placement == "rank"
-    if _lat_export:
-        # cold/hot split (make_run's cold_split): the step EXPORTS the
-        # raw latency markers instead of folding them — the (C,)-wide
-        # per-op columns pass through untouched and the run builder
-        # applies them batch-level under a lax.cond, so the cold bank
-        # is read/written only on steps where some seed marked an op.
-        if latency is None or ll == 0:
-            raise ValueError(
-                "_lat_export needs the latency tap (a LatencySpec and "
-                "Workload.lat_markers > 0) — there is nothing to export"
-            )
-        if cov_words:
-            raise ValueError(
-                "cold_split folds latency markers outside the step, so "
-                "the (window, latency-bucket) coverage features cannot "
-                "be computed in-step: use cov_words=0 (bench/obs runs) "
-                "or the in-step latency tap (hunt runs)"
-            )
-    time32 = resolve_time32(wl, cfg, time32)
-    if rt_c and time32:
-        # a retry timer's delay must fit the int32 offset form like any
-        # declared timer bound (the delay_bound_ns eligibility rule)
-        rt_worst = int(rt_timeout) + rt_boff[-1] + rt_bjit[-1]
-        lim32 = _T32_LIMIT - cfg.proc_max_ns - 1
-        if rt_worst > lim32:
-            raise ValueError(
-                f"retry timeout + max backoff + max jitter = {rt_worst} ns "
-                f"exceeds the int32 offset horizon ({lim32} ns); shrink "
-                f"the policy or build with time32=False"
-            )
     t_inf = _INF_32 if time32 else _INF_NS
 
     # -- user branch table -------------------------------------------------
@@ -2603,8 +2506,8 @@ def make_step(
             raise TypeError(
                 f"SimState.ev_time has dtype {st.ev_time.dtype} but this "
                 f"step was built with time32={time32} (expects "
-                f"{jnp.dtype(expected_t).name}); build init/step with "
-                f"matching explicit time32= values"
+                f"{jnp.dtype(expected_t).name}); build init and step "
+                f"from the same Build"
             )
         # pool-index shape guard (the same trace-time rule): an INDEXED
         # step popping a state without matching summaries would be
@@ -2616,9 +2519,7 @@ def make_step(
             raise TypeError(
                 f"SimState carries {st.tile_cnt.shape[0]} pool-index "
                 f"tiles but this step was built with {n_tiles}; build "
-                f"init/step with matching explicit pool_index= values "
-                f"(auto-resolution is backend-dependent, the time32 "
-                f"rule)"
+                f"init and step from the same Build"
             )
         # causal shape guard (the same trace-time rule): a causal step
         # folding zero-size clock columns would be silently wrong
@@ -2626,12 +2527,12 @@ def make_step(
             raise TypeError(
                 f"SimState.lam has shape {st.lam.shape} but this step "
                 f"was built with causal=True (expects ({n},)); build "
-                f"init/step with matching causal= values"
+                f"init and step from the same Build"
             )
         # ---- pop the earliest pending event (the timer-jump of
         # time/mod.rs:45-60 merged with the ready-queue drain) ----
         # Two value-identical lowerings of every per-event read/write
-        # (see the ``layout`` docstring): dense = one-hot masked
+        # (see Build's ``layout``): dense = one-hot masked
         # reductions over the small E or N axis (per-seed dynamic
         # indexing lowers to batched gathers under vmap, ~1 ms/step on
         # TPU, examples/profile_step.py); scatter = plain indexing with
@@ -3742,7 +3643,7 @@ def make_step(
         # here is ever read back by the trajectory, the RNG or the
         # trace, so latency=None runs are bit-identical.
         lat_feats = []  # (feature, on) pairs for the coverage fold
-        if lat_c and not _lat_export:
+        if lat_c:
             lat_inv, lat_resp = st.lat_inv, st.lat_resp
             lat_hist = st.lat_hist
             lat_count, lat_drop = st.lat_count, st.lat_drop
@@ -4217,141 +4118,13 @@ def make_step(
             tile_min=tile_min_out,
             tile_cnt=tile_cnt_out,
         )
-        if _lat_export:
-            # cold/hot split: hand the raw markers of this dispatch to
-            # the run builder — (valid (L,), (op, phase) rows (L, 2),
-            # the dispatch clock). The cold (C,)-wide columns passed
-            # through ``out`` untouched; the batch-level fold applies
-            # them only on steps where some seed actually marked.
-            return out, (user_dispatch & uem.lat_valid, uem.lat, now)
         return out
 
     return step
 
 
-def _make_cold_lat_apply(latency: LatencySpec, ll: int):
-    """Batch-level fold of exported latency markers onto the cold bank.
-
-    The cold/hot split (``make_run(cold_split=True)``): per-seed steps
-    export raw ``(valid, (op, phase), now)`` markers instead of folding
-    them, and this function applies the EXACT in-step semantics —
-    first start wins, first response wins, window = the invoke-time
-    phase, out-of-range ids counted loudly — to the batched
-    ``(S, C)``-wide columns at once. The run builder calls it under a
-    ``lax.cond`` on "any seed marked this step", so the army's cold
-    columns are read and written only on marker steps (they are
-    otherwise not an operand of the scan body at all) — on CPU that
-    skips the work, on TPU it skips the HBM traffic, and the values
-    are bit-identical to the in-step tap by construction
-    (tests/test_pool_index.py pins it).
-    """
-    lat_c = latency.ops
-    lat_p = latency.phases
-    phase_ns = latency.phase_ns
-    edges = jnp.asarray(LAT_EDGES_NS)
-
-    def apply(cold, markers):
-        lat_inv, lat_resp, lat_hist, lat_count, lat_drop = cold
-        mval, mops, mnow = markers  # (S, L) bool, (S, L, 2) i32, (S,) i64
-        rows = jnp.arange(mnow.shape[0])
-        for j in range(ll):
-            mv = mval[:, j]
-            oid = mops[:, j, 0]
-            is_end = mops[:, j, 1] == jnp.int32(1)
-            in_r = (oid >= 0) & (oid < lat_c)
-            lat_drop = lat_drop + (mv & ~in_r).astype(jnp.int32)
-            act = mv & in_r
-            oc = jnp.clip(oid, 0, lat_c - 1)
-            inv_o = jnp.where(in_r, lat_inv[rows, oc], jnp.int64(-1))
-            resp_o = jnp.where(in_r, lat_resp[rows, oc], jnp.int64(-1))
-            do_start = act & ~is_end & (inv_o < 0)
-            do_end = act & is_end & (inv_o >= 0) & (resp_o < 0)
-            d = mnow - inv_o
-            bkt = jnp.sum(
-                (d[:, None] >= edges[None, :]).astype(jnp.int32), axis=1
-            )
-            ph = jnp.clip(
-                (inv_o // jnp.int64(phase_ns)).astype(jnp.int32),
-                0, lat_p - 1,
-            )
-            lat_inv = lat_inv.at[
-                rows, jnp.where(do_start, oc, jnp.int32(lat_c))
-            ].set(mnow, mode="drop")
-            lat_resp = lat_resp.at[
-                rows, jnp.where(do_end, oc, jnp.int32(lat_c))
-            ].set(mnow, mode="drop")
-            lat_hist = lat_hist.at[
-                rows, jnp.where(do_end, ph, jnp.int32(lat_p)), bkt
-            ].add(jnp.int32(1), mode="drop")
-            lat_count = lat_count + do_end.astype(jnp.int32)
-        return (lat_inv, lat_resp, lat_hist, lat_count, lat_drop)
-
-    return apply
-
-
-def _cold_split_body(step, apply):
-    """One scan/while iteration of the cold-split run: advance the hot
-    state, then fold the exported markers onto the cold bank only when
-    some seed marked (the lax.cond is a real device branch — the pred
-    is batch-level scalar, not vmapped)."""
-
-    def body(s: SimState) -> SimState:
-        s2, markers = step(s)
-        cold = (s2.lat_inv, s2.lat_resp, s2.lat_hist, s2.lat_count,
-                s2.lat_drop)
-        cold = lax.cond(
-            jnp.any(markers[0]),
-            lambda op: apply(op[0], op[1]),
-            lambda op: op[0],
-            (cold, markers),
-        )
-        return dataclasses.replace(
-            s2, lat_inv=cold[0], lat_resp=cold[1], lat_hist=cold[2],
-            lat_count=cold[3], lat_drop=cold[4],
-        )
-
-    return body
-
-
-def _resolve_cold_split(
-    wl: Workload, latency, cov_words: int, cold_split: bool
-) -> bool:
-    if not cold_split:
-        return False
-    if latency is None or wl.lat_markers == 0:
-        raise ValueError(
-            "cold_split needs the latency tap: a LatencySpec and a "
-            "workload with lat_markers > 0 (there is no cold bank "
-            "otherwise — the split would be a no-op)"
-        )
-    if cov_words:
-        raise ValueError(
-            "cold_split is incompatible with coverage (cov_words > 0): "
-            "the (window, latency-bucket) coverage features must fold "
-            "in-step; run hunts with the in-step tap and benches/obs "
-            "sweeps with the split"
-        )
-    return True
-
-
 def make_run(
-    wl: Workload,
-    cfg: EngineConfig,
-    n_steps: int,
-    layout: str | None = None,
-    time32: bool | None = None,
-    dup_rows: bool = False,
-    cov_words: int = 0,
-    metrics: bool = False,
-    timeline_cap: int = 0,
-    cov_hitcount: bool = False,
-    latency: LatencySpec | None = None,
-    placement: str | None = None,
-    pool_index: bool | None = None,
-    rank_place_max_pool: int | None = None,
-    cold_split: bool = False,
-    causal: bool = False,
-    retry: "RetrySpec | None" = None,
+    wl: Workload, cfg: EngineConfig, n_steps: int, build: Build = Build()
 ):
     """Build ``run(state) -> state``: n_steps of vmapped lockstep advance.
 
@@ -4366,34 +4139,8 @@ def make_run(
     trajectory that may diverge from the int64 layout. Callers must
     check ``overflow == 0`` before trusting per-seed results (bench.py
     and engine.search do; direct callers are responsible themselves).
-
-    ``cold_split=True`` lands the cold/hot split of the carried scan
-    state: the army latency clocks and the (C,)-wide per-op columns
-    (``lat_inv``/``lat_resp`` and the sketch) move to a cold bank the
-    loop touches only on marker steps — the per-seed step exports raw
-    markers and a batch-level ``lax.cond`` folds them (the exact
-    in-step semantics, bit-identical values). Requires the latency tap
-    and ``cov_words=0``; see :func:`_make_cold_lat_apply`.
     """
-    cold = _resolve_cold_split(wl, latency, cov_words, cold_split)
-    step = jax.vmap(make_step(
-        wl, cfg, layout, time32, dup_rows, cov_words,
-        metrics, timeline_cap, cov_hitcount, latency, placement,
-        pool_index, rank_place_max_pool, causal, retry=retry,
-        _lat_export=cold,
-    ))
-
-    if cold:
-        cbody = _cold_split_body(step, _make_cold_lat_apply(latency, wl.lat_markers))
-
-        def run(state: SimState) -> SimState:
-            def body(s, _):
-                return cbody(s), None
-
-            final, _ = lax.scan(body, state, None, length=n_steps)
-            return final
-
-        return run
+    step = jax.vmap(make_step(wl, cfg, build))
 
     def run(state: SimState) -> SimState:
         def body(s, _):
@@ -4406,23 +4153,7 @@ def make_run(
 
 
 def make_run_while(
-    wl: Workload,
-    cfg: EngineConfig,
-    max_steps: int,
-    layout: str | None = None,
-    time32: bool | None = None,
-    dup_rows: bool = False,
-    cov_words: int = 0,
-    metrics: bool = False,
-    timeline_cap: int = 0,
-    cov_hitcount: bool = False,
-    latency: LatencySpec | None = None,
-    placement: str | None = None,
-    pool_index: bool | None = None,
-    rank_place_max_pool: int | None = None,
-    cold_split: bool = False,
-    causal: bool = False,
-    retry: "RetrySpec | None" = None,
+    wl: Workload, cfg: EngineConfig, max_steps: int, build: Build = Build()
 ):
     """Like :func:`make_run` but stops as soon as every seed has halted.
 
@@ -4436,8 +4167,7 @@ def make_run_while(
     The :func:`make_run` time32 contract applies here too: horizon-
     clamped timer delays are counted in ``state.overflow`` and the run
     silently continues — check ``overflow == 0`` before trusting
-    per-seed results. ``cold_split`` follows the make_run contract
-    (cold latency bank folded batch-level only on marker steps).
+    per-seed results.
 
     ``run.counted(state) -> (final, row_steps)`` is the same loop that
     also returns ``row_steps``, int64 ``[paid, live]``: rows x loop
@@ -4445,17 +4175,7 @@ def make_run_while(
     loop exits on the live count it carries, so counting costs no
     reduction beyond the one the exit test makes anyway.
     """
-    cold = _resolve_cold_split(wl, latency, cov_words, cold_split)
-    step = jax.vmap(make_step(
-        wl, cfg, layout, time32, dup_rows, cov_words,
-        metrics, timeline_cap, cov_hitcount, latency, placement,
-        pool_index, rank_place_max_pool, causal, retry=retry,
-        _lat_export=cold,
-    ))
-    advance = (
-        _cold_split_body(step, _make_cold_lat_apply(latency, wl.lat_markers))
-        if cold else step
-    )
+    step = jax.vmap(make_step(wl, cfg, build))
 
     def counted(state: SimState):
         def cond(carry):
@@ -4464,7 +4184,7 @@ def make_run_while(
 
         def body(carry):
             s, i, live, acc = carry
-            s = advance(s)
+            s = step(s)
             live2 = jnp.sum(~s.halted).astype(jnp.int64)
             return s, i + 1, live2, acc + live
 

@@ -30,7 +30,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from .compact import make_run_compacted
-from .core import EngineConfig, Workload, make_init
+from .core import Build, EngineConfig, Workload, make_init
 
 __all__ = [
     "make_repeat_program",
@@ -45,8 +45,7 @@ def make_repeat_program(
     max_steps: int,
     n_seeds: int,
     seed_mod: int,
-    layout: str | None = None,
-    time32: bool | None = None,
+    build: Build = Build(),
     shrink: int = 4,
     min_size: int = 2048,
 ):
@@ -68,9 +67,9 @@ def make_repeat_program(
     """
     if seed_mod < n_seeds:
         raise ValueError(f"seed_mod={seed_mod} must be >= n_seeds={n_seeds}")
-    init = make_init(wl, cfg, time32)
+    init = make_init(wl, cfg, build)
     run = make_run_compacted(
-        wl, cfg, max_steps, layout, time32,
+        wl, cfg, max_steps, build,
         shrink=shrink, min_size=min_size, fields=("now", "overflow", "halted"),
     )
 
@@ -164,8 +163,7 @@ def measure_throughput(
     seed_base: int = 0,
     seed_mod: int = 131072,
     max_repeats: int = 4096,
-    layout: str | None = None,
-    time32: bool | None = None,
+    build: Build = Build(),
     shrink: int = 4,
     min_size: int = 2048,
 ) -> dict:
@@ -180,7 +178,7 @@ def measure_throughput(
     if it can't hold one batch).
     """
     program = make_repeat_program(
-        wl, cfg, max_steps, n_seeds, seed_mod, layout, time32, shrink, min_size
+        wl, cfg, max_steps, n_seeds, seed_mod, build, shrink, min_size
     )
     repeats, cal_wall, walls, sims, ovf_tot, halted_min = _calibrate_and_measure(
         program, n_seeds, target_wall_s, n_measure, seed_base, max_repeats
@@ -216,8 +214,7 @@ def measure_latency(
     seed_base: int = 0,
     seed_mod: int = 131072,
     max_repeats: int = 131072,
-    layout: str | None = None,
-    time32: bool | None = None,
+    build: Build = Build(),
 ) -> dict:
     """Wall microseconds per complete single-seed sim, sized dispatches.
 
@@ -230,7 +227,7 @@ def measure_latency(
     number to be quotable — callers check.
     """
     program = make_repeat_program(
-        wl, cfg, max_steps, 1, seed_mod, layout, time32, min_size=1
+        wl, cfg, max_steps, 1, seed_mod, build, min_size=1
     )
     # cal_repeats=32: a single 1-seed run is far too short to time
     repeats, cal_wall, walls, sims, ovf_tot, halted_min = _calibrate_and_measure(

@@ -44,9 +44,10 @@ from .compact import (
     row_steps,
 )
 from .core import (
+    Build,
     EngineConfig,
     Workload,
-    resolve_time32,
+    check_plan_horizon,
     make_init,
     make_run_while,
 )
@@ -54,7 +55,7 @@ from .core import (
 __all__ = ["SearchReport", "make_sweep", "search_seeds"]
 
 # compiled-run cache: repeated searches over the same (workload, config,
-# step budget, layout) — the tool's own repro workflow — reuse the XLA
+# step budget, build) — the tool's own repro workflow — reuse the XLA
 # program instead of re-tracing per call (jit's cache keys on function
 # identity, so a fresh closure per call would defeat it). Entries hold
 # obs.prof.AotProgram wrappers, so every build is phase-timed and
@@ -87,95 +88,68 @@ def _screen_prog(screens: tuple):
     return prog
 
 
-def _build_init_run(wl: Workload, cfg: EngineConfig, max_steps: int, *,
-                    layout=None, plan_slots: int = 0, dup_rows: bool = False,
-                    cov_words: int = 0, metrics: bool = False,
-                    timeline_cap: int = 0, cov_hitcount: bool = False,
-                    latency=None, compact: bool = False,
-                    pool_index: bool | None = None, hist_screen=None,
-                    causal: bool = False, retry=None):
-    # the ONE construction of a batched sweep's (init, run) pair —
-    # make_sweep (the device-composable form) and search_seeds' cached
-    # runner both build through here, so a flag added to one path cannot
-    # silently miss the other and break host/device bit-identity
-    if pool_index is None:
-        # resolve pool_index HERE, against the layout this sweep will
-        # actually run (core.resolve_layout — the ONE default rule),
-        # and hand the same concrete bool to init and run: a forced
-        # layout= can then never make the two builders'
-        # auto-resolutions disagree (make_step's trace-time shape
-        # guard would catch it, but loudly failing a sweep over a
-        # resolvable default is worse than resolving it)
-        from .core import _resolve_pool_index, resolve_layout
+def _compiled_run(wl: Workload, cfg: EngineConfig, max_steps: int,
+                  build: Build, compact: bool = False, hist_screen=None):
+    """THE (init, run) pair of a batched sweep, built once per resolved
+    spec and cached: ``(init, run, wl)``, with ``init`` an
+    ``AotProgram`` (``jit_init`` on the device trace) and ``run`` the
+    compacted runner (``compact=True``, ``hist_screen`` embedded) or an
+    ``AotProgram`` of the lockstep ``make_run_while(...).counted``,
+    which returns ``(final state, row steps)``. Every (init, run) user
+    takes its pair from here: ``search_seeds``, ``make_sweep``,
+    ``chaos.shrink_plan`` and the ``obs`` helpers. Plan VALUES are
+    runtime data, so one entry serves every plan of the same width."""
+    b = build.resolve(wl, cfg)
+    key = (id(wl), cfg.hash(), max_steps, compact, b, hist_screen)
+    if key not in _RUN_CACHE:
+        # imported here: obs is a consumer of the engine — a module-level
+        # import would run the whole obs package during engine import
+        from ..obs.prof import AotProgram
 
-        pool_index = _resolve_pool_index(
-            cfg, None, dense=resolve_layout(layout) == "dense"
+        if compact:
+            # make_run_compacted jits internally (its build wall stays
+            # inside dispatch — documented limitation)
+            run = make_run_compacted(
+                wl, cfg, max_steps, b, hist_screen=hist_screen
+            )
+        else:
+            run = AotProgram(
+                "engine.search.run", key,
+                make_run_while(wl, cfg, max_steps, b).counted,
+            )
+        _RUN_CACHE[key] = (
+            # compiled once per batch shape and plan-row structure: run
+            # eagerly, the vmapped init dispatches each of its ~130
+            # primitives on its own
+            AotProgram("engine.search.init", key, make_init(wl, cfg, b)),
+            run,
+            wl,  # keep the workload alive so id() stays unique
         )
-    obs_kw = dict(
-        metrics=metrics, timeline_cap=timeline_cap,
-        cov_hitcount=cov_hitcount, latency=latency, causal=causal,
-        retry=retry,
-    )
-    init = make_init(wl, cfg, plan_slots=plan_slots, cov_words=cov_words,
-                     pool_index=pool_index, **obs_kw)
-    if compact:
-        run = make_run_compacted(
-            wl, cfg, max_steps, layout=layout, dup_rows=dup_rows,
-            cov_words=cov_words, pool_index=pool_index,
-            hist_screen=hist_screen, **obs_kw,
-        )
-    else:
-        # the lockstep path screens AFTER the run, as a separate cached
-        # program over the still-device-resident columns (_screen_prog)
-        run = make_run_while(
-            wl, cfg, max_steps, layout=layout, dup_rows=dup_rows,
-            cov_words=cov_words, pool_index=pool_index, **obs_kw,
-        )
-    return init, run
+    return _RUN_CACHE[key]
 
 
 def make_sweep(
-    wl: Workload,
-    cfg: EngineConfig,
-    max_steps: int,
-    *,
-    layout=None,
-    plan_slots: int = 0,
-    dup_rows: bool = False,
-    cov_words: int = 0,
-    metrics: bool = False,
-    timeline_cap: int = 0,
-    cov_hitcount: bool = False,
-    latency=None,
-    pool_index: bool | None = None,
-    causal: bool = False,
-    retry=None,
+    wl: Workload, cfg: EngineConfig, max_steps: int, build: Build = Build()
 ):
     """Build the traceable batched sweep: ``sweep(seeds[, rows]) -> view``.
 
     The device-resident form of one ``search_seeds`` dispatch: init the
-    seed batch (with the compiled ``PlanRows`` when ``plan_slots > 0``),
-    run ``make_run_while`` to the step cap, and return the final state
-    as a ``{field name: device array}`` view — NO host transfer, no
-    invariant evaluation, and the function is jit/shard_map-traceable,
-    so callers can fuse it into larger device programs (the explore
-    device driver composes it with on-device mutation and admission;
-    ``search_seeds`` wraps it with the host-side report instead). The
-    view also holds ``row_steps``, the loop's int64 ``[paid, live]``
-    row-step counters (``make_run_while``).
+    seed batch (with the compiled ``PlanRows`` when ``build.plan_slots
+    > 0``), run ``make_run_while`` to the step cap, and return the final
+    state as a ``{field name: device array}`` view — NO host transfer,
+    no invariant evaluation, and the function is jit/shard_map-
+    traceable, so callers can fuse it into larger device programs (the
+    explore device campaign composes it with on-device mutation and
+    admission; ``search_seeds`` wraps it with the host-side report
+    instead). It traces the same (init, run) pair ``search_seeds``
+    caches (:func:`_compiled_run`). The view also holds ``row_steps``,
+    the loop's int64 ``[paid, live]`` row-step counters.
     """
-    init, run = _build_init_run(
-        wl, cfg, max_steps, layout=layout, plan_slots=plan_slots,
-        dup_rows=dup_rows, cov_words=cov_words, metrics=metrics,
-        timeline_cap=timeline_cap, cov_hitcount=cov_hitcount,
-        latency=latency, pool_index=pool_index, causal=causal,
-        retry=retry,
-    )
+    init, run, _ = _compiled_run(wl, cfg, max_steps, build)
+    init, counted, plan = init.fn, run.fn, build.plan_slots
 
     def sweep(seeds, rows=None):
-        out, counts = run.counted(
-            init(seeds, rows) if plan_slots else init(seeds)
-        )
+        out, counts = counted(init(seeds, rows) if plan else init(seeds))
         view = {
             f.name: getattr(out, f.name) for f in dataclasses.fields(out)
         }
@@ -183,59 +157,6 @@ def make_sweep(
         return view
 
     return sweep
-
-
-def _compiled_run(wl: Workload, cfg: EngineConfig, max_steps: int, layout,
-                  compact: bool, plan_slots: int = 0, dup_rows: bool = False,
-                  cov_words: int = 0, metrics: bool = False,
-                  timeline_cap: int = 0, cov_hitcount: bool = False,
-                  latency=None, pool_index: bool | None = None,
-                  hist_screen=None, causal: bool = False, retry=None):
-    # plan VALUES are runtime data (PlanRows arrays); only the slot count
-    # and the dup-path flag shape the compiled program, so one cache
-    # entry serves every plan of the same width. The env-defaulted
-    # knobs (pool_index auto threshold, rank-place crossover) are
-    # resolved BEFORE keying: a knob change mid-process must build a
-    # fresh program, not silently reuse one baked under the old value
-    from .core import (
-        _resolve_pool_index,
-        resolve_layout,
-        resolve_rank_place_max_pool,
-    )
-
-    if pool_index is None:
-        pool_index = _resolve_pool_index(
-            cfg, None, dense=resolve_layout(layout) == "dense"
-        )
-    key = (id(wl), cfg.hash(), max_steps, layout, compact, plan_slots,
-           dup_rows, cov_words, metrics, timeline_cap, cov_hitcount,
-           latency, pool_index, resolve_rank_place_max_pool(),
-           hist_screen, causal, retry)
-    if key not in _RUN_CACHE:
-        # imported here: obs is a consumer of the engine — a module-level
-        # import would run the whole obs package during engine import
-        from ..obs.prof import AotProgram
-
-        init, run = _build_init_run(
-            wl, cfg, max_steps, layout=layout, plan_slots=plan_slots,
-            dup_rows=dup_rows, cov_words=cov_words, metrics=metrics,
-            timeline_cap=timeline_cap, cov_hitcount=cov_hitcount,
-            latency=latency, compact=compact, pool_index=pool_index,
-            hist_screen=hist_screen, causal=causal, retry=retry,
-        )
-        # make_run_compacted jits internally per growth stage (its
-        # build wall stays inside dispatch — documented limitation)
-        _RUN_CACHE[key] = (
-            # compiled once per batch shape and plan-row structure
-            # (jit_init on the device trace): run eagerly, the vmapped
-            # init dispatches each of its ~130 primitives on its own
-            AotProgram("engine.search.init", key, init),
-            # the lockstep program returns (final state, row steps)
-            run if compact
-            else AotProgram("engine.search.run", key, run.counted),
-            wl,  # keep the workload alive so id() stays unique
-        )
-    return _RUN_CACHE[key]
 
 
 @dataclasses.dataclass
@@ -543,22 +464,21 @@ def search_seeds(
     invariant). All of them are derived state only — the traces and
     verdicts are bit-identical with them off or on.
 
-    ``pool_index`` picks the readiness-partitioned pool lowering
-    (make_step docstring; value-identical, auto on for CPU scatter
-    pools past the crossover) — it keys the compiled-run cache like
-    every other build flag.
+    ``layout`` and ``pool_index`` pick the lowering and the other
+    keywords the compiled axes (``engine.Build`` docstring); the
+    resolved spec keys the compiled-run cache.
 
-    ``retry`` arms the client-retry timers (``engine.RetrySpec``, make_
-    step docstring). With ``plan`` it defaults to the plan's own policy
-    — ``plan.retry_spec()`` when some army carries a
+    ``retry`` arms the client-retry timers (``engine.RetrySpec``,
+    ``engine.Build`` docstring). With ``plan`` it defaults to the
+    plan's own policy — ``plan.retry_spec()`` when some army carries a
     ``chaos.RetryPolicy`` — so a policied plan sweeps retried without
     further wiring; pass ``retry=`` explicitly on the ``plan_rows``
     path (pre-compiled rows carry no policy object).
 
-    ``causal=True`` folds exact causal provenance (make_step docstring):
-    the final per-node Lamport clocks return as ``report.lam`` (S, N)
-    and — with ``timeline_cap`` — the ring gains the
-    ``tl_seq``/``tl_parent``/``tl_lam`` DAG columns, which
+    ``causal=True`` folds exact causal provenance (``engine.Build``
+    docstring): the final per-node Lamport clocks return as
+    ``report.lam`` (S, N) and — with ``timeline_cap`` — the ring gains
+    the ``tl_seq``/``tl_parent``/``tl_lam`` DAG columns, which
     ``obs.causal.causal_slice`` turns into the backward happens-before
     cone of a violation. Derived state only, like every tap here.
 
@@ -661,30 +581,27 @@ def search_seeds(
                 rows = None
                 plan_slots = 0
                 dup_rows = bool(dup_rows)
-            if rows is not None and resolve_time32(wl, cfg, None):
-                # the compiled rows land in the int32 offset representation:
-                # a plan event past the horizon would silently wrap
-                from .core import _T32_LIMIT
-
-                lim = _T32_LIMIT - cfg.proc_max_ns - 1
-                worst = int(fetch(rows.time).max(initial=0))
-                if worst > lim:
-                    raise ValueError(
-                        f"fault-plan event at t={worst} ns exceeds the "
-                        f"int32 time horizon ({lim} ns) active for this "
-                        f"(workload, config); shrink the plan windows or "
-                        f"disable time32"
-                    )
+            build = Build(
+                layout=layout, pool_index=pool_index, plan_slots=plan_slots,
+                dup_rows=dup_rows, cov_words=cov_words,
+                cov_hitcount=cov_hitcount, metrics=metrics,
+                timeline_cap=timeline_cap, latency=latency, causal=causal,
+                retry=retry,
+            ).resolve(wl, cfg)
+            if rows is not None and build.time32:
+                # the compiled rows land in the int32 offset
+                # representation: a plan event past the horizon would
+                # silently wrap
+                check_plan_horizon(
+                    build, cfg, int(fetch(rows.time).max(initial=0))
+                )
         with span("madsim.search.init"):
             init, run, _ = _compiled_run(
-                wl, cfg, max_steps, layout, compact, plan_slots, dup_rows,
-                cov_words, metrics, timeline_cap, cov_hitcount, latency,
-                pool_index,
+                wl, cfg, max_steps, build, compact,
                 # only the compacted program embeds the screen (bank-time
                 # fold); the lockstep path screens via _screen_prog, so its
                 # run cache entry must stay shared with unscreened sweeps
                 hist_screen=screens if compact else None,
-                causal=causal, retry=retry,
             )
             state0 = init(seeds, rows) if rows is not None else init(seeds)
         with span("madsim.search.run"):

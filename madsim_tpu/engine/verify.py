@@ -15,7 +15,14 @@ import numpy as np
 import jax
 
 from ..runtime.rand import DeterminismError
-from .core import EngineConfig, Workload, make_init, make_run, time32_eligible
+from .core import (
+    Build,
+    EngineConfig,
+    Workload,
+    make_init,
+    make_run,
+    time32_eligible,
+)
 
 __all__ = [
     "HISTORY_FIELDS",
@@ -97,7 +104,7 @@ def check_layouts(
     wl: Workload, cfg: EngineConfig, seeds, n_steps: int
 ) -> None:
     """Run the workload through BOTH step lowerings (dense and scatter,
-    see make_step's ``layout``) and raise on any trace divergence.
+    see Build's ``layout``) and raise on any trace divergence.
 
     The library form of the cross-backend determinism check
     (examples/cross_backend_check.py runs it across real silicon): the
@@ -106,22 +113,22 @@ def check_layouts(
     gather/scatter semantics diverge from the dense masks.
     """
     seeds = np.asarray(seeds, np.uint64)
-    variants = [("dense", False), ("scatter", False)]
-    if time32_eligible(wl, cfg):
-        # the int32 offset representation is a third value-identical
-        # lowering (make_step's ``time32``); cross it with both layouts
-        variants += [("dense", True), ("scatter", True)]
+    # pool_index pinned OFF: the dense layout has no tile index, and
+    # this check's subject is the dense/scatter duality — the indexed
+    # lowering has its own on/off identity pins
+    # (tests/test_pool_index.py, lint-soak cert 1c). The int32 offset
+    # representation is a third value-identical lowering (``time32``);
+    # where eligible it is crossed with both layouts.
+    variants = [
+        Build(layout=layout, time32=t32, pool_index=False)
+        for t32 in ((False, True) if time32_eligible(wl, cfg) else (False,))
+        for layout in ("dense", "scatter")
+    ]
     runs = {}
-    for layout, t32 in variants:
-        # pool_index pinned OFF: the dense layout has no tile index,
-        # and this check's subject is the dense/scatter duality — the
-        # indexed lowering has its own on/off identity pins
-        # (tests/test_pool_index.py, lint-soak cert 1c)
-        init = make_init(wl, cfg, time32=t32, pool_index=False)
-        runs[(layout, t32)] = jax.jit(
-            make_run(wl, cfg, n_steps, layout=layout, time32=t32,
-                     pool_index=False)
-        )(init(seeds))
+    for build in variants:
+        runs[(build.layout, build.time32)] = jax.jit(
+            make_run(wl, cfg, n_steps, build)
+        )(make_init(wl, cfg, build)(seeds))
     base_key = ("dense", False)
     base = runs[base_key]
     for key, other in runs.items():

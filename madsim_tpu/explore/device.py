@@ -66,6 +66,7 @@ runs ``make_run_while``).
 
 from __future__ import annotations
 
+import dataclasses as _dc
 import os as _os
 import time as _time
 
@@ -77,7 +78,7 @@ from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P_
 
 from ..chaos.plan import FaultEvent, FaultPlan, LiteralPlan, stack_plan_rows
-from ..engine.core import PlanRows, resolve_time32
+from ..engine.core import Build, PlanRows, check_plan_horizon
 from ..engine.rng import PURPOSE_EXPLORE, threefry2x32
 from ..engine.search import make_sweep
 from ..obs.prof import span
@@ -471,12 +472,12 @@ def _gen_programs(key, builder):
 
 
 def _build_programs(
-    wl, cfg, space, *, invariant, batch, max_steps, cov_words, layout,
-    require_halt, select_top, max_corpus, vcap, max_ops, inherit_seed_p,
-    cov_hitcount, metrics, latency, mesh, seed_corpus, cache_key,
-    pool_index=None, history_check=None, causal=False, retry=None,
+    wl, cfg, space, build, *, invariant, batch, max_steps, require_halt,
+    select_top, max_corpus, vcap, max_ops, inherit_seed_p, mesh,
+    seed_corpus, cache_key, history_check=None,
 ):
-    """Build one cache entry: the (uniform, breed, refs) triple.
+    """Build one cache entry: the (uniform, breed, refs) triple, the
+    generations' sweep built from ``build`` (a resolved ``Build``).
 
     Both programs take ``(carry, g, rk0, rk1)`` — the generation index
     and the campaign root key are runtime arguments (same threefry
@@ -489,20 +490,14 @@ def _build_programs(
     n_dev = int(mesh.devices.size) if mesh is not None else 1
     b_loc = batch // n_dev
     axes = mesh.axis_names if mesh is not None else None
-    p_slots = space.slots
-    dup = space.uses_dup()
+    p_slots = build.plan_slots
     cmax1 = max_corpus + 1
     vcap1 = vcap + 1
     tb = {k: jnp.asarray(v) for k, v in mutation_table(space).items()}
     mutator = _make_child_mutator(
         tb, max_ops, inherit_threshold(inherit_seed_p)
     )
-    sweep = make_sweep(
-        wl, cfg, max_steps, layout=layout, plan_slots=p_slots,
-        dup_rows=dup, cov_words=cov_words, metrics=metrics,
-        timeline_cap=0, cov_hitcount=cov_hitcount, latency=latency,
-        pool_index=pool_index, causal=causal, retry=retry,
-    )
+    sweep = make_sweep(wl, cfg, max_steps, build)
     k_ov = len(seed_corpus)
     if k_ov:
         ov = stack_plan_rows([_pad_literal(lp, p_slots) for lp in seed_corpus])
@@ -549,9 +544,9 @@ def _build_programs(
             # trajectories dropped events, so their bitmaps are artifacts
             cov=jnp.where(over[:, None], jnp.uint32(0), view["cov"]),
         )
-        if metrics:
+        if build.metrics:
             cols["met"] = view["met"]
-        if latency is not None:
+        if build.latency is not None:
             cols["lat_hist"] = view["lat_hist"]
         # this shard's [paid, live] row-steps: on a mesh they stay
         # sharded, (2 x devices,), and the host sums them at the sync
@@ -752,7 +747,7 @@ def _build_programs(
         }
         return cr2, summary, extras
 
-    refs = (wl, invariant, mesh, latency, space)
+    refs = (wl, invariant, mesh, space)
     return (
         AotProgram(
             "explore.device.uniform", (cache_key, "uniform"),
@@ -785,11 +780,10 @@ class _CampaignSession:
     """
 
     def __init__(
-        self, wl, cfg, space, *, invariant, generations, batch, root_seed,
-        max_steps, cov_words, layout, require_halt, seed_corpus, select_top,
-        max_corpus, max_ops, inherit_seed_p, log, cov_hitcount, telemetry,
-        resume, checkpoint_path, latency, metrics, mesh, viol_cap,
-        pool_index, history_check, causal=False,
+        self, wl, cfg, space, build, *, invariant, generations, batch,
+        root_seed, max_steps, require_halt, seed_corpus, select_top,
+        max_corpus, max_ops, inherit_seed_p, log, telemetry, resume,
+        checkpoint_path, mesh, viol_cap, history_check,
     ):
         if isinstance(space, FaultPlan):
             space = PlanSpace(space)
@@ -809,6 +803,7 @@ class _CampaignSession:
                 "program); arbitrary host-side history_invariant callables "
                 "need the host driver — use explore.run for those hunts"
             )
+        cov_words, cov_hitcount = build.cov_words, build.cov_hitcount
         if cov_words < 1:
             raise ValueError(
                 "exploration needs cov_words >= 1 (the guidance)"
@@ -825,13 +820,17 @@ class _CampaignSession:
                 f"batch={batch} does not split over {n_dev} mesh devices"
             )
         vcap = int(viol_cap) if viol_cap is not None else int(max_corpus)
-        # derive the engine retry build flag from the space plan's
-        # ClientArmy policy (the host driver's rule; LiteralPlan spaces
-        # have no retry_spec and run fire-and-forget)
-        retry = (
-            space.plan.retry_spec() if hasattr(space.plan, "retry_spec")
-            else None
-        )
+        # the sweep's plan axes come from the space: its slot count,
+        # duplication, and the retry flag derived from its ClientArmy
+        # policy (explore.run's rule; LiteralPlan spaces have no
+        # retry_spec and run fire-and-forget)
+        build = _dc.replace(
+            build, plan_slots=space.slots, dup_rows=space.uses_dup(),
+            retry=(
+                space.plan.retry_spec()
+                if hasattr(space.plan, "retry_spec") else None
+            ),
+        ).resolve(wl, cfg)
         p_slots = space.slots
         cmax1 = int(max_corpus) + 1
         vcap1 = vcap + 1
@@ -841,23 +840,14 @@ class _CampaignSession:
         # horizon (checked statically over the template windows —
         # mutation and compilation both stay inside them)
         space.plan.compile_batch(np.zeros(1, np.uint64), wl=wl)
-        if resolve_time32(wl, cfg, None):
-            from ..engine.core import _T32_LIMIT
-
-            tb_np = mutation_table(space)
-            lim = _T32_LIMIT - cfg.proc_max_ns - 1
-            worst = int(tb_np["t_hi"].max(initial=1)) - 1
+        if build.time32:
+            worst = int(mutation_table(space)["t_hi"].max(initial=1)) - 1
             if seed_corpus:
                 worst = max(
                     worst,
                     max(e.t for lp in seed_corpus for e in lp.events),
                 )
-            if worst > lim:
-                raise ValueError(
-                    f"plan-space window reaches t={worst} ns, past the int32 "
-                    f"time horizon ({lim} ns) active for this (workload, "
-                    f"config); shrink the windows or disable time32"
-                )
+            check_plan_horizon(build, cfg, worst, space=True)
 
         # ---- resumed / fresh host mirrors ----
         loaded_corpus: list = []
@@ -931,11 +921,9 @@ class _CampaignSession:
         k_ov = len(seed_corpus)
         key = (
             id(wl), id(invariant), cfg.hash(), space.hash(), batch,
-            max_steps, cov_words, layout, require_halt, select_top,
-            int(max_corpus), vcap, max_ops, float(inherit_seed_p),
-            bool(cov_hitcount), bool(metrics), latency, _mesh_key(mesh),
-            tuple(lp.hash() for lp in seed_corpus), pool_index,
-            bool(causal), retry,
+            max_steps, build, require_halt, select_top, int(max_corpus),
+            vcap, max_ops, float(inherit_seed_p), _mesh_key(mesh),
+            tuple(lp.hash() for lp in seed_corpus),
             # invariant identity of the device history screen: screens
             # are value-hashable literals, so equal screen sets share
             # programs across campaigns (the ROADMAP "invariant
@@ -945,15 +933,12 @@ class _CampaignSession:
         self.prog_uniform, self.prog_breed = _gen_programs(
             key,
             lambda: _build_programs(
-                wl, cfg, space, invariant=invariant, batch=batch,
-                max_steps=max_steps, cov_words=cov_words, layout=layout,
-                require_halt=require_halt, select_top=select_top,
-                max_corpus=int(max_corpus), vcap=vcap, max_ops=max_ops,
-                inherit_seed_p=inherit_seed_p, cov_hitcount=cov_hitcount,
-                metrics=metrics, latency=latency, mesh=mesh,
-                seed_corpus=seed_corpus, cache_key=key,
-                pool_index=pool_index, history_check=history_check,
-                causal=causal, retry=retry,
+                wl, cfg, space, build, invariant=invariant, batch=batch,
+                max_steps=max_steps, require_halt=require_halt,
+                select_top=select_top, max_corpus=int(max_corpus),
+                vcap=vcap, max_ops=max_ops, inherit_seed_p=inherit_seed_p,
+                mesh=mesh, seed_corpus=seed_corpus, cache_key=key,
+                history_check=history_check,
             ),
         )
 
@@ -1231,17 +1216,19 @@ def run_device(
     """
     with span("madsim.explore.start"):
         sess = _CampaignSession(
-            wl, cfg, space, invariant=invariant, generations=generations,
-            batch=batch, root_seed=root_seed, max_steps=max_steps,
-            cov_words=cov_words, layout=layout, require_halt=require_halt,
-            seed_corpus=seed_corpus, select_top=select_top,
-            max_corpus=max_corpus, max_ops=max_ops,
-            inherit_seed_p=inherit_seed_p, log=log,
-            cov_hitcount=cov_hitcount, telemetry=telemetry, resume=resume,
-            checkpoint_path=checkpoint_path, latency=latency,
-            metrics=metrics, mesh=mesh, viol_cap=viol_cap,
-            pool_index=pool_index, history_check=history_check,
-            causal=causal,
+            wl, cfg, space,
+            Build(
+                layout=layout, pool_index=pool_index, cov_words=cov_words,
+                cov_hitcount=cov_hitcount, metrics=metrics, latency=latency,
+                causal=causal,
+            ),
+            invariant=invariant, generations=generations, batch=batch,
+            root_seed=root_seed, max_steps=max_steps,
+            require_halt=require_halt, seed_corpus=seed_corpus,
+            select_top=select_top, max_corpus=max_corpus, max_ops=max_ops,
+            inherit_seed_p=inherit_seed_p, log=log, telemetry=telemetry,
+            resume=resume, checkpoint_path=checkpoint_path, mesh=mesh,
+            viol_cap=viol_cap, history_check=history_check,
         )
         sess.start("device")
 

@@ -353,7 +353,7 @@ def run(
     generation.
 
     ``cov_hitcount=True`` runs the engine's AFL-style hit-count
-    bucketing (make_step docstring): recurrence-magnitude changes
+    bucketing (``engine.Build`` docstring): recurrence-magnitude changes
     become fresh coverage, at the cost of a per-seed counter column.
 
     ``telemetry`` (callable, e.g. ``obs.JsonlSink(path)``) receives one

@@ -51,6 +51,7 @@ import time as _time
 import jax
 import jax.numpy as jnp
 
+from ..engine.core import Build
 from ..explore.device import _CampaignSession
 from ..explore.driver import ExploreReport
 from ..obs.prof import span
@@ -109,17 +110,19 @@ def run_pipelined(
         raise ValueError("need pipeline depth >= 1")
     with span("madsim.explore.start"):
         sess = _CampaignSession(
-            wl, cfg, space, invariant=invariant, generations=generations,
-            batch=batch, root_seed=root_seed, max_steps=max_steps,
-            cov_words=cov_words, layout=layout, require_halt=require_halt,
-            seed_corpus=seed_corpus, select_top=select_top,
-            max_corpus=max_corpus, max_ops=max_ops,
-            inherit_seed_p=inherit_seed_p, log=log,
-            cov_hitcount=cov_hitcount, telemetry=telemetry, resume=resume,
-            checkpoint_path=checkpoint_path, latency=latency,
-            metrics=metrics, mesh=mesh, viol_cap=viol_cap,
-            pool_index=pool_index, history_check=history_check,
-            causal=causal,
+            wl, cfg, space,
+            Build(
+                layout=layout, pool_index=pool_index, cov_words=cov_words,
+                cov_hitcount=cov_hitcount, metrics=metrics, latency=latency,
+                causal=causal,
+            ),
+            invariant=invariant, generations=generations, batch=batch,
+            root_seed=root_seed, max_steps=max_steps,
+            require_halt=require_halt, seed_corpus=seed_corpus,
+            select_top=select_top, max_corpus=max_corpus, max_ops=max_ops,
+            inherit_seed_p=inherit_seed_p, log=log, telemetry=telemetry,
+            resume=resume, checkpoint_path=checkpoint_path, mesh=mesh,
+            viol_cap=viol_cap, history_check=history_check,
         )
         sess.log_label = "pipelined"
         sess.start("device-pipelined", pipeline_depth=depth)

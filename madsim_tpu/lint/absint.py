@@ -76,6 +76,7 @@ from jax.extend import core as jax_core
 
 from ..engine.core import (
     ABSINT_HORIZON_NS,
+    Build,
     EngineConfig,
     LatencySpec,
     Workload,
@@ -83,10 +84,16 @@ from ..engine.core import (
     make_init,
     make_run,
     make_step,
-    pool_index_eligible,
-    time32_eligible,
 )
 from ..engine import rng as _rng
+from .noninterference import (
+    BASE_LOWERING,
+    LAYOUT_AXES,
+    _leaf_names,
+    lowering_eligible,
+    report_flags,
+    split_axis,
+)
 from .rules import DEFAULT_PATHS, _pragma_entries
 
 __all__ = [
@@ -1183,19 +1190,9 @@ class AbsintReport:
 def check_ranges(
     wl: Workload,
     cfg: EngineConfig,
+    build: Build = BASE_LOWERING,
     *,
     entry: str = "step",
-    layout: str = "scatter",
-    time32: bool = False,
-    placement: str | None = None,
-    pool_index: bool | None = None,
-    dup_rows: bool = False,
-    cov_words: int = 0,
-    metrics: bool = False,
-    timeline_cap: int = 0,
-    cov_hitcount: bool = False,
-    latency: LatencySpec | None = None,
-    causal: bool = False,
     horizon_ns: int | None = None,
     n_steps: int = 4,
     n_seeds: int = 2,
@@ -1209,38 +1206,14 @@ def check_ranges(
     to their contracts — the assume-guarantee boundary). ``mutate``
     wraps the traced function, the planted-mutant hook shared with the
     taint proof."""
-    flags = dict(
-        layout=layout, time32=time32, placement=placement,
-        pool_index=pool_index, dup_rows=dup_rows, cov_words=cov_words,
-        metrics=metrics, timeline_cap=timeline_cap,
-        cov_hitcount=cov_hitcount, causal=causal,
-        latency=(
-            (latency.ops, latency.phases, latency.phase_ns)
-            if latency is not None else None
-        ),
-    )
-    obs_kw = dict(
-        dup_rows=dup_rows, cov_words=cov_words, metrics=metrics,
-        timeline_cap=timeline_cap, cov_hitcount=cov_hitcount,
-        latency=latency, causal=causal,
-    )
-    init = make_init(
-        wl, cfg, time32=time32, cov_words=cov_words, metrics=metrics,
-        timeline_cap=timeline_cap, cov_hitcount=cov_hitcount,
-        latency=latency, pool_index=pool_index, causal=causal,
-    )
+    flags = report_flags(build)
+    init = make_init(wl, cfg, build)
     state = init(np.zeros(max(n_seeds, 1), np.uint64))
     if entry == "step":
-        fn = make_step(
-            wl, cfg, layout=layout, time32=time32, placement=placement,
-            pool_index=pool_index, **obs_kw,
-        )
+        fn = make_step(wl, cfg, build)
         template = jax.tree.map(lambda a: a[0], state)
     elif entry == "run":
-        fn = make_run(
-            wl, cfg, n_steps, layout=layout, time32=time32,
-            placement=placement, pool_index=pool_index, **obs_kw,
-        )
+        fn = make_run(wl, cfg, n_steps, build)
         template = state
     else:
         raise ValueError(f"unknown entry {entry!r} (step or run)")
@@ -1250,11 +1223,9 @@ def check_ranges(
     with _rng.lane_site_tracing():
         closed = jax.make_jaxpr(fn)(template)
 
-    from .noninterference import _leaf_names
-
     names = _leaf_names(template)
     contracts = column_contracts(
-        wl, cfg, time32=bool(time32), horizon_ns=horizon_ns
+        wl, cfg, time32=bool(build.time32), horizon_ns=horizon_ns
     )
     in_vals = []
     for name, var in zip(names, closed.jaxpr.invars):
@@ -1375,8 +1346,8 @@ def run_mutant_controls() -> list:
         pool_size=40, loss_p=0.02, clog_backoff_max_ns=2_000_000_000
     )
     rep_sd = check_ranges(
-        wl, cfg, entry="step", layout="scatter", time32=True,
-        pool_index=True, mutate=plant_time32_sentinel_decay,
+        wl, cfg, Build(layout="scatter", time32=True, pool_index=True),
+        entry="step", mutate=plant_time32_sentinel_decay,
     )
     caught_sd = not rep_sd.ok and any(
         f["rule"] == OVERFLOW_RULE
@@ -1385,8 +1356,7 @@ def run_mutant_controls() -> list:
         for f in rep_sd.findings
     )
     rep_lc = check_ranges(
-        wl, cfg, entry="step", layout="scatter",
-        mutate=plant_lane_collision,
+        wl, cfg, entry="step", mutate=plant_lane_collision,
     )
     caught_lc = not rep_lc.ok and any(
         f["rule"] == LANE_RULE and len(f.get("sites", [])) == 2
@@ -1439,7 +1409,7 @@ def absint_model_matrix() -> list:
 def absint_matrix(
     models=None,
     axes=None,
-    layouts=None,
+    layouts: tuple = LAYOUT_AXES,
     *,
     entry: str = "step",
     log=None,
@@ -1447,32 +1417,24 @@ def absint_matrix(
 ) -> list:
     """Run the range proof over a model x build-flag x lowering matrix.
 
-    ``layouts`` takes the same (layout, time32[, placement[,
-    pool_index]]) tuples as ``noninterference.check_matrix``
-    (``LAYOUT_AXES`` is the full set); ineligible (model, lowering)
-    pairs are skipped, not failed."""
-    from .noninterference import LAYOUT_AXES
-
+    ``layouts`` takes the same :class:`Build` lowerings as
+    ``noninterference.check_matrix`` (``LAYOUT_AXES``, the default, is
+    the full set); ineligible (model, lowering) pairs are skipped, not
+    failed."""
     if models is not None and not models:
         raise ValueError("absint_matrix: models is empty")
-    if layouts is None:
-        layouts = LAYOUT_AXES
     reports = []
     for tag, wl, cfg, horizon in (
         models if models is not None else absint_model_matrix()
     ):
-        for lay, t32, *rest in layouts:
-            place = rest[0] if rest else None
-            pidx = rest[1] if len(rest) > 1 else None
-            if t32 and not time32_eligible(wl, cfg):
-                continue
-            if pidx and not pool_index_eligible(cfg):
+        for lowering in layouts:
+            if not lowering_eligible(lowering, wl, cfg):
                 continue
             for axis, fl in (axes or ABSINT_AXES).items():
+                build, kw = split_axis(lowering, fl)
                 rep = check_ranges(
-                    wl, cfg, entry=entry, layout=lay, time32=t32,
-                    placement=place, pool_index=pidx,
-                    horizon_ns=horizon, onehot_sums=onehot_sums, **fl,
+                    wl, cfg, build, entry=entry, horizon_ns=horizon,
+                    onehot_sums=onehot_sums, **kw,
                 )
                 rep.flags["axis"] = axis
                 rep.workload = tag

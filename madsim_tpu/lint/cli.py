@@ -115,7 +115,6 @@ def main(argv=None) -> int:
             absint_model_matrix,
             stale_absint_pragmas,
         )
-        from .noninterference import LAYOUT_AXES as _LAX
 
         models = [m for m in absint_model_matrix() if m[0] == "raft/record"]
         if not models:
@@ -129,7 +128,6 @@ def main(argv=None) -> int:
         # rebase pragmas, so the staleness check below stays honest
         absint_reports = absint_matrix(
             models, {"all": ABSINT_AXES["all"], "dup": ABSINT_AXES["dup"]},
-            layouts=_LAX,
         )
         used = set()
         for r in absint_reports:

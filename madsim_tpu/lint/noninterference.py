@@ -32,6 +32,7 @@ import numpy as np
 import jax
 
 from ..engine.core import (
+    Build,
     EngineConfig,
     LatencySpec,
     Workload,
@@ -39,10 +40,13 @@ from ..engine.core import (
     make_init,
     make_run,
     make_step,
+    pool_index_eligible,
+    time32_eligible,
 )
 from .taint import analyze_jaxpr
 
 __all__ = [
+    "BASE_LOWERING",
     "NonInterferenceReport",
     "check_matrix",
     "check_noninterference",
@@ -166,22 +170,29 @@ class NonInterferenceReport:
         return "\n".join(lines)
 
 
+# the historical scatter/int64 program: the lowering a proof walks unless
+# it is given another
+BASE_LOWERING = Build(layout="scatter", time32=False)
+
+
+def report_flags(build: Build) -> dict:
+    """The JSON-able form of a build for a report's ``flags``."""
+    flags = {f.name: getattr(build, f.name) for f in dataclasses.fields(build)}
+    lat, rt = build.latency, build.retry
+    # the spec's defining triple; the retry spec as its field tuple
+    flags["latency"] = (
+        (lat.ops, lat.phases, lat.phase_ns) if lat is not None else None
+    )
+    flags["retry"] = dataclasses.astuple(rt) if rt is not None else None
+    return flags
+
+
 def check_noninterference(
     wl: Workload,
     cfg: EngineConfig,
+    build: Build = BASE_LOWERING,
     *,
     entry: str = "step",
-    layout: str = "scatter",
-    time32: bool = False,
-    placement: str | None = None,
-    dup_rows: bool = False,
-    cov_words: int = 0,
-    metrics: bool = False,
-    timeline_cap: int = 0,
-    cov_hitcount: bool = False,
-    latency: LatencySpec | None = None,
-    pool_index: bool | None = None,
-    causal: bool = False,
     n_steps: int = 4,
     n_seeds: int = 2,
     mutate=None,
@@ -222,21 +233,7 @@ def check_noninterference(
     appears. Needs a run-shaped entry (the detectors judge batched
     final states).
     """
-    flags = dict(
-        layout=layout, time32=time32, placement=placement, dup_rows=dup_rows,
-        cov_words=cov_words, metrics=metrics, timeline_cap=timeline_cap,
-        cov_hitcount=cov_hitcount, pool_index=pool_index, causal=causal,
-        # JSON-able form (reports serialize): the spec's defining triple
-        latency=(
-            (latency.ops, latency.phases, latency.phase_ns)
-            if latency is not None else None
-        ),
-    )
-    obs_kw = dict(
-        dup_rows=dup_rows, cov_words=cov_words, metrics=metrics,
-        timeline_cap=timeline_cap, cov_hitcount=cov_hitcount,
-        latency=latency, causal=causal,
-    )
+    flags = report_flags(build)
     if check:
         if entry == "step":
             raise ValueError(
@@ -260,23 +257,13 @@ def check_noninterference(
     else:
         def _with_check(base):
             return base
-    init = make_init(
-        wl, cfg, time32=time32, cov_words=cov_words, metrics=metrics,
-        timeline_cap=timeline_cap, cov_hitcount=cov_hitcount,
-        latency=latency, pool_index=pool_index, causal=causal,
-    )
+    init = make_init(wl, cfg, build)
     state = init(np.zeros(max(n_seeds, 1), np.uint64))
     if entry == "step":
-        fn = make_step(
-            wl, cfg, layout=layout, time32=time32, placement=placement,
-            pool_index=pool_index, **obs_kw,
-        )
+        fn = make_step(wl, cfg, build)
         template = jax.tree.map(lambda a: a[0], state)
     elif entry == "run":
-        fn = _with_check(make_run(
-            wl, cfg, n_steps, layout=layout, time32=time32,
-            placement=placement, pool_index=pool_index, **obs_kw,
-        ))
+        fn = _with_check(make_run(wl, cfg, n_steps, build))
         template = state
     elif entry == "sharded_run":
         # the multi-chip campaign program (explore.run_device's simulate
@@ -296,10 +283,7 @@ def check_noninterference(
         if rows % n_dev:
             rows += n_dev - rows % n_dev
         state = init(np.zeros(rows, np.uint64))
-        run_fn = _with_check(make_run(
-            wl, cfg, n_steps, layout=layout, time32=time32,
-            placement=placement, pool_index=pool_index, **obs_kw,
-        ))
+        run_fn = _with_check(make_run(wl, cfg, n_steps, build))
         spec = _P(mesh.axis_names)
         # the detector (when check=True) is INSIDE the shard_map body:
         # the per-shard program is sim + screen, exactly how
@@ -453,40 +437,66 @@ BUILD_AXES = {
     ),
 }
 
-# lowering/representation axes: (layout, time32, placement) triples.
-# The scatter int64 build was the historical matrix; dense and time32
-# produce the same jaxpr SHAPES (masked selects vs gathers, int32 vs
-# int64 pool times) but different equation graphs — the proof must
-# hold over all of them, and the COMBINED (dense, time32) pair is the
-# exact program an accelerator runs (layout and representation both
-# auto-resolve that way off-CPU), so it is swept too, not merely each
-# axis alone. The placement member sweeps the scatter layout's two
-# pool-write lowerings (PR 8): "rank" is the select-chain program a
-# small-pool CPU run compiles (cold-bank appends — history rank-append,
-# timeline/latency rows — ride this path), "scatter" the historical
-# .at[].set stores a client-army-scale pool still uses; both must keep
-# the derived columns isolated, not just the default one. Dense
-# ignores placement (its one-hot writes are already rank-matched).
+# lowering/representation axes: Build lowerings. The scatter int64
+# build was the historical matrix; dense and time32 produce the same
+# jaxpr SHAPES (masked selects vs gathers, int32 vs int64 pool times)
+# but different equation graphs — the proof must hold over all of them,
+# and the COMBINED (dense, time32) pair is the exact program an
+# accelerator runs (layout and representation both auto-resolve that
+# way off-CPU), so it is swept too, not merely each axis alone. The
+# placement member sweeps the scatter layout's two pool-write
+# lowerings: "rank" is the select-chain program a small-pool CPU run
+# compiles (cold-bank appends — history rank-append, timeline/latency
+# rows — ride this path), "scatter" the historical .at[].set stores a
+# client-army-scale pool still uses; both must keep the derived columns
+# isolated, not just the default one. Dense ignores placement (its
+# one-hot writes are already rank-matched). An unset placement or pool
+# index is the automatic one.
 LAYOUT_AXES = (
-    ("scatter", False, "rank"),
-    ("scatter", False, "scatter"),
-    ("dense", False, None),
-    ("scatter", True, "rank"),
-    ("dense", True, None),
-    # the readiness-partitioned pool (ISSUE 13): the indexed program an
-    # army-scale CPU pool compiles — tile-summary pop, per-tile free
-    # search, element-store placement. The tile summary columns are
-    # derived BY CONSTRUCTION (rebuilt on restore, excluded from the
-    # checkpoint format) but trajectory-coupled, so they sit on the
-    # CORE side of this proof: the obligation here is that no obs
-    # column ever reaches them (or anything else core) through the new
-    # index arithmetic; their own value-correctness certificate is the
-    # index on/off bit-identity pin (tests/test_pool_index.py,
+    Build(layout="scatter", time32=False, placement="rank"),
+    Build(layout="scatter", time32=False, placement="scatter"),
+    Build(layout="dense", time32=False),
+    Build(layout="scatter", time32=True, placement="rank"),
+    Build(layout="dense", time32=True),
+    # the readiness-partitioned pool: the indexed program an army-scale
+    # CPU pool compiles — tile-summary pop, per-tile free search,
+    # element-store placement. The tile summary columns are derived BY
+    # CONSTRUCTION (rebuilt on restore, excluded from the checkpoint
+    # format) but trajectory-coupled, so they sit on the CORE side of
+    # this proof: the obligation here is that no obs column ever
+    # reaches them (or anything else core) through the index
+    # arithmetic; their own value-correctness certificate is the index
+    # on/off bit-identity pin (tests/test_pool_index.py,
     # tools/lint_soak.py cert 1c). The time32 pair covers the rebased
     # tile minima.
-    ("scatter", False, None, True),
-    ("scatter", True, None, True),
+    Build(layout="scatter", time32=False, pool_index=True),
+    Build(layout="scatter", time32=True, pool_index=True),
 )
+
+_BUILD_FIELDS = frozenset(f.name for f in dataclasses.fields(Build))
+
+
+def split_axis(lowering: Build, flags: dict) -> tuple:
+    """A build-axis dict applied to a lowering: ``(build, kwargs)``,
+    the dict's :class:`Build` fields set on ``lowering`` and the rest
+    (the proof's own options, e.g. ``flight``/``check``) left over."""
+    build = dataclasses.replace(
+        lowering, **{k: v for k, v in flags.items() if k in _BUILD_FIELDS}
+    )
+    return build, {k: v for k, v in flags.items() if k not in _BUILD_FIELDS}
+
+
+def lowering_eligible(
+    lowering: Build, wl: Workload, cfg: EngineConfig
+) -> bool:
+    """Whether a (model, lowering) pair can be built: a time32 lowering
+    needs a time32-eligible model, a pool-index one a tile-divisible
+    pool. Matrices skip ineligible pairs rather than failing them."""
+    return not (
+        (lowering.time32 and not time32_eligible(wl, cfg))
+        or (lowering.pool_index and not pool_index_eligible(cfg))
+    )
+
 
 # The sharded-campaign matrix entry (ROADMAP lint follow-on; required
 # before pod-scale campaigns are load-bearing): the device campaign's
@@ -558,45 +568,30 @@ def check_matrix(
     axes=None,
     *,
     entry: str = "step",
-    layout: str = "scatter",
-    layouts: tuple | None = None,
+    layouts: tuple = (BASE_LOWERING,),
     log=None,
 ) -> list:
     """Run the proof over a model x build-flag matrix; returns reports.
 
     Defaults to the full certified matrix (tools/lint_soak.py scale);
     tests pass a slice for the tier-1 smoke. ``layouts`` sweeps
-    (layout, time32[, placement]) lowering tuples per cell
-    (``LAYOUT_AXES`` is the full set; two-tuples mean the auto
-    placement, four-tuples add the pool_index axis); the single
-    ``layout`` argument remains the one-lowering form. A model whose
-    (workload, config) is not time32-eligible is skipped for time32
-    pairs, and one whose pool has no tile divisor is skipped for
-    pool-index rows, rather than failing the matrix.
+    :class:`Build` lowerings per cell (``LAYOUT_AXES`` is the full set).
+    A (model, lowering) pair that cannot be built (:func:`lowering_eligible`)
+    is skipped rather than failing the matrix.
     """
-    from ..engine.core import pool_index_eligible, time32_eligible
-
     if models is not None and not models:
         # an explicitly empty slice is a caller bug (e.g. a tag filter
         # that matched nothing) — falling back to the full matrix here
         # would silently multiply the gate's cost instead
         raise ValueError("check_matrix: models is empty")
-    if layouts is None:
-        layouts = ((layout, False),)
     reports = []
     for name, wl, cfg in (models if models is not None else model_matrix()):
-        for lay, t32, *rest in layouts:
-            place = rest[0] if rest else None
-            pidx = rest[1] if len(rest) > 1 else None
-            if t32 and not time32_eligible(wl, cfg):
-                continue
-            if pidx and not pool_index_eligible(cfg):
+        for lowering in layouts:
+            if not lowering_eligible(lowering, wl, cfg):
                 continue
             for axis, flags in (axes or BUILD_AXES).items():
-                rep = check_noninterference(
-                    wl, cfg, entry=entry, layout=lay, time32=t32,
-                    placement=place, pool_index=pidx, **flags,
-                )
+                build, kw = split_axis(lowering, flags)
+                rep = check_noninterference(wl, cfg, build, entry=entry, **kw)
                 rep.flags["axis"] = axis
                 if log is not None:
                     log(rep.summary())

@@ -31,9 +31,11 @@ import jax.numpy as jnp
 from ..engine.core import (
     LAT_EDGES_NS,
     N_LAT_BUCKETS,
+    Build,
     LatencySpec,
     lat_bucket_hi,
 )
+from .metrics import _fleet_run
 
 __all__ = [
     "FleetLatency",
@@ -177,11 +179,6 @@ def latency_reduce(
     )
 
 
-# compiled-run cache, the engine.search discipline: repeated tail sweeps
-# over one (workload, config, budget, spec) reuse the XLA program
-_RUN_CACHE: dict = {}
-
-
 def fleet_latency(
     wl,
     cfg,
@@ -203,30 +200,10 @@ def fleet_latency(
     normally composes a ``chaos.ClientArmy`` (the load) with fault
     specs (the chaos the tail is measured under).
     """
-    from ..engine.core import make_init, make_run_while
-
-    if seeds is None:
-        seeds = np.arange(seed_base, seed_base + n_seeds, dtype=np.uint64)
-    else:
-        seeds = np.asarray(seeds, np.uint64)
-    plan_slots = int(plan.slots) if plan is not None else 0
-    dup = bool(plan.uses_dup()) if plan is not None else False
-    key = (id(wl), cfg.hash(), max_steps, layout, plan_slots, dup, spec)
-    if key not in _RUN_CACHE:
-        _RUN_CACHE[key] = (
-            make_init(wl, cfg, plan_slots=plan_slots, latency=spec),
-            jax.jit(make_run_while(
-                wl, cfg, max_steps, layout=layout, dup_rows=dup,
-                latency=spec,
-            )),
-            wl,  # keep alive so id() stays unique
-        )
-    init, run, _ = _RUN_CACHE[key]
-    if plan is not None:
-        state = init(seeds, plan.compile_batch(seeds, wl=wl))
-    else:
-        state = init(seeds)
-    out = run(state)
+    out = _fleet_run(
+        wl, cfg, Build(layout=layout, latency=spec), n_seeds, max_steps,
+        seed_base, seeds, plan,
+    )
     return latency_reduce(
         out.lat_hist, out.lat_count, out.lat_drop, phase_ns=spec.phase_ns
     )

@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from ..engine.core import (
+    Build,
     HALT_DONE,
     HALT_IDLE,
     HALT_RUNNING,
@@ -29,6 +30,7 @@ from ..engine.core import (
     METRIC_NAMES,
     N_METRICS,
 )
+from ..engine.search import _compiled_run
 
 __all__ = ["FleetMetrics", "fleet_reduce", "fleet_metrics"]
 
@@ -244,9 +246,25 @@ def fleet_reduce(met, overflow=None, lam=None) -> FleetMetrics:
     )
 
 
-# compiled-run cache, the engine.search discipline: repeated fleet
-# sweeps over one (workload, config, budget) reuse the XLA program
-_RUN_CACHE: dict = {}
+def _fleet_run(wl, cfg, build, n_seeds, max_steps, seed_base, seeds, plan):
+    """The final batched state of one fleet sweep, device-resident: the
+    (init, run) pair comes from ``engine.search``'s compiled-run cache,
+    with ``plan``'s slot count and duplication added to ``build``."""
+    if seeds is None:
+        seeds = np.arange(seed_base, seed_base + n_seeds, dtype=np.uint64)
+    else:
+        seeds = np.asarray(seeds, np.uint64)
+    if plan is not None:
+        build = dataclasses.replace(
+            build, plan_slots=int(plan.slots), dup_rows=bool(plan.uses_dup())
+        )
+    init, run, _ = _compiled_run(wl, cfg, max_steps, build)
+    if plan is not None:
+        state = init(seeds, plan.compile_batch(seeds, wl=wl))
+    else:
+        state = init(seeds)
+    out, _ = run(state)
+    return out
 
 
 def fleet_metrics(
@@ -270,28 +288,8 @@ def fleet_metrics(
     ``plan`` follows the ``search_seeds`` contract (a chaos FaultPlan
     compiled per seed).
     """
-    from ..engine.core import make_init, make_run_while
-
-    if seeds is None:
-        seeds = np.arange(seed_base, seed_base + n_seeds, dtype=np.uint64)
-    else:
-        seeds = np.asarray(seeds, np.uint64)
-    plan_slots = int(plan.slots) if plan is not None else 0
-    dup = bool(plan.uses_dup()) if plan is not None else False
-    key = (id(wl), cfg.hash(), max_steps, layout, plan_slots, dup)
-    if key not in _RUN_CACHE:
-        _RUN_CACHE[key] = (
-            make_init(wl, cfg, plan_slots=plan_slots, metrics=True),
-            jax.jit(make_run_while(
-                wl, cfg, max_steps, layout=layout, dup_rows=dup,
-                metrics=True,
-            )),
-            wl,  # keep alive so id() stays unique
-        )
-    init, run, _ = _RUN_CACHE[key]
-    if plan is not None:
-        state = init(seeds, plan.compile_batch(seeds, wl=wl))
-    else:
-        state = init(seeds)
-    out = run(state)
+    out = _fleet_run(
+        wl, cfg, Build(layout=layout, metrics=True), n_seeds, max_steps,
+        seed_base, seeds, plan,
+    )
     return fleet_reduce(out.met, overflow=out.overflow)

@@ -347,6 +347,9 @@ class AotProgram:
     def __init__(self, name: str, key, fn):
         self.name = name
         self.key = digest(key)
+        # the untransformed function, for callers that trace it into a
+        # larger program (engine.search.make_sweep)
+        self.fn = fn
         self._jit = jax.jit(fn)
         self._exes: dict = {}
         self.builds = 0

@@ -32,9 +32,9 @@ from ..engine.core import (
     HALT_TIME_LIMIT,
     MET_HALT_CODE,
     METRIC_NAMES,
-    make_init,
-    make_run_while,
+    Build,
 )
+from ..engine.search import _compiled_run
 from .timeline import decode_timeline
 
 __all__ = ["JsonlSink", "explain", "explain_diff"]
@@ -101,47 +101,24 @@ def _plan_rows_for(plan, seed):
     return stack_plan_rows([lit]), lit.slots, lit.uses_dup(), lit
 
 
-# compiled-run cache: explain/explain_diff re-runs over the same
-# (workload, config, caps) — a diff is two captures, a forensics
-# session many — reuse the XLA program instead of re-tracing per call
-# (the engine.search._RUN_CACHE pattern: jit keys on function identity,
-# so a fresh make_run_while closure per capture would defeat it).
-# Keyed on id(wl) like that cache (workload closures aren't hashable),
-# so hold ONE workload object across captures to hit it; bounded FIFO
-# so a sweep over many (wl, cfg) pairs cannot grow memory unboundedly.
-_CAPTURE_CACHE: dict = {}
-_CAPTURE_CACHE_MAX = 8
-
-
 def _capture(wl, cfg, seed, plan, max_steps, timeline_cap, layout,
              latency=None, causal=False):
     """Re-run one (seed, plan) with the forensics taps on: a field-name
-    view dict of the final state plus the literalized plan (or None)."""
+    view dict of the final state plus the literalized plan (or None).
+    The (init, run) pair comes from ``engine.search``'s compiled-run
+    cache, so a diff or a forensics session over one workload object
+    compiles once."""
     seeds = np.asarray([seed], np.uint64)
     if plan is not None:
         rows, slots, dup, lit = _plan_rows_for(plan, seed)
     else:
         rows, slots, dup, lit = None, 0, False, None
-    key = (id(wl), cfg.hash(), max_steps, timeline_cap, layout, slots, dup,
-           latency, causal)
-    if key not in _CAPTURE_CACHE:
-        while len(_CAPTURE_CACHE) >= _CAPTURE_CACHE_MAX:
-            _CAPTURE_CACHE.pop(next(iter(_CAPTURE_CACHE)))
-        _CAPTURE_CACHE[key] = (
-            make_init(
-                wl, cfg, plan_slots=slots, metrics=True,
-                timeline_cap=timeline_cap, latency=latency, causal=causal,
-            ),
-            jax.jit(make_run_while(
-                wl, cfg, max_steps, layout=layout, dup_rows=dup,
-                metrics=True, timeline_cap=timeline_cap, latency=latency,
-                causal=causal,
-            )),
-            wl,  # keep the workload alive so id() stays unique
-        )
-    init, run, _wl = _CAPTURE_CACHE[key]
+    init, run, _ = _compiled_run(wl, cfg, max_steps, Build(
+        layout=layout, plan_slots=slots, dup_rows=dup, metrics=True,
+        timeline_cap=timeline_cap, latency=latency, causal=causal,
+    ))
     state = init(seeds, rows) if rows is not None else init(seeds)
-    out = jax.block_until_ready(run(state))
+    out, _ = jax.block_until_ready(run(state))
     view = {
         f.name: np.asarray(getattr(out, f.name))
         for f in dataclasses.fields(out)

@@ -21,6 +21,8 @@ import numpy as np
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..engine.core import Build
+
 
 def shard_map_nocheck(fn, mesh, in_specs, out_specs):
     """``shard_map`` with the replication checker off — the repo's one
@@ -248,12 +250,11 @@ def shard_run_compacted(
     cfg,
     max_steps: int,
     mesh: Mesh,
-    layout: str | None = None,
-    time32: bool | None = None,
+    build: Build = Build(),
+    *,
     shrink: int = 4,
     min_size: int = 2048,
     fields: tuple | None = None,
-    latency=None,
     hist_screen=None,
 ):
     """Multi-chip form of :func:`engine.make_run_compacted`.
@@ -268,7 +269,8 @@ def shard_run_compacted(
     runner (tests/test_parallel.py asserts it).
 
     Returns ``run(state) -> SimpleNamespace`` of per-original-seed
-    numpy arrays, like the single-device runner. ``state`` should be
+    numpy arrays, like the single-device runner; ``build`` (an
+    ``engine.Build``) as for it. ``state`` should be
     placed with :func:`shard_state` (an unsharded state works too — jit
     reshards it to the declared input sharding).
 
@@ -283,8 +285,8 @@ def shard_run_compacted(
 
     kw = {} if fields is None else {"fields": fields}
     base = _compact.make_run_compacted(
-        wl, cfg, max_steps, layout, time32, shrink=shrink,
-        min_size=min_size, latency=latency, hist_screen=hist_screen, **kw,
+        wl, cfg, max_steps, build,
+        shrink=shrink, min_size=min_size, hist_screen=hist_screen, **kw,
     )
     n_dev = mesh.devices.size
     spec = P(mesh.axis_names)

@@ -9,7 +9,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from madsim_tpu.engine import EngineConfig, Workload
+from madsim_tpu.engine import Build, EngineConfig, Workload
 from madsim_tpu.engine.rng import PURPOSE_LANES, lane, lane_of
 from madsim_tpu.lint import (
     absint_matrix,
@@ -35,17 +35,21 @@ H = 60 * 1_000_000_000
 @pytest.fixture(scope="module")
 def rep_int64():
     return check_ranges(
-        make_raft(record=True), CFG, entry="step", layout="scatter",
-        time32=False, horizon_ns=H, metrics=True, timeline_cap=8,
-        cov_words=8,
+        make_raft(record=True), CFG,
+        Build(
+            layout="scatter", time32=False, metrics=True, timeline_cap=8,
+            cov_words=8,
+        ),
+        entry="step", horizon_ns=H,
     )
 
 
 @pytest.fixture(scope="module")
 def rep_t32_indexed():
     return check_ranges(
-        make_raft(record=True), CFG, entry="step", layout="scatter",
-        time32=True, pool_index=True, horizon_ns=H,
+        make_raft(record=True), CFG,
+        Build(layout="scatter", time32=True, pool_index=True), entry="step",
+        horizon_ns=H,
     )
 
 
@@ -204,16 +208,16 @@ class TestOverflowProver:
 
     def test_run_entry_scan_path(self):
         rep = check_ranges(
-            make_raft(record=True), CFG, entry="run", layout="scatter",
-            time32=False, horizon_ns=H, n_steps=3,
+            make_raft(record=True), CFG, Build(layout="scatter", time32=False),
+            entry="run", horizon_ns=H, n_steps=3,
         )
         assert rep.ok, rep.summary()
 
     def test_sentinel_decay_mutant_caught_with_chain(self):
         rep = check_ranges(
-            make_raft(record=True), CFG, entry="step", layout="scatter",
-            time32=True, pool_index=True, horizon_ns=H,
-            mutate=plant_time32_sentinel_decay,
+            make_raft(record=True), CFG,
+            Build(layout="scatter", time32=True, pool_index=True),
+            entry="step", horizon_ns=H, mutate=plant_time32_sentinel_decay,
         )
         assert not rep.ok
         hits = [
@@ -245,7 +249,7 @@ class TestOverflowProver:
         step = lambda st: st  # noqa: E731 — shape probe only
         from madsim_tpu.engine import make_init
 
-        st = make_init(make_raft(), CFG, pool_index=False)(
+        st = make_init(make_raft(), CFG, Build(pool_index=False))(
             np.zeros(1, np.uint64)
         )
         tmpl = jax.tree.map(lambda a: a[0], st)
@@ -263,16 +267,17 @@ class TestLaneProver:
 
     def test_dup_axis_lights_the_dup_lane(self):
         rep = check_ranges(
-            make_raft(record=True), CFG, entry="step", layout="scatter",
-            dup_rows=True, horizon_ns=H,
+            make_raft(record=True), CFG,
+            Build(layout="scatter", time32=False, dup_rows=True), entry="step",
+            horizon_ns=H,
         )
         assert rep.ok, rep.summary()
         assert "dup" in rep.lanes
 
     def test_lane_collision_mutant_caught(self):
         rep = check_ranges(
-            make_raft(record=True), CFG, entry="step", layout="scatter",
-            horizon_ns=H, mutate=plant_lane_collision,
+            make_raft(record=True), CFG, Build(layout="scatter", time32=False),
+            entry="step", horizon_ns=H, mutate=plant_lane_collision,
         )
         assert not rep.ok
         hits = [f for f in rep.findings if f["rule"] == "absint-lane"]
@@ -376,7 +381,8 @@ class TestFullMatrix:
         reports = absint_matrix(
             axes={"all": ABSINT_AXES["all"]},
             layouts=(
-                ("scatter", False, None), ("scatter", True, None, True),
+                Build(layout="scatter", time32=False),
+                Build(layout="scatter", time32=True, pool_index=True),
             ),
             entry="run",
         )

@@ -43,6 +43,7 @@ from madsim_tpu.chaos import CrashStorm, FaultPlan, GrayFailure, PauseStorm
 from madsim_tpu.check import device as dc
 from madsim_tpu.check import election_safety, violation_cones
 from madsim_tpu.engine import (
+    Build,
     EngineConfig,
     load_checkpoint,
     make_init,
@@ -94,8 +95,8 @@ class TestOffIdentity:
     def test_causal_off_columns_are_zero_size(self):
         wl = make_raft()
         seeds = np.arange(4, dtype=np.uint64)
-        off = make_init(wl, RAFT_CFG, timeline_cap=8)(seeds)
-        on = make_init(wl, RAFT_CFG, timeline_cap=8, causal=True)(seeds)
+        off = make_init(wl, RAFT_CFG, Build(timeline_cap=8))(seeds)
+        on = make_init(wl, RAFT_CFG, Build(timeline_cap=8, causal=True))(seeds)
         for f in ("lam", "ev_parent", "ev_lam", "tl_seq", "tl_parent",
                   "tl_lam"):
             assert np.asarray(getattr(off, f)).size == 0, f
@@ -118,11 +119,10 @@ class TestOffIdentity:
             for t32 in (False, True):
                 outs = {}
                 for causal in (False, True):
-                    init = make_init(wl, cfg, time32=t32,
-                                     causal=causal)
+                    init = make_init(wl, cfg, Build(time32=t32, causal=causal))
                     run = jax.jit(make_run(
-                        wl, cfg, 200, layout=layout, time32=t32,
-                        causal=causal,
+                        wl, cfg, 200,
+                        Build(layout=layout, time32=t32, causal=causal),
                     ))
                     outs[causal] = jax.block_until_ready(run(init(seeds)))
                 for f in ("trace", "now", "step", "halted", "ev_time",
@@ -285,13 +285,14 @@ class TestCheckpoint:
         uninterrupted one — clock, provenance and ring included."""
         wl = make_raft()
         seeds = np.arange(6, dtype=np.uint64)
-        init = make_init(wl, RAFT_CFG, timeline_cap=128, causal=True)
-        run = jax.jit(make_run(wl, RAFT_CFG, 120, timeline_cap=128,
-                               causal=True))
+        init = make_init(wl, RAFT_CFG, Build(timeline_cap=128, causal=True))
+        run = jax.jit(make_run(
+            wl, RAFT_CFG, 120, Build(timeline_cap=128, causal=True),
+        ))
         mid = jax.block_until_ready(run(init(seeds)))
         p = str(tmp_path / "causal.npz")
         save_checkpoint(p, mid, RAFT_CFG)
-        resumed = jax.block_until_ready(run(load_checkpoint(p, RAFT_CFG)))
+        resumed = jax.block_until_ready(run(load_checkpoint(p, wl, RAFT_CFG)))
         straight = jax.block_until_ready(run(run(init(seeds))))
         for f in dataclasses.fields(straight):
             assert np.array_equal(
@@ -304,14 +305,14 @@ class TestCheckpoint:
         causal step refuses it with the designed shape error instead of
         silently restarting the clock."""
         wl = make_raft()
-        st = make_init(wl, RAFT_CFG, timeline_cap=8)(
+        st = make_init(wl, RAFT_CFG, Build(timeline_cap=8))(
             np.arange(4, dtype=np.uint64)
         )
         p = str(tmp_path / "off.npz")
         save_checkpoint(p, st, RAFT_CFG)
-        run = make_run(wl, RAFT_CFG, 20, timeline_cap=8, causal=True)
+        run = make_run(wl, RAFT_CFG, 20, Build(timeline_cap=8, causal=True))
         with pytest.raises(TypeError, match="causal"):
-            jax.jit(run)(load_checkpoint(p, RAFT_CFG))
+            jax.jit(run)(load_checkpoint(p, wl, RAFT_CFG))
 
 
 # ----------------------------------------------------- perfetto arrows
@@ -479,8 +480,9 @@ class TestLintCausal:
 
         _tag, wl, cfg_kw = raft_mod.lint_entries()[0]
         rep = check_noninterference(
-            wl, EngineConfig(**cfg_kw), entry="step", causal=True,
-            timeline_cap=8,
+            wl, EngineConfig(**cfg_kw),
+            Build(layout="scatter", time32=False, causal=True, timeline_cap=8),
+            entry="step",
         )
         assert rep.ok, rep.summary()
         assert rep.flags["causal"] is True
@@ -494,8 +496,9 @@ class TestLintCausal:
 
         _tag, wl, cfg_kw, horizon = raft_mod.absint_entries()[0]
         rep = check_ranges(
-            wl, EngineConfig(**cfg_kw), entry="step", causal=True,
-            timeline_cap=8, horizon_ns=horizon,
+            wl, EngineConfig(**cfg_kw),
+            Build(layout="scatter", time32=False, causal=True, timeline_cap=8),
+            entry="step", horizon_ns=horizon,
         )
         assert rep.ok, rep.summary()
         assert rep.flags["causal"] is True

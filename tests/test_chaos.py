@@ -32,6 +32,7 @@ from madsim_tpu.chaos import (
 )
 from madsim_tpu.check import election_safety, read_your_writes, stale_reads
 from madsim_tpu.engine import (
+    Build,
     EngineConfig,
     search_seeds,
     make_init,
@@ -202,7 +203,7 @@ class TestEngineFaultKinds:
             dur_min_ns=400_000_000, dur_max_ns=500_000_000,
             mult_min=32, mult_max=64,
         ),))
-        init_g = make_init(kv_plain, kv_cfg, plan_slots=gray.slots)
+        init_g = make_init(kv_plain, kv_cfg, Build(plan_slots=gray.slots))
         slowed = run(init_g(seeds, gray.compile_batch(seeds, wl=kv_plain)))
         assert np.asarray(base.halted).all()
         assert np.asarray(slowed.halted).all()
@@ -216,7 +217,9 @@ class TestEngineFaultKinds:
     ):
         seeds = SEEDS64
         init = make_init(kv_plain, kv_cfg)
-        run_d = jax.jit(make_run_while(kv_plain, kv_cfg, 4000, dup_rows=True))
+        run_d = jax.jit(make_run_while(
+            kv_plain, kv_cfg, 4000, Build(dup_rows=True),
+        ))
         run = jax.jit(make_run_while(kv_plain, kv_cfg, 4000))
         base = run(init(seeds))
         # dup_rows compiled but no plan: values bit-identical
@@ -229,7 +232,7 @@ class TestEngineFaultKinds:
             t_min_ns=0, t_max_ns=1,
             dur_min_ns=500_000_000, dur_max_ns=600_000_000,
         ),))
-        init_d = make_init(kv_plain, kv_cfg, plan_slots=dupp.slots)
+        init_d = make_init(kv_plain, kv_cfg, Build(plan_slots=dupp.slots))
         dup = run_d(init_d(seeds, dupp.compile_batch(seeds, wl=kv_plain)))
         assert np.asarray(dup.halted).all()
         assert (
@@ -268,7 +271,7 @@ class TestEngineFaultKinds:
         ))
         run = jax.jit(make_run_while(wl, cfg, 50))
         plain = run(make_init(wl, cfg)(seeds))
-        init_s = make_init(wl, cfg, plan_slots=1)
+        init_s = make_init(wl, cfg, Build(plan_slots=1))
         skewed = run(init_s(seeds, skew.compile_batch(seeds)))
         d = np.asarray(skewed.node_state)[:, 0, 0] - np.asarray(
             plain.node_state
@@ -288,7 +291,7 @@ class TestEngineFaultKinds:
         lp = LiteralPlan(events=(
             FaultEvent(t=1_000, kind=KIND_CLOG_1W, a0=2, a1=3),
         ))
-        init_1 = make_init(kv_plain, kv_cfg, plan_slots=1)
+        init_1 = make_init(kv_plain, kv_cfg, Build(plan_slots=1))
         run = jax.jit(make_run_while(kv_plain, kv_cfg, 200))
         out = run(init_1(seeds, lp.compile_batch(seeds)))
         clog = np.asarray(out.clog)
@@ -302,7 +305,7 @@ class TestEngineFaultKinds:
     def test_pool_too_small_for_plan_rows(self, kv_plain):
         cfg = EngineConfig(pool_size=8)
         with pytest.raises(ValueError, match="fault-plan rows"):
-            make_init(kv_plain, cfg, plan_slots=6)
+            make_init(kv_plain, cfg, Build(plan_slots=6))
 
 
 # -------------------------------------------- search + shrink (planted bug)

@@ -37,7 +37,7 @@ from madsim_tpu.check.history import (
     OP_WRITE,
 )
 from madsim_tpu.check.linearize import check_kv
-from madsim_tpu.engine import EngineConfig, make_init, search_seeds
+from madsim_tpu.engine import Build, EngineConfig, make_init, search_seeds
 from madsim_tpu.engine.compact import make_run_compacted
 from madsim_tpu.models import make_kvchaos, make_raft, make_raftlog
 from madsim_tpu.models.raft import OP_ELECT
@@ -358,8 +358,8 @@ class TestEngineIdentity:
         seeds = np.arange(2048, dtype=np.uint64)
         verdicts = []
         for t32 in (False, True):
-            st = jax.jit(make_run_while(wl, CFG, 600, time32=t32))(
-                make_init(wl, CFG, time32=t32)(seeds)
+            st = jax.jit(make_run_while(wl, CFG, 600, Build(time32=t32)))(
+                make_init(wl, CFG, Build(time32=t32))(seeds)
             )
             assert not np.asarray(st.overflow).any()
             dev = _device(KV_SCREENS, BatchHistory.from_state(st))

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 
 from madsim_tpu.check import device as dc
 from madsim_tpu.engine import (
+    Build,
     EngineConfig,
     make_init,
     make_run_while,
@@ -64,7 +65,7 @@ def four_chips(topo, one_chip):
 
 
 def _state_shapes(wl, cfg, sharding, time32, n_seeds=N_SEEDS):
-    init = make_init(wl, cfg, time32=time32)
+    init = make_init(wl, cfg, Build(time32=time32))
     shapes = jax.eval_shape(init, jax.ShapeDtypeStruct((n_seeds,),
                                                        jnp.uint64))
     return jax.tree.map(
@@ -77,7 +78,7 @@ def test_raft_run_while_compiles_for_v5e(one_chip):
     factory, cfg_kw, n_seeds, n_steps = BENCH_SPECS["raft"]
     assert n_seeds == N_SEEDS
     wl, cfg = factory(), EngineConfig(**cfg_kw)
-    run = make_run_while(wl, cfg, n_steps, layout="dense", time32=True)
+    run = make_run_while(wl, cfg, n_steps, Build(layout="dense", time32=True))
     st = _state_shapes(wl, cfg, one_chip, True)
     compiled = jax.jit(run).lower(st).compile()
     mem = compiled.memory_analysis()
@@ -110,7 +111,7 @@ def test_sharded_run_compiles_for_v5e_2x2(four_chips):
     n = n_seeds * four_chips.devices.size
     st = _state_shapes(wl, cfg, seed_sharding(four_chips), True, n)
     run = shard_over_seeds(
-        make_run_while(wl, cfg, n_steps, layout="dense", time32=True),
+        make_run_while(wl, cfg, n_steps, Build(layout="dense", time32=True)),
         four_chips,
     )
     mem = run.lower(st).compile().memory_analysis()

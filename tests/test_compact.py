@@ -14,6 +14,7 @@ import pytest
 import jax
 
 from madsim_tpu.engine import (
+    Build,
     EngineConfig,
     make_init,
     make_run_compacted,
@@ -134,13 +135,15 @@ def test_packed_assemble_equals_bank_scatter(case):
     build, n_seeds, shrink, min_size, n_banks = PACK_CASES[case]
     wl, cfg, plan, taps, run_kw = build()
     seeds = np.arange(n_seeds, dtype=np.uint64)
-    slots = 0 if plan is None else plan.slots
-    init = make_init(wl, cfg, plan_slots=slots, **taps)
+    b = Build(
+        plan_slots=0 if plan is None else plan.slots,
+        dup_rows=plan is not None and plan.uses_dup(), **taps,
+    )
+    init = make_init(wl, cfg, b)
     st = init(seeds) if plan is None else init(
         seeds, plan.compile_batch(seeds, wl=wl))
     run = make_run_compacted(
-        wl, cfg, 2000, shrink=shrink, min_size=min_size,
-        dup_rows=plan is not None and plan.uses_dup(), **taps, **run_kw,
+        wl, cfg, 2000, b, shrink=shrink, min_size=min_size, **run_kw,
     )
     banked = jax.jit(run.phases)(st)
     assert len(banked) == n_banks

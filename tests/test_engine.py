@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from madsim_tpu.engine import (
+    Build,
     KIND_KILL,
     KIND_RESTART,
     KIND_CLOG,
@@ -352,7 +353,7 @@ class TestRunWhileAndCheckpoint:
         mid = run_half(st)
         path = str(tmp_path / "ckpt.npz")
         save_checkpoint(path, mid, cfg)
-        resumed = load_checkpoint(path, cfg)
+        resumed = load_checkpoint(path, wl, cfg)
         a = run_half(mid)
         b = run_half(resumed)
         assert np.array_equal(np.asarray(a.trace), np.asarray(b.trace))
@@ -367,24 +368,26 @@ class TestRunWhileAndCheckpoint:
         path = str(tmp_path / "c.npz")
         save_checkpoint(path, st, cfg)
         with pytest.raises(ValueError, match="different EngineConfig"):
-            load_checkpoint(path, EngineConfig(pool_size=8, loss_p=0.5))
+            load_checkpoint(path, wl, EngineConfig(pool_size=8, loss_p=0.5))
 
     def test_checkpoint_rejects_time_representation_mismatch(self, tmp_path):
         """A checkpoint saved under one ev_time representation refuses a
         declared resume under the other (auto-resolution is platform-
         dependent, so this is the cross-platform resume hazard)."""
         from madsim_tpu.engine import load_checkpoint, save_checkpoint
+        from madsim_tpu.models import BENCH_SPECS
 
-        wl = make_microbench(rounds=5)
-        cfg = EngineConfig(pool_size=8)
+        # a time32-eligible pair, so that both builds resolve
+        factory, kw, _, _ = BENCH_SPECS["raft"]
+        wl, cfg = factory(), EngineConfig(**kw)
         st = make_init(wl, cfg)(np.arange(2, dtype=np.uint64))
         path = str(tmp_path / "c.npz")
         save_checkpoint(path, st, cfg)
         saved32 = np.asarray(st.ev_time).dtype == np.int32
         # matching declaration loads fine; the opposite one is rejected
-        load_checkpoint(path, cfg, time32=saved32)
+        load_checkpoint(path, wl, cfg, Build(time32=saved32))
         with pytest.raises(ValueError, match="ev_time dtype"):
-            load_checkpoint(path, cfg, time32=not saved32)
+            load_checkpoint(path, wl, cfg, Build(time32=not saved32))
 
 
 class TestKvChaos:
@@ -553,7 +556,7 @@ class TestTime32:
         # the default 10 s clog-backoff cap exceeds the int32 horizon
         wl, cfg = make_raft(), EngineConfig(pool_size=48)
         with pytest.raises(ValueError, match="not eligible"):
-            make_step(wl, cfg, time32=True)
+            make_step(wl, cfg, Build(time32=True))
 
     def test_undeclared_delay_bound_is_ineligible(self):
         from madsim_tpu.engine import EngineConfig, time32_eligible
@@ -590,8 +593,9 @@ class TestTime32:
             delay_bound_ns=1_000,  # the lie
         )
         cfg = EngineConfig(pool_size=4, clog_backoff_max_ns=10_000_000)
-        out = jax.jit(make_run(wl, cfg, 3, time32=True))(
-            make_init(wl, cfg, time32=True)(np.arange(2, dtype=np.uint64))
+        out = jax.jit(make_run(wl, cfg, 3, Build(time32=True)))(
+            make_init(wl, cfg, Build(time32=True))(
+                np.arange(2, dtype=np.uint64))
         )
         assert int(np.asarray(out.overflow).sum()) >= 2
 
@@ -604,9 +608,10 @@ class TestTime32:
 
         factory, kw, _, _ = BENCH_SPECS["raft"]
         wl, cfg = factory(), EngineConfig(**kw)
-        state = make_init(wl, cfg, time32=True)(np.arange(2, dtype=np.uint64))
+        state = make_init(wl, cfg, Build(time32=True))(
+            np.arange(2, dtype=np.uint64))
         with pytest.raises(TypeError, match="time32"):
-            jax.jit(make_run(wl, cfg, 3, time32=False))(state)
+            jax.jit(make_run(wl, cfg, 3, Build(time32=False)))(state)
 
 
 def test_twophase_atomicity_under_chaos():

@@ -26,7 +26,7 @@ from madsim_tpu.chaos import (
     stack_plan_rows,
 )
 from madsim_tpu.check import election_safety, read_your_writes, stale_reads
-from madsim_tpu.engine import EngineConfig, search_seeds
+from madsim_tpu.engine import Build, EngineConfig, search_seeds
 from madsim_tpu.engine.rng import PURPOSE_EXPLORE
 from madsim_tpu.explore.mutate import HostStream, PlanSpace, mutate_plan
 from madsim_tpu.models import make_kvchaos, make_raft
@@ -89,7 +89,7 @@ class TestEngineCoverage:
         from madsim_tpu.engine import make_init
 
         with pytest.raises(ValueError, match="power of two"):
-            make_init(_raft_wl(), RAFT_CFG, cov_words=24)
+            make_init(_raft_wl(), RAFT_CFG, Build(cov_words=24))
 
     def test_explicit_seeds_match_range_sweep(self):
         wl = _raft_wl()

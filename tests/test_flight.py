@@ -23,7 +23,7 @@ import jax.numpy as jnp
 
 from madsim_tpu import explore, obs
 from madsim_tpu.chaos import FaultPlan, GrayFailure, PauseStorm
-from madsim_tpu.engine import EngineConfig, search_seeds
+from madsim_tpu.engine import Build, EngineConfig, search_seeds
 from madsim_tpu.explore import device as _device
 from madsim_tpu.models import make_raft
 from madsim_tpu.obs import prof
@@ -341,9 +341,12 @@ def test_flight_taps_never_enter_traced_code():
 
     flags = dict(FLIGHT_AXES["flight-campaign"])
     assert flags.pop("flight") is True
-    base = check_noninterference(WL, CFG, entry="run", **flags)
+    base = check_noninterference(
+        WL, CFG, Build(layout="scatter", time32=False, **flags), entry="run",
+    )
     armed = check_noninterference(
-        WL, CFG, entry="run", flight=True, **flags
+        WL, CFG, Build(layout="scatter", time32=False, **flags), entry="run",
+        flight=True,
     )
     assert base.ok and armed.ok
     assert armed.callback_prims == []

@@ -24,6 +24,7 @@ from madsim_tpu import explore
 from madsim_tpu.chaos import CrashStorm, FaultPlan, GrayFailure, PauseStorm
 from madsim_tpu.check import device as dc
 from madsim_tpu.engine import (
+    Build,
     EngineConfig,
     make_init,
     make_run_compacted,
@@ -61,9 +62,9 @@ def _lives(wl, cfg, seeds, max_steps, rows=None, dup_rows=False):
     """Rows not yet halted before each iteration of a lockstep loop over
     ``seeds``, stepped one iteration at a time on the host's loop."""
     slots = 0 if rows is None else np.asarray(rows.time).shape[1]
-    init = make_init(wl, cfg, plan_slots=slots)
+    init = make_init(wl, cfg, Build(plan_slots=slots))
     st = init(seeds) if rows is None else init(seeds, rows)
-    step = jax.jit(jax.vmap(make_step(wl, cfg, dup_rows=dup_rows)))
+    step = jax.jit(jax.vmap(make_step(wl, cfg, Build(dup_rows=dup_rows))))
     lives = []
     while len(lives) < max_steps:
         live = int((~np.asarray(st.halted)).sum())

@@ -24,6 +24,7 @@ from madsim_tpu import check, obs
 from madsim_tpu.chaos import ClientArmy, FaultPlan, GrayFailure, Nemesis
 from madsim_tpu.chaos.plan import stack_plan_rows
 from madsim_tpu.engine import (
+    Build,
     EngineConfig,
     LatencySpec,
     lat_bucket,
@@ -77,8 +78,8 @@ def lat_state():
     from madsim_tpu.engine import make_run_while
 
     seeds = np.arange(N_SEEDS, dtype=np.uint64)
-    init = make_init(WL, CFG, plan_slots=PLAN.slots, latency=SPEC)
-    run = jax.jit(make_run_while(WL, CFG, MAX_STEPS, latency=SPEC))
+    init = make_init(WL, CFG, Build(plan_slots=PLAN.slots, latency=SPEC))
+    run = jax.jit(make_run_while(WL, CFG, MAX_STEPS, Build(latency=SPEC)))
     return jax.block_until_ready(
         run(init(seeds, PLAN.compile_batch(seeds, wl=WL)))
     )
@@ -186,8 +187,8 @@ class TestClientArmy:
 
         spec3 = LatencySpec(ops=3)
         seeds = np.arange(4, dtype=np.uint64)
-        init = make_init(WL, CFG, plan_slots=lit.slots, latency=spec3)
-        run = jax.jit(make_run_while(WL, CFG, MAX_STEPS, latency=spec3))
+        init = make_init(WL, CFG, Build(plan_slots=lit.slots, latency=spec3))
+        run = jax.jit(make_run_while(WL, CFG, MAX_STEPS, Build(latency=spec3)))
         out = jax.block_until_ready(
             run(init(seeds, lit.compile_batch(seeds, wl=WL)))
         )
@@ -217,12 +218,12 @@ class TestLatencyIdentity:
         import jax
 
         seeds = np.arange(4, dtype=np.uint64)
-        init = make_init(WL, CFG, plan_slots=PLAN.slots, latency=SPEC)
-        run = jax.jit(make_run(WL, CFG, 250, latency=SPEC))
+        init = make_init(WL, CFG, Build(plan_slots=PLAN.slots, latency=SPEC))
+        run = jax.jit(make_run(WL, CFG, 250, Build(latency=SPEC)))
         mid = run(init(seeds, PLAN.compile_batch(seeds, wl=WL)))
         path = str(tmp_path / "lat.ckpt")
         save_checkpoint(path, mid, CFG)
-        resumed = run(load_checkpoint(path, CFG))
+        resumed = run(load_checkpoint(path, WL, CFG))
         straight = run(mid)
         assert np.array_equal(
             np.asarray(resumed.trace), np.asarray(straight.trace)

@@ -298,7 +298,7 @@ class TestDeterminism:
         mid = run_half(st)
         path = str(tmp_path / "leasekv.npz")
         save_checkpoint(path, mid, _CFG)
-        resumed = load_checkpoint(path, _CFG)
+        resumed = load_checkpoint(path, wl, _CFG)
         a, b = run_half(mid), run_half(resumed)
         for f in ("trace", "now", "node_state", "hist_word", "hist_count"):
             np.testing.assert_array_equal(

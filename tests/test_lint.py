@@ -9,6 +9,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from madsim_tpu.engine import (
+    Build,
     DERIVED_STATE_FIELDS,
     STORAGE_STATE_FIELDS,
     EngineConfig,
@@ -186,8 +187,11 @@ class TestNonInterference:
 
     def test_step_all_taps(self):
         rep = check_noninterference(
-            make_raft(record=True), CFG, metrics=True, timeline_cap=8,
-            cov_words=8, cov_hitcount=True,
+            make_raft(record=True), CFG,
+            Build(
+                layout="scatter", time32=False, metrics=True, timeline_cap=8,
+                cov_words=8, cov_hitcount=True,
+            ),
         )
         assert rep.ok, rep.summary()
         # the derived columns themselves are legitimately tainted
@@ -199,8 +203,9 @@ class TestNonInterference:
 
     def test_run_entry_scan_path(self):
         rep = check_noninterference(
-            make_raft(record=True), CFG, entry="run", metrics=True,
-            cov_words=8, n_steps=3,
+            make_raft(record=True), CFG,
+            Build(layout="scatter", time32=False, metrics=True, cov_words=8),
+            entry="run", n_steps=3,
         )
         assert rep.ok, rep.summary()
         assert any(
@@ -215,8 +220,9 @@ class TestNonInterference:
 
         flags = dict(CAMPAIGN_AXES["sharded-campaign"])
         rep = check_noninterference(
-            make_raft(record=True), CFG, entry="sharded_run",
-            n_seeds=4, n_steps=3, **flags,
+            make_raft(record=True), CFG,
+            Build(layout="scatter", time32=False, **flags),
+            entry="sharded_run", n_seeds=4, n_steps=3,
         )
         assert rep.ok, rep.summary()
         assert rep.flags["mesh_devices"] == jax.device_count()
@@ -230,11 +236,13 @@ class TestNonInterference:
         set unchanged, the verdict output carries ONLY history taint,
         no callback prims, and step entries are rejected."""
         from madsim_tpu.lint import CHECK_AXES
+        from madsim_tpu.lint.noninterference import BASE_LOWERING, split_axis
 
-        flags = dict(CHECK_AXES["device-check"])
+        build, kw = split_axis(BASE_LOWERING, CHECK_AXES["device-check"])
+        assert kw == {"check": True}
         rep = check_noninterference(
-            make_raft(record=True), CFG, entry="sharded_run",
-            n_seeds=4, n_steps=3, **flags,
+            make_raft(record=True), CFG, build,
+            entry="sharded_run", n_seeds=4, n_steps=3, **kw,
         )
         assert rep.ok, rep.summary()
         assert rep.flags["check"] is True
@@ -268,8 +276,9 @@ class TestNonInterference:
             return mutant
 
         rep = check_noninterference(
-            make_raft(record=True), CFG, entry="sharded_run",
-            n_seeds=4, n_steps=3, metrics=True, mutate=batched_met_leak,
+            make_raft(record=True), CFG,
+            Build(layout="scatter", time32=False, metrics=True),
+            entry="sharded_run", n_seeds=4, n_steps=3, mutate=batched_met_leak,
         )
         assert not rep.ok
         assert "met" in rep.leaks["step"]["labels"]
@@ -289,14 +298,18 @@ class TestNonInterference:
     def test_durable_discipline_reclassifies(self):
         rep = check_noninterference(
             make_raftlog(durable=True, record=True), CFG_RL,
-            metrics=True, timeline_cap=8, cov_words=8,
+            Build(
+                layout="scatter", time32=False, metrics=True, timeline_cap=8,
+                cov_words=8,
+            ),
         )
         assert rep.ok, rep.summary()
         assert "disk" not in rep.derived
 
     def test_planted_met_leak_is_caught(self):
         rep = check_noninterference(
-            make_raft(record=True), CFG, metrics=True,
+            make_raft(record=True), CFG,
+            Build(layout="scatter", time32=False, metrics=True),
             mutate=plant_met_leak,
         )
         assert not rep.ok
@@ -310,7 +323,10 @@ class TestNonInterference:
         assert "reaches core column 'step'" in rep.summary()
 
     def test_report_is_machine_readable(self):
-        rep = check_noninterference(make_raft(), CFG, metrics=True)
+        rep = check_noninterference(
+            make_raft(), CFG,
+            Build(layout="scatter", time32=False, metrics=True),
+        )
         d = rep.to_dict()
         assert d["ok"] and isinstance(d["frontier"], list)
         assert all(
@@ -332,8 +348,11 @@ class TestNonInterference:
         spec = LatencySpec(ops=8, phases=2)
         for layout, t32 in (("dense", False), ("dense", True)):
             rep = check_noninterference(
-                wl, CFG, layout=layout, time32=t32, latency=spec,
-                timeline_cap=8, cov_words=8,
+                wl, CFG,
+                Build(
+                    layout=layout, time32=t32, latency=spec, timeline_cap=8,
+                    cov_words=8,
+                ),
             )
             assert rep.ok, rep.summary()
             assert "lat_hist" in rep.out_taint
@@ -353,8 +372,11 @@ class TestNonInterference:
         spec = LatencySpec(ops=8, phases=2)
         for place in ("rank", "scatter"):
             rep = check_noninterference(
-                wl, CFG, layout="scatter", placement=place, latency=spec,
-                timeline_cap=8, cov_words=8, metrics=True,
+                wl, CFG,
+                Build(
+                    layout="scatter", time32=False, placement=place,
+                    latency=spec, timeline_cap=8, cov_words=8, metrics=True,
+                ),
             )
             assert rep.ok, rep.summary()
             assert rep.flags["placement"] == place
@@ -365,14 +387,16 @@ class TestNonInterference:
         from madsim_tpu.lint import check_matrix
         from madsim_tpu.lint.noninterference import LAYOUT_AXES
 
-        assert ("dense", False, None) in LAYOUT_AXES
-        assert ("scatter", True, "rank") in LAYOUT_AXES
+        assert Build(layout="dense", time32=False) in LAYOUT_AXES
+        assert Build(layout="scatter", time32=True, placement="rank") \
+            in LAYOUT_AXES
         # the combined pair is the exact program an accelerator runs
-        assert ("dense", True, None) in LAYOUT_AXES
-        # BOTH scatter-layout pool-write lowerings (PR 8): the rank
+        assert Build(layout="dense", time32=True) in LAYOUT_AXES
+        # BOTH scatter-layout pool-write lowerings: the rank
         # select-chain program and the historical .at[].set stores
-        assert ("scatter", False, "rank") in LAYOUT_AXES
-        assert ("scatter", False, "scatter") in LAYOUT_AXES
+        for place in ("rank", "scatter"):
+            assert Build(layout="scatter", time32=False, placement=place) \
+                in LAYOUT_AXES
         # a non-eligible (workload, config) is skipped for time32
         # pairs instead of failing the matrix
         wl = make_raft()
@@ -383,7 +407,7 @@ class TestNonInterference:
         })
         reps = check_matrix(
             [("raft/unbounded", wl, CFG)], {"base": {}},
-            layouts=(("scatter", True),),
+            layouts=(Build(layout="scatter", time32=True),),
         )
         assert reps == []
 
@@ -610,8 +634,8 @@ class TestSyncEio:
     def test_handler_observes_eio_and_syncs_fail(self):
         wl = self._probe()
         cfg = EngineConfig(pool_size=8)
-        init = make_init(wl, cfg, metrics=True)
-        run = jax.jit(make_run_while(wl, cfg, 64, metrics=True))
+        init = make_init(wl, cfg, Build(metrics=True))
+        run = jax.jit(make_run_while(wl, cfg, 64, Build(metrics=True)))
         out = run(init(np.zeros(2, np.uint64)))
         st = np.asarray(out.node_state)[0, 0]
         met = np.asarray(out.met)[0]

@@ -19,6 +19,7 @@ from madsim_tpu import explore, obs
 from madsim_tpu.chaos import CrashStorm, FaultPlan, Partition
 from madsim_tpu.check import election_safety
 from madsim_tpu.engine import (
+    Build,
     HALT_DONE,
     HALT_TIME_LIMIT,
     MET_HALT_CODE,
@@ -88,9 +89,9 @@ class TestObsIdentity:
     def test_build_validation(self):
         wl = make_pingpong(rounds=2)
         with pytest.raises(ValueError, match="cov_hitcount"):
-            make_init(wl, EngineConfig(), cov_hitcount=True)
+            make_init(wl, EngineConfig(), Build(cov_hitcount=True))
         with pytest.raises(ValueError, match="timeline_cap"):
-            make_init(wl, EngineConfig(), timeline_cap=-1)
+            make_init(wl, EngineConfig(), Build(timeline_cap=-1))
 
 
 class TestFleetMetrics:

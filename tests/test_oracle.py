@@ -16,7 +16,13 @@ import pytest
 
 import jax
 
-from madsim_tpu.engine import EngineConfig, make_init, make_run, threefry2x32
+from madsim_tpu.engine import (
+    Build,
+    EngineConfig,
+    make_init,
+    make_run,
+    threefry2x32,
+)
 from madsim_tpu.engine.oracle import oracle_threefry, run_oracle
 from madsim_tpu.models import (
     make_broadcast,
@@ -43,7 +49,7 @@ LAYOUTS = [pytest.param("dense", marks=pytest.mark.slow), "scatter"]
 
 def engine_batch(wl, cfg, seeds, n_steps, layout=None):
     init = make_init(wl, cfg)
-    run = jax.jit(make_run(wl, cfg, n_steps, layout=layout))
+    run = jax.jit(make_run(wl, cfg, n_steps, Build(layout=layout)))
     return run(init(np.asarray(seeds, np.uint64)))
 
 

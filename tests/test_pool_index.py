@@ -8,9 +8,9 @@ bit-identical with the index on or off, across both write lowerings
 (element stores / within-tile select chains), under time32, under
 chaos + client-army plans, and through checkpoint save/restore (where
 the summaries are REBUILT, never read from the file). The knob tests
-pin the documented resolution rules (rank_place_max_pool default/env/
-argument, pool_index auto thresholds) so a silent default change
-fails here, not in a bench artifact.
+pin the documented resolution rules (pool_index auto thresholds, the
+placement default) so a silent default change fails here, not in a
+bench artifact.
 """
 
 import dataclasses
@@ -22,6 +22,7 @@ import jax
 
 from madsim_tpu.chaos import CrashStorm, FaultPlan, GrayFailure
 from madsim_tpu.engine import (
+    Build,
     POOL_INDEX_STATE_FIELDS,
     EngineConfig,
     LatencySpec,
@@ -33,15 +34,9 @@ from madsim_tpu.engine import (
     make_run_compacted,
     make_run_while,
     pool_tile,
-    resolve_rank_place_max_pool,
     save_checkpoint,
 )
-from madsim_tpu.engine.core import (
-    _POOL_INDEX_MIN_POOL,
-    _RANK_PLACE_MAX_POOL,
-    _resolve_pool_index,
-    make_step,
-)
+from madsim_tpu.engine.core import _POOL_INDEX_MIN_POOL, make_step
 from madsim_tpu.models import make_raft, make_raftlog
 from madsim_tpu.models import raftlog as rl_mod
 
@@ -91,20 +86,19 @@ def _assert_summaries_consistent(st, cfg):
     assert np.array_equal(tm[mask], np.asarray(st.tile_min)[mask])
 
 
-def _run_pair(wl, cfg, n_steps, seeds, plan=None, lat=None, **kw):
-    slots = plan.slots if plan is not None else 0
+def _run_pair(wl, cfg, n_steps, seeds, plan=None, lat=None):
     rows = plan.compile_batch(seeds, wl=wl) if plan is not None else None
 
-    def one(pool_index, **extra):
-        init = make_init(wl, cfg, plan_slots=slots, latency=lat,
-                         pool_index=pool_index,
-                         time32=extra.get("time32"))
+    def one(pool_index, **lowering):
+        b = Build(
+            layout="scatter", pool_index=pool_index, latency=lat,
+            plan_slots=plan.slots if plan is not None else 0, **lowering,
+        )
+        init = make_init(wl, cfg, b)
         st0 = init(seeds, rows) if rows is not None else init(seeds)
-        run = jax.jit(make_run(
-            wl, cfg, n_steps, layout="scatter", latency=lat,
-            pool_index=pool_index, **kw, **extra,
-        ))
-        return jax.block_until_ready(run(st0))
+        return jax.block_until_ready(
+            jax.jit(make_run(wl, cfg, n_steps, b))(st0)
+        )
 
     return one
 
@@ -150,17 +144,17 @@ class TestIndexIdentity:
         wl = make_raft(record=True)
         cfg = EngineConfig(pool_size=40, loss_p=0.02,
                            clog_backoff_max_ns=2_000_000_000)
-        init_f = make_init(wl, cfg, pool_index=False)
-        init_i = make_init(wl, cfg, pool_index=True)
+        init_f = make_init(wl, cfg, Build(pool_index=False))
+        init_i = make_init(wl, cfg, Build(pool_index=True))
         ref = jax.block_until_ready(jax.jit(make_run_while(
-            wl, cfg, 400, layout="scatter", pool_index=False
+            wl, cfg, 400, Build(layout="scatter", pool_index=False),
         ))(init_f(SEEDS)))
         got = jax.block_until_ready(jax.jit(make_run_while(
-            wl, cfg, 400, layout="scatter", pool_index=True
+            wl, cfg, 400, Build(layout="scatter", pool_index=True),
         ))(init_i(SEEDS)))
         _assert_state_equal(ref, got, "run_while")
         out = make_run_compacted(
-            wl, cfg, 400, layout="scatter", pool_index=True, min_size=8
+            wl, cfg, 400, Build(layout="scatter", pool_index=True), min_size=8,
         )(init_i(SEEDS))
         for name in ("now", "trace", "halted", "overflow", "node_state",
                      "hist_count", "hist_word"):
@@ -196,20 +190,20 @@ class TestIndexEdgeCases:
         seeds = np.arange(4, dtype=np.uint64)
         outs = {}
         for pi in (False, True):
-            st = make_init(wl, cfg, time32=True, pool_index=pi)(seeds)
+            st = make_init(wl, cfg, Build(time32=True, pool_index=pi))(seeds)
             outs[pi] = jax.block_until_ready(jax.jit(make_run(
-                wl, cfg, 60, layout="scatter", time32=True, pool_index=pi
+                wl, cfg, 60,
+                Build(layout="scatter", time32=True, pool_index=pi),
             ))(st))
         _assert_state_equal(outs[False], outs[True], "sentinel decay")
 
-    def test_dense_step_over_indexed_state(self, monkeypatch):
-        # the mixed-resolution case the auto rule can produce on CPU
-        # (layout-blind init auto-indexes a big pool, a forced dense
-        # run has no index): the off-step must consume the state,
-        # match the flat trajectory AND keep the carried summaries
-        # exact (index-preserving rebuild), so a later indexed resume
-        # can trust them
-        monkeypatch.delenv("MADSIM_POOL_INDEX_MIN_POOL", raising=False)
+    def test_dense_step_over_indexed_state(self):
+        # an indexed state under a dense run, built on purpose from two
+        # Builds (an auto init indexes a big CPU pool, the dense run
+        # has no index): the off-step must consume the state, match the
+        # flat trajectory AND keep the carried summaries exact
+        # (index-preserving rebuild), so a later indexed resume can
+        # trust them
         wl = make_raft()
         cfg = EngineConfig(pool_size=2048, loss_p=0.02,
                            clog_backoff_max_ns=2_000_000_000)
@@ -217,61 +211,34 @@ class TestIndexEdgeCases:
         st = make_init(wl, cfg)(seeds)  # auto: indexed (CPU, pool 2048)
         assert st.tile_cnt.shape[1] == 2048 // pool_tile(2048)
         dense_out = jax.block_until_ready(jax.jit(make_run(
-            wl, cfg, 150, layout="dense"
+            wl, cfg, 150, Build(layout="dense"),
         ))(st))
         flat_out = jax.block_until_ready(jax.jit(make_run(
-            wl, cfg, 150, layout="scatter", pool_index=False
-        ))(make_init(wl, cfg, pool_index=False)(seeds)))
+            wl, cfg, 150, Build(layout="scatter", pool_index=False),
+        ))(make_init(wl, cfg, Build(pool_index=False))(seeds)))
         _assert_state_equal(flat_out, dense_out, "dense over indexed state")
         _assert_summaries_consistent(dense_out, cfg)
-
-
-class TestColdSplit:
-    # tier-1 budget: three full army runs through the heaviest model;
-    # the cold-split identity also holds under the stepident matrix
-    # (slow) and the profile sweep, and tier-1 keeps the validation
-    # guard plus the indexed-vs-flat identity pins above.
-    @pytest.mark.slow
-    def test_cold_split_bit_identical(self):
-        wl = make_raftlog(record=True, army=True)
-        cfg = EngineConfig(pool_size=96, loss_p=0.02,
-                           clog_backoff_max_ns=2_000_000_000)
-        one = _run_pair(wl, cfg, N_STEPS, SEEDS, plan=_ARMY_PLAN, lat=_LAT)
-        hot = one(False)
-        cold = one(False, cold_split=True)
-        both = one(True, cold_split=True)
-        _assert_state_equal(hot, cold, "cold_split")
-        _assert_state_equal(hot, both, "cold_split + pool_index")
-        assert int(np.asarray(hot.lat_count).sum()) > 0
-
-    def test_cold_split_validation(self):
-        wl = make_raftlog(army=True)
-        with pytest.raises(ValueError, match="cold_split needs"):
-            make_run(wl, CFG, 10, cold_split=True)
-        with pytest.raises(ValueError, match="incompatible with coverage"):
-            make_run(wl, CFG, 10, latency=_LAT, cov_words=8, cold_split=True)
 
 
 class TestCheckpoint:
     def _run_some(self, wl, cfg, n, state, pool_index):
         return jax.block_until_ready(jax.jit(make_run(
-            wl, cfg, n, layout="scatter", pool_index=pool_index
+            wl, cfg, n, Build(layout="scatter", pool_index=pool_index),
         ))(state))
 
     def test_roundtrip_rebuilds_summaries(self, tmp_path):
         wl = make_raft(record=True)
         cfg = EngineConfig(pool_size=40, loss_p=0.02,
                            clog_backoff_max_ns=2_000_000_000)
-        mid = self._run_some(
-            wl, cfg, 150, make_init(wl, cfg, pool_index=True)(SEEDS), True
-        )
+        init = make_init(wl, cfg, Build(pool_index=True))
+        mid = self._run_some(wl, cfg, 150, init(SEEDS), True)
         p = str(tmp_path / "idx.npz")
         save_checkpoint(p, mid, cfg)
         # the file carries NO summary entries — they are not format
         with np.load(p) as data:
             for f in POOL_INDEX_STATE_FIELDS:
                 assert f not in data.files
-        back = load_checkpoint(p, cfg, pool_index=True)
+        back = load_checkpoint(p, wl, cfg, Build(pool_index=True))
         # rebuilt summaries equal a from-scratch build over the loaded
         # pool (count exactly; minima on nonempty tiles)
         tm, tc = build_pool_index(
@@ -283,8 +250,7 @@ class TestCheckpoint:
             np.asarray(tm)[mask], np.asarray(back.tile_min)[mask]
         )
         # resuming from the restore equals the uninterrupted run
-        full = self._run_some(wl, cfg, 300,
-                              make_init(wl, cfg, pool_index=True)(SEEDS), True)
+        full = self._run_some(wl, cfg, 300, init(SEEDS), True)
         resumed = self._run_some(wl, cfg, 150, back, True)
         _assert_state_equal(full, resumed, "checkpoint resume")
 
@@ -295,41 +261,23 @@ class TestCheckpoint:
         wl = make_raft()
         cfg = EngineConfig(pool_size=40, loss_p=0.02,
                            clog_backoff_max_ns=2_000_000_000)
-        mid = self._run_some(
-            wl, cfg, 150, make_init(wl, cfg, pool_index=False)(SEEDS), False
-        )
+        init = make_init(wl, cfg, Build(pool_index=False))
+        mid = self._run_some(wl, cfg, 150, init(SEEDS), False)
         p = str(tmp_path / "flat.npz")
         save_checkpoint(p, mid, cfg)
-        back = load_checkpoint(p, cfg, pool_index=True)
+        back = load_checkpoint(p, wl, cfg, Build(pool_index=True))
         assert back.tile_cnt.shape == (
             len(SEEDS), cfg.pool_size // pool_tile(cfg.pool_size)
         )
         resumed_idx = self._run_some(wl, cfg, 150, back, True)
         resumed_flat = self._run_some(
-            wl, cfg, 150, load_checkpoint(p, cfg, pool_index=False), False
+            wl, cfg, 150, load_checkpoint(p, wl, cfg, Build(pool_index=False)),
+            False,
         )
         _assert_state_equal(resumed_flat, resumed_idx, "cross-format resume")
 
 
 class TestKnobs:
-    def test_rank_place_max_pool_resolution(self, monkeypatch):
-        monkeypatch.delenv("MADSIM_RANK_PLACE_MAX_POOL", raising=False)
-        assert resolve_rank_place_max_pool() == _RANK_PLACE_MAX_POOL == 512
-        monkeypatch.setenv("MADSIM_RANK_PLACE_MAX_POOL", "64")
-        assert resolve_rank_place_max_pool() == 64
-        # the explicit argument beats the env override
-        assert resolve_rank_place_max_pool(2048) == 2048
-        with pytest.raises(ValueError):
-            resolve_rank_place_max_pool(-1)
-        # env typos name the variable; negatives are rejected like the
-        # explicit argument (no silent nonsense from a deployment typo)
-        monkeypatch.setenv("MADSIM_RANK_PLACE_MAX_POOL", "abc")
-        with pytest.raises(ValueError, match="MADSIM_RANK_PLACE_MAX_POOL"):
-            resolve_rank_place_max_pool()
-        monkeypatch.setenv("MADSIM_RANK_PLACE_MAX_POOL", "-5")
-        with pytest.raises(ValueError, match="MADSIM_RANK_PLACE_MAX_POOL"):
-            resolve_rank_place_max_pool()
-
     def test_pool_tile_divisors(self):
         assert pool_tile(2048) == 64
         assert pool_tile(8192) == 64
@@ -339,24 +287,23 @@ class TestKnobs:
         assert pool_tile(7) == 0  # no candidate divides it
         assert pool_tile(64) == 32  # needs >= 2 tiles
 
-    def test_pool_index_auto_rule(self, monkeypatch):
-        monkeypatch.delenv("MADSIM_POOL_INDEX_MIN_POOL", raising=False)
+    def test_pool_index_auto_rule(self):
+        wl = make_raft()
+
+        def resolved(pool, **kw):
+            cfg = EngineConfig(pool_size=pool)
+            return Build(**kw).resolve(wl, cfg).pool_index
+
         # CPU backend (the test env): auto on only past the threshold
-        assert not _resolve_pool_index(EngineConfig(pool_size=512), None)
-        assert not _resolve_pool_index(
-            EngineConfig(pool_size=_POOL_INDEX_MIN_POOL), None
-        )
-        assert _resolve_pool_index(EngineConfig(pool_size=2048), None)
+        assert not resolved(512)
+        assert not resolved(_POOL_INDEX_MIN_POOL)
+        assert resolved(2048)
         # dense layout never auto-engages, and explicit True rejects it
-        assert not _resolve_pool_index(
-            EngineConfig(pool_size=2048), None, dense=True
-        )
+        assert not resolved(2048, layout="dense")
         with pytest.raises(ValueError, match="dense"):
-            _resolve_pool_index(EngineConfig(pool_size=2048), True, dense=True)
+            resolved(2048, layout="dense", pool_index=True)
         with pytest.raises(ValueError, match="no tile divisor"):
-            _resolve_pool_index(EngineConfig(pool_size=2049), True)
-        monkeypatch.setenv("MADSIM_POOL_INDEX_MIN_POOL", "256")
-        assert _resolve_pool_index(EngineConfig(pool_size=512), None)
+            resolved(2049, pool_index=True)
 
     def test_default_placement_under_index_is_store(self):
         # the measured CPU default: under the
@@ -366,9 +313,11 @@ class TestKnobs:
         wl = make_raft()
         # builds must succeed; the resolution itself is pinned via the
         # error path (placement="bogus" names the resolved set)
-        make_step(wl, cfg_small, layout="scatter", pool_index=True)
+        make_step(wl, cfg_small, Build(layout="scatter", pool_index=True))
         with pytest.raises(ValueError, match="unknown placement"):
-            make_step(wl, cfg_small, layout="scatter", placement="bogus")
+            make_step(
+                wl, cfg_small, Build(layout="scatter", placement="bogus"),
+            )
 
     def test_army_pool_sizing_is_tile_aligned(self):
         wl = make_raftlog(army=True)
@@ -386,8 +335,8 @@ class TestKnobs:
         wl = make_raft()
         cfg = EngineConfig(pool_size=40, loss_p=0.02,
                            clog_backoff_max_ns=2_000_000_000)
-        st = make_init(wl, cfg, pool_index=False)(SEEDS)
-        step = make_step(wl, cfg, layout="scatter", pool_index=True)
+        st = make_init(wl, cfg, Build(pool_index=False))(SEEDS)
+        step = make_step(wl, cfg, Build(layout="scatter", pool_index=True))
         with pytest.raises(TypeError, match="pool-index tiles"):
             jax.vmap(step)(st)
 

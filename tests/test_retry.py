@@ -56,6 +56,7 @@ from madsim_tpu.chaos import (
 from madsim_tpu.check import BatchHistory, OK_FAIL, OK_OK, OK_PENDING
 from madsim_tpu.check import device as dc
 from madsim_tpu.engine import (
+    Build,
     MET_RETRY,
     MET_RETRY_GIVEUP,
     N_METRICS,
@@ -106,18 +107,14 @@ def _plan(retry):
 
 def _run(wl, plan, seeds, retry, *, layout=None, time32=None,
          pool_index=None, compact=False, steps=STEPS):
-    kw = dict(latency=SPEC, metrics=True, retry=retry)
-    init = make_init(wl, CFG, plan_slots=plan.slots, time32=time32,
-                     pool_index=pool_index, **kw)
-    st0 = init(seeds, plan.compile_batch(seeds, wl=wl))
+    b = Build(
+        layout=layout, time32=time32, pool_index=pool_index,
+        plan_slots=plan.slots, latency=SPEC, metrics=True, retry=retry,
+    )
+    st0 = make_init(wl, CFG, b)(seeds, plan.compile_batch(seeds, wl=wl))
     if compact:
-        run = make_run_compacted(wl, CFG, steps, layout=layout,
-                                 time32=time32, pool_index=pool_index,
-                                 min_size=8, **kw)
-        return run(st0)
-    run = jax.jit(make_run_while(wl, CFG, steps, layout=layout,
-                                 time32=time32, pool_index=pool_index,
-                                 **kw))
+        return make_run_compacted(wl, CFG, steps, b, min_size=8)(st0)
+    run = jax.jit(make_run_while(wl, CFG, steps, b))
     return jax.block_until_ready(run(st0))
 
 
@@ -128,11 +125,16 @@ class TestOffIdentity:
         plan = _plan(POLICY)
         seeds = np.arange(4, dtype=np.uint64)
         rows = plan.compile_batch(seeds, wl=wl)
-        off = make_init(wl, CFG, plan_slots=plan.slots, latency=SPEC,
-                        metrics=True)(seeds, rows)
-        on = make_init(wl, CFG, plan_slots=plan.slots, latency=SPEC,
-                       metrics=True,
-                       retry=plan.retry_spec())(seeds, rows)
+        off = make_init(
+            wl, CFG, Build(plan_slots=plan.slots, latency=SPEC, metrics=True),
+        )(seeds, rows)
+        on = make_init(
+            wl, CFG,
+            Build(
+                plan_slots=plan.slots, latency=SPEC, metrics=True,
+                retry=plan.retry_spec(),
+            ),
+        )(seeds, rows)
         for f in RETRY_STATE_FIELDS:
             assert np.asarray(getattr(off, f)).size == 0, f
             assert np.asarray(getattr(on, f)).shape == (4, N_OPS), f
@@ -294,12 +296,17 @@ class TestGiveup:
         cfg = EngineConfig(pool_size=80, time_limit_ns=700_000_000)
         rt = plan.retry_spec()
         seeds = np.arange(8, dtype=np.uint64)
-        init = make_init(wl, cfg, plan_slots=plan.slots,
-                         latency=LatencySpec(ops=6), metrics=True,
-                         retry=rt)
-        run = jax.jit(make_run_while(wl, cfg, 5000,
-                                     latency=LatencySpec(ops=6),
-                                     metrics=True, retry=rt))
+        init = make_init(
+            wl, cfg,
+            Build(
+                plan_slots=plan.slots, latency=LatencySpec(ops=6),
+                metrics=True, retry=rt,
+            ),
+        )
+        run = jax.jit(make_run_while(
+            wl, cfg, 5000,
+            Build(latency=LatencySpec(ops=6), metrics=True, retry=rt),
+        ))
         out = jax.block_until_ready(
             run(init(seeds, plan.compile_batch(seeds, wl=wl))))
         met = np.asarray(out.met)
@@ -318,8 +325,8 @@ class TestCheckpoint:
         rt = plan.retry_spec()
         seeds = np.arange(4, dtype=np.uint64)
         kw = dict(latency=SPEC, metrics=True, retry=rt)
-        init = make_init(wl, CFG, plan_slots=plan.slots, **kw)
-        run = jax.jit(make_run(wl, CFG, 300, **kw))
+        init = make_init(wl, CFG, Build(plan_slots=plan.slots, **kw))
+        run = jax.jit(make_run(wl, CFG, 300, Build(**kw)))
         mid = jax.block_until_ready(
             run(init(seeds, plan.compile_batch(seeds, wl=wl))))
         # armed deadlines must actually be in flight at the cut for the
@@ -328,7 +335,7 @@ class TestCheckpoint:
         p = str(tmp_path / "retry.npz")
         save_checkpoint(p, mid, CFG)
         resumed = jax.block_until_ready(
-            run(load_checkpoint(p, CFG, retry=rt)))
+            run(load_checkpoint(p, wl, CFG, Build(retry=rt))))
         straight = jax.block_until_ready(run(mid))
         for f in dataclasses.fields(straight):
             assert np.array_equal(
@@ -342,26 +349,28 @@ class TestCheckpoint:
         rt = plan.retry_spec()
         seeds = np.arange(2, dtype=np.uint64)
         rows = plan.compile_batch(seeds, wl=wl)
-        on = make_init(wl, CFG, plan_slots=plan.slots, latency=SPEC,
-                       retry=rt)(seeds, rows)
-        off = make_init(wl, CFG, plan_slots=plan.slots,
-                        latency=SPEC)(seeds, rows)
+        on = make_init(
+            wl, CFG, Build(plan_slots=plan.slots, latency=SPEC, retry=rt),
+        )(seeds, rows)
+        off = make_init(
+            wl, CFG, Build(plan_slots=plan.slots, latency=SPEC),
+        )(seeds, rows)
         p_on = str(tmp_path / "on.npz")
         p_off = str(tmp_path / "off.npz")
         save_checkpoint(p_on, on, CFG)
         save_checkpoint(p_off, off, CFG)
         with pytest.raises(ValueError, match="no retry policy"):
-            load_checkpoint(p_on, CFG)
+            load_checkpoint(p_on, wl, CFG)
         with pytest.raises(ValueError, match="retry.n_ops"):
-            load_checkpoint(p_off, CFG, retry=rt)
+            load_checkpoint(p_off, wl, CFG, Build(retry=rt))
         with pytest.raises(ValueError, match="retry columns"):
             load_checkpoint(
-                p_on, CFG, retry=dataclasses.replace(rt, n_ops=8)
+                p_on, wl, CFG, Build(retry=dataclasses.replace(rt, n_ops=8))
             )
         # the matching spec loads cleanly both ways
-        assert np.asarray(load_checkpoint(p_on, CFG, retry=rt)
+        assert np.asarray(load_checkpoint(p_on, wl, CFG, Build(retry=rt))
                           .rt_done).shape == (2, N_OPS)
-        assert np.asarray(load_checkpoint(p_off, CFG).rt_done).size == 0
+        assert np.asarray(load_checkpoint(p_off, wl, CFG).rt_done).size == 0
 
 
 # -------------------------------------------------- exactly-once oracle
@@ -450,11 +459,16 @@ class TestExactlyOnce:
             cfg = EngineConfig(pool_size=96, time_limit_ns=600_000_000)
             rt = plan.retry_spec()
             seeds = np.arange(8, dtype=np.uint64)
-            init = make_init(wl, cfg, plan_slots=plan.slots,
-                             latency=LatencySpec(ops=16), retry=rt)
-            run = jax.jit(make_run_while(wl, cfg, 3000,
-                                         latency=LatencySpec(ops=16),
-                                         retry=rt))
+            init = make_init(
+                wl, cfg,
+                Build(
+                    plan_slots=plan.slots, latency=LatencySpec(ops=16),
+                    retry=rt,
+                ),
+            )
+            run = jax.jit(make_run_while(
+                wl, cfg, 3000, Build(latency=LatencySpec(ops=16), retry=rt),
+            ))
             out = jax.block_until_ready(
                 run(init(seeds, plan.compile_batch(seeds, wl=wl))))
             h = BatchHistory.from_state(out)

@@ -8,7 +8,7 @@ import numpy as np
 import pytest
 
 from madsim_tpu.chaos import CrashStorm, FaultPlan
-from madsim_tpu.engine import EngineConfig, make_init, search_seeds
+from madsim_tpu.engine import Build, EngineConfig, make_init, search_seeds
 from madsim_tpu.models import make_kvchaos, make_microbench, make_raft
 from madsim_tpu.obs import prof
 
@@ -137,8 +137,13 @@ def test_cached_init_equals_eager_init(case):
     search_seeds(wl, cfg, inv, seeds=seeds, max_steps=40, **kw)
     init = _cached_init(wl)
     assert isinstance(init, prof.AotProgram) and init.builds == 1
-    eager = make_init(wl, cfg, plan_slots=0 if rows is None
-                      else rows.time.shape[1])
+    eager = make_init(
+        wl, cfg,
+        Build(
+            plan_slots=0 if rows is None
+                      else rows.time.shape[1],
+        ),
+    )
     args = (seeds,) if rows is None else (seeds, rows)
     got, want = init(*args), eager(*args)
     assert init.builds == 1  # the search's own build served this call

@@ -32,6 +32,7 @@ from madsim_tpu.chaos import (
 )
 from madsim_tpu.check import BatchHistory, election_safety, recovery_safety
 from madsim_tpu.engine import (
+    Build,
     EngineConfig,
     Workload,
     make_init,
@@ -100,8 +101,10 @@ KILL = LiteralPlan(events=(FaultEvent(t=50_000_000, kind=KIND_KILL, a0=0),))
 
 
 def run_probe(wl, plan, layout=None, metrics=False, seeds=SEEDS):
-    init = make_init(wl, CFG, plan_slots=plan.slots, metrics=metrics)
-    run = jax.jit(make_run_while(wl, CFG, 60, layout=layout, metrics=metrics))
+    init = make_init(wl, CFG, Build(plan_slots=plan.slots, metrics=metrics))
+    run = jax.jit(make_run_while(
+        wl, CFG, 60, Build(layout=layout, metrics=metrics),
+    ))
     return jax.block_until_ready(
         run(init(seeds, plan.compile_batch(seeds)))
     )
@@ -279,7 +282,7 @@ class TestDiskFaultSpec:
             t_min_ns=1_000, t_max_ns=2_000,
             dur_min_ns=1_000_000, dur_max_ns=2_000_000,
         ),))
-        init = make_init(wl, CFG, plan_slots=plan.slots)
+        init = make_init(wl, CFG, Build(plan_slots=plan.slots))
         run = jax.jit(make_run_while(wl, CFG, 60))
         out = run(init(SEEDS, plan.compile_batch(SEEDS)))
         assert (durable_rows(out) == WRITE_VALS).all()
@@ -808,8 +811,10 @@ class TestRaftlogStorage:
             FaultEvent(t=5_000_000, kind=KIND_SYNC_OK, a0=0),
             FaultEvent(t=8_000_000, kind=KIND_TORN_ON, a0=0),  # unclosed
         ))
-        init = make_init(wl, CFG, plan_slots=plan.slots, timeline_cap=64)
-        run = jax.jit(make_run_while(wl, CFG, 60, timeline_cap=64))
+        init = make_init(
+            wl, CFG, Build(plan_slots=plan.slots, timeline_cap=64),
+        )
+        run = jax.jit(make_run_while(wl, CFG, 60, Build(timeline_cap=64)))
         out = run(init(SEEDS[:1], plan.compile_batch(SEEDS[:1])))
         doc = obs.to_perfetto(obs.decode_timeline(out, wl, 0), wl)
         chaos = {

@@ -19,6 +19,7 @@ from madsim_tpu import explore, models
 from madsim_tpu.chaos import CrashStorm, FaultPlan
 from madsim_tpu.check import device as dc
 from madsim_tpu.engine import (
+    Build,
     EngineConfig,
     make_init,
     make_run_compacted,
@@ -68,7 +69,8 @@ def test_engine_runner(name, layout):
     else:
         wl, cfg = getattr(models, f"make_{name}")(), EngineConfig(pool_size=64)
     st = jax.eval_shape(make_init(wl, cfg), np.arange(8, dtype=np.uint64))
-    jaxpr = jax.make_jaxpr(make_run_while(wl, cfg, 10, layout=layout))(st)
+    run = make_run_while(wl, cfg, 10, Build(layout=layout))
+    jaxpr = jax.make_jaxpr(run)(st)
     assert batched_bool_scatters(jaxpr.jaxpr) == []
 
 
@@ -95,7 +97,7 @@ def test_sweep_init(name):
         wl, cfg = kvchaos()
         plan = FaultPlan((CrashStorm(targets=(1, 2, 3, 4), n=2),), name="kv")
         args = (seeds, plan.compile_batch(seeds, wl=wl))
-        init = make_init(wl, cfg, plan_slots=plan.slots)
+        init = make_init(wl, cfg, Build(plan_slots=plan.slots))
     else:
         factory, cfg_kw, _, _ = BENCH_SPECS[name]
         args, init = (seeds,), make_init(factory(), EngineConfig(**cfg_kw))

@@ -46,7 +46,7 @@ from madsim_tpu.lint import (
     run_mutant_controls,
     stale_absint_pragmas,
 )
-from madsim_tpu.lint.noninterference import LAYOUT_AXES
+from madsim_tpu.engine import Build
 
 
 def main() -> None:
@@ -57,12 +57,13 @@ def main() -> None:
     # ---- certificate 1: the full overflow + lane matrix ----
     t0 = time.monotonic()  # lint: allow(wall-clock)
     print("== cert 1: interval matrix, model x axis x lowering ==")
-    reports = absint_matrix(
-        layouts=LAYOUT_AXES, log=lambda s: print(f"  {s}")
-    )
+    reports = absint_matrix(log=lambda s: print(f"  {s}"))
     run_reports = absint_matrix(
         axes={"all": ABSINT_AXES["all"]},
-        layouts=(("scatter", False, None), ("scatter", True, None, True)),
+        layouts=(
+            Build(layout="scatter", time32=False),
+            Build(layout="scatter", time32=True, pool_index=True),
+        ),
         entry="run",
         log=lambda s: print(f"  {s}"),
     )

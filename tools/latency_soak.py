@@ -127,7 +127,7 @@ def main() -> None:
     print("== cert 2: sketch exactness vs exact per-op latencies ==")
     import jax as _jax
 
-    from madsim_tpu.engine import make_init, make_run_while
+    from madsim_tpu.engine import Build, make_init, make_run_while
 
     # the acceptance scale: >= 4096 seeds. The sketch side goes through
     # obs.fleet_latency — the device-resident reduction that never
@@ -139,8 +139,9 @@ def main() -> None:
         WL, CFG, SPEC, n_seeds=s_ex, max_steps=MAX_STEPS, plan=gray_plan,
     )
     seeds = np.arange(s_ex, dtype=np.uint64)
-    init = make_init(WL, CFG, plan_slots=gray_plan.slots, latency=SPEC)
-    run = _jax.jit(make_run_while(WL, CFG, MAX_STEPS, latency=SPEC))
+    build = Build(plan_slots=gray_plan.slots, latency=SPEC)
+    init = make_init(WL, CFG, build)
+    run = _jax.jit(make_run_while(WL, CFG, MAX_STEPS, build))
     out = _jax.block_until_ready(
         run(init(seeds, gray_plan.compile_batch(seeds, wl=WL)))
     )

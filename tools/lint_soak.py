@@ -154,6 +154,7 @@ def main() -> None:
 
     from madsim_tpu.engine import (
         POOL_INDEX_STATE_FIELDS,
+        Build,
         build_pool_index,
         make_init,
         make_run,
@@ -165,12 +166,12 @@ def main() -> None:
         pool_size=40, loss_p=0.02, clog_backoff_max_ns=2_000_000_000
     )
     _seeds = _np.arange(64, dtype=_np.uint64)
-    _a = jax.block_until_ready(jax.jit(make_run(
-        _wl, _cfg, 300, layout="scatter", pool_index=False
-    ))(make_init(_wl, _cfg, pool_index=False)(_seeds)))
-    _b = jax.block_until_ready(jax.jit(make_run(
-        _wl, _cfg, 300, layout="scatter", pool_index=True
-    ))(make_init(_wl, _cfg, pool_index=True)(_seeds)))
+    _off = Build(layout="scatter", pool_index=False)
+    _on = Build(layout="scatter", pool_index=True)
+    _a = jax.block_until_ready(jax.jit(make_run(_wl, _cfg, 300, _off))(
+        make_init(_wl, _cfg, _off)(_seeds)))
+    _b = jax.block_until_ready(jax.jit(make_run(_wl, _cfg, 300, _on))(
+        make_init(_wl, _cfg, _on)(_seeds)))
     _div = [
         f.name for f in _dc.fields(_a)
         if f.name not in POOL_INDEX_STATE_FIELDS
@@ -309,7 +310,7 @@ def main() -> None:
         EngineConfig(
             pool_size=40, loss_p=0.02, clog_backoff_max_ns=2_000_000_000
         ),
-        metrics=True,
+        Build(layout="scatter", time32=False, metrics=True),
         mutate=plant_met_leak,
     )
     caught = (

@@ -31,7 +31,6 @@ speedup clears the 2x acceptance floor).
 from __future__ import annotations
 
 import dataclasses
-import os
 import sys
 import time
 
@@ -45,11 +44,12 @@ from jax import lax
 from madsim_tpu.chaos import CrashStorm, FaultPlan, GrayFailure
 from madsim_tpu.engine import (
     POOL_INDEX_STATE_FIELDS,
+    Build,
     EngineConfig,
     LatencySpec,
     make_init,
+    make_step,
 )
-from madsim_tpu.engine.core import _resolve_pool_index, make_step
 from madsim_tpu.models import make_kvchaos, make_raftlog
 from madsim_tpu.models import kvchaos as kv_mod
 from madsim_tpu.models import raftlog as rl_mod
@@ -86,9 +86,11 @@ def _army_setup(name: str, pool: int):
 
 
 def _build(wl, cfg, plan, lat, n_steps, pool_index):
-    step = jax.vmap(make_step(
-        wl, cfg, layout="scatter", latency=lat, pool_index=pool_index,
-    ))
+    build = Build(
+        layout="scatter", pool_index=pool_index, plan_slots=plan.slots,
+        latency=lat,
+    )
+    step = jax.vmap(make_step(wl, cfg, build))
 
     def run(st):
         final, _ = lax.scan(
@@ -96,9 +98,7 @@ def _build(wl, cfg, plan, lat, n_steps, pool_index):
         )
         return final
 
-    init = make_init(wl, cfg, plan_slots=plan.slots, latency=lat,
-                     pool_index=pool_index)
-    return jax.jit(run), init
+    return jax.jit(run), make_init(wl, cfg, build)
 
 
 def _state_fields(st):
@@ -162,17 +162,12 @@ def main() -> None:
     print(f"# pool-bench ({mode}): readiness-partitioned pool A/B, "
           f"platform={plat}")
 
-    # this is a GATE over the shipped defaults: neutralize any
-    # deployment env overrides so an exported knob on the CI box
-    # cannot flip what is being certified (the knobs themselves are
-    # test-pinned in tests/test_pool_index.py)
-    for var in ("MADSIM_POOL_INDEX_MIN_POOL", "MADSIM_RANK_PLACE_MAX_POOL"):
-        os.environ.pop(var, None)
-
     # small-pool guard: <= 512 resolves the index OFF by default, so
     # the default program is the pre-ISSUE-13 one — 0% regression by
     # construction (a real check, not an assert: gates must survive -O)
-    if _resolve_pool_index(EngineConfig(pool_size=512), None):
+    if Build(layout="scatter").resolve(
+        make_raftlog(record=True, army=True), EngineConfig(pool_size=512)
+    ).pool_index:
         print("# FAIL: pool_index auto-resolved ON at pool_size=512 — "
               "the small-pool no-regression guarantee is broken")
         sys.exit(1)

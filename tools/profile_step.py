@@ -50,8 +50,13 @@ import numpy as np
 import jax
 from jax import lax
 
-from madsim_tpu.engine import EngineConfig, LatencySpec, make_init
-from madsim_tpu.engine.core import make_step
+from madsim_tpu.engine import (
+    Build,
+    EngineConfig,
+    LatencySpec,
+    make_init,
+    make_step,
+)
 from madsim_tpu.models import BENCH_SPECS
 
 DEFAULT_CONFIGS = ("raftlog", "kvchaos", "raft")
@@ -70,7 +75,8 @@ def _nop_handler(ctx):
 
 def _time_variant(wl, cfg, n_seeds, n_steps, **mk) -> float:
     """Best-of-3 wall of a jitted n_steps scan, ns per seed-step."""
-    step = jax.vmap(make_step(wl, cfg, **mk))
+    build = Build(**mk)
+    step = jax.vmap(make_step(wl, cfg, build))
 
     def run(st):
         def body(s, _):
@@ -80,7 +86,7 @@ def _time_variant(wl, cfg, n_seeds, n_steps, **mk) -> float:
         return final
 
     r = jax.jit(run)
-    st = make_init(wl, cfg)(np.arange(n_seeds, dtype=np.uint64))
+    st = make_init(wl, cfg, build)(np.arange(n_seeds, dtype=np.uint64))
     jax.block_until_ready(r(st))  # compile
     best = float("inf")
     for _ in range(3):
@@ -152,7 +158,8 @@ def profile_config(name: str, n_seeds: int = N_SEEDS, n_steps: int = N_STEPS) ->
 
 def _time_pool_variant(wl, cfg, rows, slots, lat, n_seeds, n_steps, **mk) -> float:
     """Best-of-2 wall of a jitted plan-seeded scan, ns per seed-step."""
-    step = jax.vmap(make_step(wl, cfg, layout="scatter", latency=lat, **mk))
+    build = Build(layout="scatter", plan_slots=slots, latency=lat, **mk)
+    step = jax.vmap(make_step(wl, cfg, build))
 
     def run(st):
         final, _ = lax.scan(
@@ -161,8 +168,7 @@ def _time_pool_variant(wl, cfg, rows, slots, lat, n_seeds, n_steps, **mk) -> flo
         return final
 
     r = jax.jit(run)
-    init = make_init(wl, cfg, plan_slots=slots, latency=lat,
-                     pool_index=mk.get("pool_index"))
+    init = make_init(wl, cfg, build)
     seeds = np.arange(n_seeds, dtype=np.uint64)
     st = init(seeds, rows) if rows is not None else init(seeds)
     jax.block_until_ready(r(st))  # compile

@@ -33,6 +33,7 @@ import jax
 
 from madsim_tpu.chaos import ClientArmy, CrashStorm, FaultPlan, GrayFailure
 from madsim_tpu.engine import (
+    Build,
     EngineConfig,
     LatencySpec,
     make_init,
@@ -189,21 +190,18 @@ def run_scenario(
     pool_index=None,
 ) -> str:
     seeds = np.arange(N_SEEDS, dtype=np.uint64)
-    slots = plan.slots if plan is not None else 0
-    init = make_init(wl, cfg, plan_slots=slots, latency=lat,
-                     pool_index=pool_index, **OBS)
+    build = Build(
+        layout=layout, placement=placement, pool_index=pool_index,
+        plan_slots=plan.slots if plan is not None else 0, latency=lat,
+        **OBS,
+    )
+    init = make_init(wl, cfg, build)
     rows = plan.compile_batch(seeds, wl=wl) if plan is not None else None
     st0 = init(seeds, rows) if rows is not None else init(seeds)
     if compact:
-        run = make_run_compacted(
-            wl, cfg, N_STEPS, layout=layout, latency=lat, min_size=8,
-            placement=placement, pool_index=pool_index, **OBS
-        )
+        run = make_run_compacted(wl, cfg, N_STEPS, build, min_size=8)
         return digest_state(run(st0))
-    run = jax.jit(make_run(
-        wl, cfg, N_STEPS, layout=layout, latency=lat, placement=placement,
-        pool_index=pool_index, **OBS
-    ))
+    run = jax.jit(make_run(wl, cfg, N_STEPS, build))
     return digest_state(jax.block_until_ready(run(st0)))
 
 

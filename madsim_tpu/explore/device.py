@@ -37,7 +37,9 @@ The host sees exactly one synchronization point per generation: the
 admission summary (corpus size, new-entry count, coverage bits,
 violation count) and — when logging asks for them — the fresh
 violation keys. Per-seed state never reaches the host until the final
-report (or a checkpoint) materializes the corpus once.
+report (or a checkpoint) copies the corpus and violation stores once;
+the report reads them through :class:`EntryView`, which builds each
+``CorpusEntry`` when it is first read.
 
 Campaign outcomes are **bit-identical to the host driver** given the
 same arguments: same corpus (ids, seeds, plans, traces, new-bit
@@ -67,8 +69,10 @@ runs ``make_run_while``).
 from __future__ import annotations
 
 import dataclasses as _dc
+import operator
 import os as _os
 import time as _time
+from collections.abc import Sequence
 
 import numpy as np
 
@@ -94,7 +98,7 @@ from .mutate import (
     mutation_table,
 )
 
-__all__ = ["gen_cache_stats", "run_device"]
+__all__ = ["EntryView", "gen_cache_stats", "run_device"]
 
 
 def _kth_true(mask, k):
@@ -388,6 +392,55 @@ def _store_entry(st_np, i, name) -> CorpusEntry:
         violating=bool(st_np["viol"][i]),
         halt_t=int(st_np["halt"][i]),
     )
+
+
+class EntryView(Sequence):
+    """A campaign's corpus or violations: a read-only sequence over the
+    host copy of an entry store, whose entry ``i`` is built from store
+    row ``i`` (:func:`_store_entry`) the first time it is read.
+
+    ``cache`` is the session's slot -> entry map, shared by every view
+    of the store, so an entry is built once and entries loaded on
+    resume come back as the same objects. ``shared(i)`` may return
+    another view's entry for row ``i`` (a violation that joined the
+    corpus), or None. ``built`` counts the entries this view
+    constructed."""
+
+    def __init__(self, rows, n: int, cache: dict, name, shared=None):
+        self._rows, self._n, self._cache = rows, n, cache
+        self._name, self._shared = name, shared
+        self.built = 0
+
+    def _entry(self, i: int) -> CorpusEntry:
+        e = self._cache.get(i)
+        if e is None:
+            e = self._shared(i) if self._shared is not None else None
+            if e is None:
+                e = _store_entry(self._rows, i, self._name(self._rows, i))
+                self.built += 1
+            self._cache[i] = e
+        return e
+
+    def __len__(self) -> int:
+        return self._n
+
+    def __getitem__(self, i):
+        if isinstance(i, slice):
+            return [self._entry(j) for j in range(*i.indices(self._n))]
+        i = operator.index(i)
+        if not -self._n <= i < self._n:
+            raise IndexError(f"entry {i} of {self._n}")
+        return self._entry(i % self._n)
+
+    def __iter__(self):
+        return (self._entry(i) for i in range(self._n))
+
+    def __eq__(self, other) -> bool:
+        if not isinstance(other, Sequence):
+            return NotImplemented
+        return len(other) == self._n and all(
+            a is b or a == b for a, b in zip(self, other)
+        )
 
 
 # ---------------------------------------------------------------------------
@@ -910,10 +963,10 @@ class _CampaignSession:
         self.carry = carry
         self.count = len(loaded_corpus)  # host mirror (uniform vs breed)
 
-        # materialized-entry caches: slot -> CorpusEntry. Loaded entries
-        # are returned as the same objects (names and identity survive
-        # resume); new slots materialize once and are reused by every
-        # later checkpoint/report build.
+        # materialized-entry caches: slot -> CorpusEntry, shared by every
+        # EntryView of the session. Loaded entries are returned as the
+        # same objects (names and identity survive resume); a new slot
+        # is built the first time any view reads it, and reused after.
         self._c_cache = {i: e for i, e in enumerate(loaded_corpus)}
         self._v_cache = {i: e for i, e in enumerate(loaded_viol)}
 
@@ -1034,48 +1087,52 @@ class _CampaignSession:
         return paid, live
 
     # ---- materialization ------------------------------------------------
-    def _entry_name(self, gen, parent, bslot, seed):
+    def _entry_name(self, rows, i: int) -> str:
+        gen, parent = int(rows["gen"][i]), int(rows["parent"][i])
         if parent >= 0:
             return f"g{gen}p{parent}"
+        bslot = int(rows["bslot"][i])
         if gen == 0 and 0 <= bslot < self.k_ov:
             return self.seed_corpus[bslot].name
-        return f"{self.space.plan.name}@{seed}"
+        return f"{self.space.plan.name}@{int(rows['seed'][i])}"
 
-    def _materialize(self, carry):
-        with span("madsim.explore.materialize"):
-            return self._materialize_host(jax.device_get(carry))
+    def _materialize(self, carry, *, build: bool = False):
+        """The corpus and violations as of ``carry``: one host copy of
+        both stores, read through :class:`EntryView`s; ``build=True``
+        builds every entry inside the span and returns lists. The span
+        carries ``entries`` (corpus plus violations) and ``built`` (the
+        entries constructed inside it)."""
+        with span("madsim.explore.materialize") as sp:
+            corpus, violations, gm = self._views(jax.device_get(carry))
+            views = (corpus, violations)
+            if build:
+                corpus, violations = list(corpus), list(violations)
+            sp.set_metadata(entries=len(corpus) + len(violations),
+                            built=sum(v.built for v in views))
+        return corpus, violations, gm
 
-    def _materialize_host(self, carry_host):
-        cn = {k: np.asarray(v) for k, v in carry_host["c"].items()}
-        vn = {k: np.asarray(v) for k, v in carry_host["v"].items()}
+    def _views(self, carry_host):
+        cn, vn = carry_host["c"], carry_host["v"]
         n_c = int(carry_host["count"])
-        n_v = int(carry_host["vcount"])
-        c_cache, v_cache = self._c_cache, self._v_cache
-        for i in range(len(c_cache), n_c):
-            c_cache[i] = _store_entry(
-                cn, i,
-                self._entry_name(int(cn["gen"][i]), int(cn["parent"][i]),
-                                 int(cn["bslot"][i]), int(cn["seed"][i])),
-            )
-        corpus = [c_cache[i] for i in range(n_c)]
-        by_id = {e.id: e for e in corpus}
-        for i in range(len(v_cache), min(n_v, self.vcap)):
-            eid = int(vn["id"][i])
-            # a violating entry that also joined the corpus is the SAME
-            # object in both lists (the host driver's sharing)
-            v_cache[i] = by_id.get(eid) or _store_entry(
-                vn, i,
-                self._entry_name(int(vn["gen"][i]), int(vn["parent"][i]),
-                                 int(vn["bslot"][i]), int(vn["seed"][i])),
-            )
-        violations = [v_cache[i] for i in range(min(n_v, self.vcap))]
+        n_v = min(int(carry_host["vcount"]), self.vcap)
+        corpus = EntryView(cn, n_c, self._c_cache, self._entry_name)
+        # a violating entry that also joined the corpus is the SAME
+        # object in both sequences (the host driver's sharing)
+        slot_of = {e: i for i, e in enumerate(cn["id"][:n_c].tolist())}
+
+        def shared(i):
+            slot = slot_of.get(int(vn["id"][i]))
+            return None if slot is None else corpus[slot]
+
+        violations = EntryView(vn, n_v, self._v_cache, self._entry_name,
+                               shared)
         return corpus, violations, np.asarray(carry_host["gmap"], np.uint32)
 
     def snapshot(self, gens_done: int, carry=None):
         from .persist import CampaignState
 
         corpus, violations, gm = self._materialize(
-            self.carry if carry is None else carry
+            self.carry if carry is None else carry, build=True
         )
         return CampaignState(
             workload=self.wl.name, config_hash=self.cfg.hash(),
@@ -1084,7 +1141,7 @@ class _CampaignSession:
             cov_hitcount=self.cov_hitcount, generations_done=gens_done,
             next_id=self.next_id, sims=self.sims, curve=list(self.curve),
             viol_curve=list(self.viol_curve), cov_map=gm.copy(),
-            corpus=list(corpus), violations=list(violations),
+            corpus=corpus, violations=violations,
         )
 
     # ---- telemetry + report ---------------------------------------------

@@ -30,6 +30,7 @@ hash (:func:`replay_entry`), and feeds ``chaos.shrink_plan`` directly.
 from __future__ import annotations
 
 import dataclasses
+from collections.abc import Sequence
 
 import numpy as np
 
@@ -68,6 +69,15 @@ class CorpusEntry:
     halt_t: int = 0  # halt clock ns (0 = ran to the step cap) — the
     # causal horizon the mutators respect when breeding from this entry
 
+    def __eq__(self, other):
+        # field by field, the coverage signature by value
+        if other.__class__ is not self.__class__:
+            return NotImplemented
+        return np.array_equal(self.cov, other.cov) and all(
+            getattr(self, f.name) == getattr(other, f.name)
+            for f in dataclasses.fields(self) if f.name != "cov"
+        )
+
 
 @dataclasses.dataclass
 class ExploreReport:
@@ -83,8 +93,11 @@ class ExploreReport:
     max_steps: int
     cov_words: int
     sims: int  # total simulations executed (the budget spent)
-    corpus: list  # admitted CorpusEntry list, admission order
-    violations: list  # violating CorpusEntry list (also in corpus)
+    # admitted CorpusEntry sequence, admission order, and the violating
+    # entries (also in corpus): lists on the host driver, read-only
+    # device.EntryView sequences on the device drivers
+    corpus: Sequence
+    violations: Sequence
     cov_map: np.ndarray  # (CW,) uint32 final global coverage map
     curve: list  # coverage bits after each generation
     viol_curve: list  # cumulative violation count after each generation

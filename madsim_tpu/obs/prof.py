@@ -65,9 +65,12 @@ covers and the metadata it carries:
     fold and ``consume``; at exit it carries the generation's
     ``row_steps_paid`` and ``row_steps_live``.
 
-* ``madsim.explore.materialize`` — the corpus and violations turned
-  into host objects for the report or a checkpoint, its
-  ``device_get`` included.
+* ``madsim.explore.materialize`` — the one ``device_get`` of the
+  corpus and violation stores for the report or a checkpoint, and
+  their ``EntryView``s; a checkpoint also builds every entry in it. At
+  exit it carries ``entries`` (corpus plus violations reported) and
+  ``built`` (the entries constructed inside the span: 0 for a report,
+  the entries new since the last checkpoint for a checkpoint).
 
 The pipelined driver (``farm.run_pipelined``) opens ``start``,
 ``sync`` (with no generation parent: it consumes behind its

@@ -19,6 +19,7 @@ import jax
 
 from madsim_tpu import explore
 from madsim_tpu.chaos import FaultPlan, GrayFailure, PauseStorm
+from madsim_tpu.explore import device
 from madsim_tpu.engine import EngineConfig
 from madsim_tpu.models import make_raft
 from madsim_tpu.parallel import make_mesh
@@ -188,6 +189,117 @@ class TestDeviceParity:
                 **dict(KW, generations=1, batch=8,
                        invariant=lambda v: v["halted"] & False),
             )
+
+
+# ---------------------------------------------------------------------------
+# the report's entries: a view over one host copy of the stores
+# ---------------------------------------------------------------------------
+
+# a campaign whose corpus fills (max_corpus=6), so its violations hold
+# both entries shared with the corpus and entries the full corpus refused
+VIEW_KW = dict(KW, invariant=_biased_inv, generations=2, max_corpus=6)
+_VIEW_RUN: dict = {}
+
+
+def _view_run():
+    """One device campaign, and what the eager materialize loop builds
+    from its session's final stores."""
+    if _VIEW_RUN:
+        return _VIEW_RUN
+    sessions = []
+    report = device._CampaignSession.report
+
+    def spy(self, **kw):
+        sessions.append(self)
+        return report(self, **kw)
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(device._CampaignSession, "report", spy)
+        rep = explore.run_device(make_raft(), CFG, PLAN, **VIEW_KW)
+    (sess,) = sessions
+    host = jax.device_get(sess.carry)
+    cn, vn = host["c"], host["v"]
+    corpus = [device._store_entry(cn, i, sess._entry_name(cn, i))
+              for i in range(int(host["count"]))]
+    by_id = {e.id: e for e in corpus}
+    violations = [
+        by_id.get(int(vn["id"][i]))
+        or device._store_entry(vn, i, sess._entry_name(vn, i))
+        for i in range(int(host["vcount"]))
+    ]
+    _VIEW_RUN.update(rep=rep, corpus=corpus, violations=violations)
+    return _VIEW_RUN
+
+
+class TestEntryView:
+    @pytest.mark.parametrize("which", ["corpus", "violations"])
+    def test_every_index_equals_the_store_row(self, which):
+        run = _view_run()
+        view, eager = getattr(run["rep"], which), run[which]
+        assert isinstance(view, device.EntryView)
+        assert len(view) == len(eager) > 0
+        for i in range(len(eager)):
+            assert view[i] == eager[i]
+            assert view[i] is view[i]  # built once, then cached
+
+    @pytest.mark.parametrize("which", ["corpus", "violations"])
+    def test_sequence_protocol(self, which):
+        run = _view_run()
+        view, eager = getattr(run["rep"], which), run[which]
+        n = len(eager)
+        assert view[-1] is view[n - 1] and view[-n] is view[0]
+        assert view[1:4] == eager[1:4] and view[::-2] == eager[::-2]
+        assert view[n:] == []
+        with pytest.raises(IndexError):
+            view[n]
+        with pytest.raises(IndexError):
+            view[-n - 1]
+        assert bool(view) and list(view) == eager
+        assert [e.id for e in view] == [e.id for e in eager]
+        assert view == eager and eager == view and view == tuple(eager)
+        assert view != eager[:-1] and view != eager[::-1]
+        assert view.built <= n
+
+    def test_empty_view_is_falsy(self):
+        view = device.EntryView({}, 0, {}, None)
+        assert not view and len(view) == 0 and list(view) == []
+        assert view == [] and view[:] == []
+
+    def test_violating_corpus_entry_is_one_object(self):
+        rep = _view_run()["rep"]
+        by_id = {e.id: e for e in rep.corpus}
+        shared = [v for v in rep.violations if v.id in by_id]
+        refused = [v for v in rep.violations if v.id not in by_id]
+        assert shared and refused, "the fixture must hold both kinds"
+        assert all(v is by_id[v.id] for v in shared)
+        assert rep.violations.built <= len(refused)
+
+    def test_device_and_host_fingerprints_equal(self):
+        host = explore.run(make_raft(), CFG, PLAN, **VIEW_KW)
+        assert _fingerprint(host) == _fingerprint(_view_run()["rep"])
+
+    def test_report_checkpoint_round_trips(self, tmp_path):
+        rep = _view_run()["rep"]
+        st = explore.CampaignState.from_report(rep)
+        assert st.corpus == list(rep.corpus)
+        p = str(tmp_path / "camp.npz")
+        st.save(p)
+        back = explore.load_campaign(p)
+        assert back.to_dict() == st.to_dict()
+        assert back.corpus == rep.corpus and back.violations == rep.violations
+
+    def test_resumed_entries_are_the_loaded_objects(self, tmp_path):
+        p = str(tmp_path / "camp.npz")
+        explore.run_device(make_raft(), CFG, PLAN,
+                           **dict(VIEW_KW, generations=1, checkpoint_path=p))
+        st = explore.load_campaign(p)
+        rep = explore.run_device(make_raft(), CFG, PLAN,
+                                 **dict(VIEW_KW, generations=1), resume=st)
+        assert st.corpus and st.violations
+        assert all(rep.corpus[i] is e for i, e in enumerate(st.corpus))
+        assert all(rep.violations[i] is e
+                   for i, e in enumerate(st.violations))
+        assert _fingerprint(rep) == _fingerprint(_view_run()["rep"])
 
 
 @pytest.mark.slow

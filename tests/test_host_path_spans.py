@@ -326,6 +326,48 @@ def test_campaign_spans_nest_and_carry_the_counters(tmp_path_factory):
     assert sum(s[3]["row_steps_live"] for s in syncs) == rep.row_steps_live
 
 
+def test_materialize_span_counts_entries_and_builds_none(tmp_path_factory):
+    runs = _runs(tmp_path_factory)
+    spans, rep = runs["spans"], runs["traced"][1]
+    (mat,) = _named(spans, "madsim.explore.materialize")
+    assert mat[3] == {"entries": len(rep.corpus) + len(rep.violations),
+                      "built": 0}
+    assert rep.corpus.built == 0 and len(rep.corpus) > 0
+
+
+def test_checkpoint_materialize_builds_the_checkpointed_entries(
+        tmp_path_factory):
+    """Each per-generation checkpoint builds its new entries inside its
+    materialize span; the report after them reuses every one."""
+    d = tmp_path_factory.mktemp("ckpt")
+    records = []
+    jax.profiler.start_trace(str(d / "trace"))
+    try:
+        rep = explore.run_device(
+            RAFT_WL, RAFT_CFG, RAFT_PLAN, telemetry=records.append,
+            checkpoint_path=str(d / "camp.npz"),
+            **dict(CAMPAIGN, invariant=lambda v: (v["trace"] & 7) != 0))
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(f"{d}/trace/**/*.xplane.pb", recursive=True)
+    mats = sorted(
+        (e.start_ns, dict(e.stats))
+        for plane in ProfileData.from_file(path).planes
+        if plane.name.startswith("/host:")
+        for line in plane.lines for e in line.events
+        if e.name == "madsim.explore.materialize")
+    gens = [r for r in records if r["event"] == "generation"]
+    assert len(mats) == len(gens) + 1 == 3
+    assert [m["entries"] for _, m in mats[:-1]] == [
+        r["corpus_size"] + r["violations"] for r in gens]
+    assert rep.violations, "the invariant must flag seeds"
+    entries = {id(e) for e in list(rep.corpus) + list(rep.violations)}
+    assert sum(m["built"] for _, m in mats) == len(entries)
+    assert mats[0][1]["built"] > 0
+    assert mats[-1][1] == {"entries": len(rep.corpus) + len(rep.violations),
+                           "built": 0}
+
+
 def test_search_is_bit_identical_under_the_profiler(tmp_path_factory):
     runs = _runs(tmp_path_factory)
     plain, traced = runs["plain"][0], runs["traced"][0]
